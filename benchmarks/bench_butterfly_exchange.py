@@ -36,17 +36,17 @@ def _worker(args: dict) -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.sharding import Mesh, PartitionSpec as P
+    from jax.sharding import PartitionSpec as P
 
     from repro.distributed import traversal
-    from repro.distributed.compat import shard_map
+    from repro.launch.mesh import make_mesh
 
     rows, num_words, iters = args["rows"], args["num_words"], args["iters"]
     n = rows * num_words
     rng = np.random.default_rng(5)
 
     for s in args["shard_counts"]:
-        mesh = Mesh(np.array(jax.devices()[:s]), ("model",))
+        mesh = make_mesh((s,), ("model",), devices=jax.devices()[:s])
         cap = traversal.gather_capacity_words(rows, num_words, 0)
 
         def dense_leg(fr):
@@ -59,10 +59,12 @@ def _worker(args: dict) -> None:
                                             num_words, s)
             return full, jax.lax.psum(sent, "model")
 
-        dense = jax.jit(shard_map(dense_leg, mesh, in_specs=P("model"),
-                                  out_specs=P(), check=False))
-        bf = jax.jit(shard_map(butterfly_leg, mesh, in_specs=P("model"),
-                               out_specs=(P(), P()), check=False))
+        dense = jax.jit(jax.shard_map(dense_leg, mesh=mesh,
+                                      in_specs=P("model"), out_specs=P(),
+                                      check_vma=False))
+        bf = jax.jit(jax.shard_map(butterfly_leg, mesh=mesh,
+                                   in_specs=P("model"),
+                                   out_specs=(P(), P()), check_vma=False))
 
         for active in args["active_words"]:
             if active > cap:

@@ -39,9 +39,9 @@ def _worker(args: dict) -> None:
 
     import jax
     import numpy as np
-    from jax.sharding import Mesh
 
     from repro.graph import generators
+    from repro.launch.mesh import make_mesh
     from repro.serve.distributed import (AsyncFrontEnd,
                                          DistributedQueryEngine,
                                          ShardedSketchStore)
@@ -51,7 +51,8 @@ def _worker(args: dict) -> None:
                                     prob=(0.0, 0.25), seed=11)
     n = g.num_vertices
     for shards in args["shard_counts"]:
-        mesh = Mesh(np.array(jax.devices()[:shards]), ("data",))
+        mesh = make_mesh((shards,), ("data",),
+                         devices=jax.devices()[:shards])
         store = ShardedSketchStore(
             g, PoolConfig(num_colors=args["colors"],
                           max_batches=args["batches"]), mesh)

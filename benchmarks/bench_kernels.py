@@ -14,7 +14,7 @@ import jax.numpy as jnp
 
 from repro.core import tiles, traversal
 from repro.graph import csr
-from repro.kernels import coverage, flash_attention, fused_expand
+from repro.kernels import coverage, flash_attention, fused_expand, ops
 
 
 def _time(fn, reps=3):
@@ -42,7 +42,7 @@ def run(out=print):
                              tg.padded_vertices)
     t = _time(lambda: fused_expand.fused_expand(
         tg.prob, tg.edge_id, tg.tile_src, tg.tile_dst, tg.first_of_dst,
-        fr, fr, jnp.uint32(1), jnp.uint32(0), interpret=True))
+        fr, fr, jnp.uint32(1), jnp.uint32(0), interpret=ops._interpret()))
     vmem_kb = (2 * 128 * 128 * 4 + 3 * 128 * 2 * 4) / 1024
     row = ("fused_expand", f"tiles={tg.num_tiles},W=2",
            round(1e6 * t, 1), f"vmem_tile={vmem_kb:.0f}KiB")
@@ -51,7 +51,8 @@ def run(out=print):
 
     vis = jnp.asarray(rng.integers(0, 2**32, (4096, 16), dtype=np.uint32))
     act = jnp.asarray(rng.integers(0, 2**32, (16,), dtype=np.uint32))
-    t = _time(lambda: coverage.cover_counts(vis, act, interpret=True))
+    t = _time(lambda: coverage.cover_counts(vis, act,
+                                            interpret=ops._interpret()))
     row = ("cover_counts", "V=4096,W=16", round(1e6 * t, 1),
            "popcount-SWAR")
     rows.append(row)
@@ -59,7 +60,7 @@ def run(out=print):
 
     q = jax.random.normal(jax.random.key(1), (512, 4, 64), jnp.float32)
     t = _time(lambda: flash_attention.flash_attention(
-        q, q, q, causal=True, interpret=True))
+        q, q, q, causal=True, interpret=ops._interpret()))
     row = ("flash_attention", "L=512,H=4,D=64", round(1e6 * t, 1),
            f"flops={2*2*512*512*4*64/1e6:.0f}MF")
     rows.append(row)

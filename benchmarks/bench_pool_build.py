@@ -106,10 +106,10 @@ def _worker(args: dict) -> None:
     accel.configure(host_devices=_DEVICES)
     import jax
     import numpy as np
-    from jax.sharding import Mesh
 
     from repro import sampling
     from repro.graph import csr, generators
+    from repro.launch.mesh import make_mesh
     from repro.serve.distributed import ShardedSketchStore
     from repro.serve.influence import PoolConfig, SketchStore
 
@@ -152,8 +152,10 @@ def _worker(args: dict) -> None:
                         store = SketchStore(g, cfg)
                     else:
                         devs = np.array(jax.devices()[: d * m])
-                        mesh = (Mesh(devs.reshape(d, m), ("data", "model"))
-                                if m > 1 else Mesh(devs, ("data",)))
+                        mesh = (make_mesh((d, m), ("data", "model"),
+                                          devices=devs)
+                                if m > 1 else
+                                make_mesh((d,), ("data",), devices=devs))
                         store = ShardedSketchStore(g, cfg, mesh)
                     # Cold build compiles every program; stack staging
                     # arms the in-place refresh path.

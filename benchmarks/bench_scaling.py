@@ -33,10 +33,11 @@ import numpy as np, jax, jax.numpy as jnp
 from repro.core import traversal
 from repro.distributed import traversal as dtrav
 from repro.graph import generators
+from repro.launch.mesh import make_mesh
 
 n_dev = int(sys.argv[1])
 g = generators.powerlaw_cluster(3000, 10.0, prob=0.2, seed=1)
-mesh = jax.make_mesh((n_dev,), ("data",))
+mesh = make_mesh((n_dev,), ("data",))
 B, C = 16, 64
 starts = jnp.stack([
     traversal.random_starts(jax.random.key(b), g.num_vertices, C)

@@ -153,6 +153,7 @@ def _worker(args: dict) -> None:
 
     from repro import sampling
     from repro.graph import csr, generators
+    from repro.launch.mesh import make_mesh
     from repro.serve.distributed import ShardedSketchStore
     from repro.serve.influence import PoolConfig, SketchStore
 
@@ -165,7 +166,7 @@ def _worker(args: dict) -> None:
         g = csr.dedupe(generators.powerlaw_cluster(
             sweep["n"], sweep["deg"], prob=tuple(sweep["prob"]), seed=11))
         for backend, shards in sweep["backends"]:
-            mesh = (jax.make_mesh((shards,), ("data",))
+            mesh = (make_mesh((shards,), ("data",))
                     if backend == "data_parallel" else None)
             spec = sampling.SamplerSpec(
                 diffusion="ic", backend=backend,

@@ -33,6 +33,7 @@ import jax                   # noqa: E402
 import numpy as np           # noqa: E402
 
 from repro.graph import generators                              # noqa: E402
+from repro.launch.mesh import make_mesh                         # noqa: E402
 from repro.serve.distributed import (AsyncFrontEnd,             # noqa: E402
                                      DistributedQueryEngine,
                                      ShardedSketchStore)
@@ -59,7 +60,7 @@ def main():
                      memory_budget_mb=args.budget_mb, master_seed=7)
 
     # --- 1. shard a pool over the mesh's data axis -----------------------
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     store = ShardedSketchStore(g, cfg, mesh)
     t0 = time.time()
     store.ensure(args.batches)
@@ -114,7 +115,7 @@ def main():
     fe.close()
 
     # --- 4. restore the 8-shard snapshot under 2 shards ------------------
-    mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+    mesh2 = make_mesh((2, 4), ("data", "model"))
     restored = ShardedSketchStore.restore(ckpt, g, cfg, mesh2)
     r_seeds, r_sigma = DistributedQueryEngine(restored).top_k(args.k)
     assert np.array_equal(seeds, r_seeds) and sigma == r_sigma

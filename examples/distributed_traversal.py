@@ -21,6 +21,7 @@ import numpy as np              # noqa: E402
 from repro.core import imm, tiles, traversal            # noqa: E402
 from repro.distributed import traversal as dtrav        # noqa: E402
 from repro.graph import csr, generators, partition      # noqa: E402
+from repro.launch.mesh import make_mesh                 # noqa: E402
 
 
 def main():
@@ -28,7 +29,7 @@ def main():
     g = generators.powerlaw_cluster(1500, 8.0, prob=0.25, seed=3)
 
     # --- sample parallel: 16 batches over 8 devices -----------------------
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     B, C = 16, 64
     starts = jnp.stack([traversal.random_starts(jax.random.key(b),
                                                 g.num_vertices, C)
@@ -44,7 +45,7 @@ def main():
           f"coverage={cov:.4f}")
 
     # --- graph parallel: vertex partition over 'model' --------------------
-    mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+    mesh2 = make_mesh((2, 4), ("data", "model"))
     g2 = csr.dedupe(g)
     ptg = partition.partition(tiles.from_graph(g2), num_shards=4)
     st = traversal.random_starts(jax.random.key(9), g2.num_vertices, C)

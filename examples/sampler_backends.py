@@ -22,6 +22,7 @@ import numpy as np              # noqa: E402
 
 from repro import sampling      # noqa: E402
 from repro.graph import generators                          # noqa: E402
+from repro.launch.mesh import make_mesh                     # noqa: E402
 from repro.serve.distributed import (DistributedQueryEngine,    # noqa: E402
                                      ShardedSketchStore)
 from repro.serve.influence import (PoolConfig, QueryEngine,     # noqa: E402
@@ -34,7 +35,7 @@ def main():
     # parallel edges merged, and bit-identity needs one shared edge list.
     from repro.graph import csr
     g = csr.dedupe(generators.powerlaw_cluster(1000, 8.0, prob=0.25, seed=3))
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     batches, colors = 16, 64
 
     # One spec per backend — everything else identical.
@@ -74,7 +75,7 @@ def main():
           "(bit-identical on both engines)")
 
     # --- graph parallel: rows over 'model', batches over 'data' ------------
-    mesh2d = jax.make_mesh((4, 2), ("data", "model"))
+    mesh2d = make_mesh((4, 2), ("data", "model"))
     gp_store = ShardedSketchStore(
         g, PoolConfig(max_batches=batches,
                       spec=dense_spec.replace(backend="graph_parallel")),

@@ -10,6 +10,8 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import rng
+
 WORD_BITS = 32
 
 
@@ -80,8 +82,7 @@ def unpack_bits(mask: jnp.ndarray) -> jnp.ndarray:
 
 def pack_bits(bits: jnp.ndarray) -> jnp.ndarray:
     """(..., W, 32) bool → (..., W) uint32."""
-    weights = jnp.uint32(1) << jnp.arange(WORD_BITS, dtype=jnp.uint32)
-    return jnp.sum(bits.astype(jnp.uint32) * weights, axis=-1, dtype=jnp.uint32)
+    return rng.pack_bool_word(bits)
 
 
 def popcount(mask: jnp.ndarray) -> jnp.ndarray:

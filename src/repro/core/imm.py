@@ -400,6 +400,7 @@ def _run_from_frontier(g: csr.Graph, frontier, num_colors: int, seed,
     from repro.core import traversal as trav
 
     visited = jnp.zeros_like(frontier)
+    view = trav.dst_view(g)
 
     def cond(carry):
         fr, _, lvl = carry
@@ -407,7 +408,7 @@ def _run_from_frontier(g: csr.Graph, frontier, num_colors: int, seed,
 
     def body(carry):
         fr, vis, lvl = carry
-        nf, nv, _ = trav.fused_step(g, fr, vis, lvl, seed)
+        nf, nv, _ = trav._expand(view, fr, vis, lvl, seed)
         return nf, nv, lvl + 1
 
     fr, vis, _ = jax.lax.while_loop(cond, body,

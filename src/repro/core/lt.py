@@ -116,11 +116,19 @@ def _selection_mask(g: csr.Graph, num_colors: int, seed) -> jnp.ndarray:
 
 
 def lt_traversal_program(g: csr.Graph, sel, starts, num_colors: int,
-                         max_levels: int):
+                         max_levels: int, segments=None):
     """Level loop over a fixed live-edge selection — trace-time program
-    (callers jit or stage inside shard_map).  Returns visited (V, W)."""
+    (callers jit or stage inside shard_map).  Returns visited (V, W).
+    ``segments``: `traversal.dst_segments(g.dst, V)` when the caller holds
+    it (built here otherwise)."""
+    from repro.core.traversal import _or_by_dst, dst_segments
+
     frontier = init_frontier(g.num_vertices, num_colors, starts)
     visited = jnp.zeros_like(frontier)
+    seg = (segments if segments is not None
+           else dst_segments(g.dst, g.num_vertices))
+    # Edge sources and selections in destination order, once per traversal.
+    src, sel = g.src[seg.order], sel[seg.order]
 
     def cond(c):
         fr, _, lvl = c
@@ -129,9 +137,8 @@ def lt_traversal_program(g: csr.Graph, sel, starts, num_colors: int,
     def body(c):
         fr, vis, lvl = c
         vis = vis | fr
-        contrib = fr[g.src] & sel & ~vis[g.dst]
-        from repro.core.traversal import _scatter_or
-        nf = _scatter_or(jnp.zeros_like(vis), g.dst, contrib) & ~vis
+        # Visited colors are masked once per destination, after the OR.
+        nf = _or_by_dst(jnp.zeros_like(vis), fr[src] & sel, seg) & ~vis
         return nf, vis, lvl + 1
 
     fr, vis, _ = jax.lax.while_loop(cond, body,
@@ -159,15 +166,21 @@ def _run_fused_lt_jit(g: csr.Graph, cb, starts, seed, num_colors: int,
 
 @partial(jax.jit, static_argnames=("num_colors", "max_levels"))
 def run_fused_lt_block(g: csr.Graph, cb, starts, seeds, num_colors: int,
-                       max_levels: int = 64) -> jnp.ndarray:
+                       max_levels: int = 64, segments=None) -> jnp.ndarray:
     """Fused multi-batch LT sweep: ONE dispatch traverses a block of
     batches via ``lax.map`` (each batch draws its own live-edge selection
     from its seed, one (E, W) selection transient at a time).
 
-    starts (B, C) int32 / seeds (B,) uint32 → visited (B, V, W)."""
+    starts (B, C) int32 / seeds (B,) uint32 → visited (B, V, W).
+    ``segments`` as in `lt_traversal_program`."""
+    if segments is None:
+        from repro.core.traversal import dst_segments
+        segments = dst_segments(g.dst, g.num_vertices)
+
     def one(args):
         st, sd = args
         sel = selection_mask_from_cb(g, cb, num_colors, sd)
-        return lt_traversal_program(g, sel, st, num_colors, max_levels)
+        return lt_traversal_program(g, sel, st, num_colors, max_levels,
+                                    segments)
 
     return jax.lax.map(one, (starts, seeds))

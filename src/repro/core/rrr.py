@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import bitmask, tiled_traversal, tiles, traversal
+from repro.core import bitmask, traversal
 from repro.graph import csr
 
 
@@ -60,20 +60,17 @@ def batch_starts(num_vertices: int, num_colors: int, master_seed: int,
 def sample_batch(g_rev: csr.Graph, num_colors: int, master_seed: int,
                  batch_index: int, *, sort_starts: bool = False,
                  max_levels: int = 64,
-                 tg_rev: tiles.TiledGraph | None = None,
-                 use_kernel: bool = False,
                  model: str = "ic") -> RRRBatch:
     """Sample one fused batch of RRR sets on the REVERSED graph ``g_rev``.
 
-    NOTE: this is the low-level primitive of the `repro.sampling` facade —
-    new code should go through ``repro.sampling.make_sampler`` (a CI grep
-    guard enforces that nothing outside ``repro/sampling/`` calls this).
+    NOTE: this per-batch path is the reference the `repro.sampling`
+    facade's block programs are tested against — new code goes through
+    ``repro.sampling.make_sampler`` (a CI grep guard enforces that nothing
+    outside ``repro/sampling/`` calls this).
 
     ``model``: "ic" (Independent Cascade, the paper's evaluation model) or
     "lt" (Linear Threshold via live-edge selection — g_rev must carry
     LT-normalized in-weights, see core/lt.normalize_lt_weights).
-    ``tg_rev``/``use_kernel`` switch expansion to the tiled Pallas path;
-    results are bit-for-bit identical to the CSR path (coupled RNG).
     """
     seed = batch_seed(master_seed, batch_index)
     roots = batch_starts(g_rev.num_vertices, num_colors, master_seed,
@@ -82,11 +79,6 @@ def sample_batch(g_rev: csr.Graph, num_colors: int, master_seed: int,
         from repro.core import lt
         visited = lt.run_fused_lt(g_rev, roots, num_colors, seed,
                                   max_levels=max_levels)
-        return RRRBatch(visited, np.asarray(roots), batch_index, -1, -1)
-    if tg_rev is not None:
-        visited, _, _ = tiled_traversal.run_fused_tiled(
-            tg_rev, roots, num_colors, seed, max_levels=max_levels,
-            use_kernel=use_kernel)
         return RRRBatch(visited, np.asarray(roots), batch_index, -1, -1)
     res = traversal.run_fused(g_rev, roots, num_colors, seed,
                               max_levels=max_levels)

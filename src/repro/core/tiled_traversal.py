@@ -78,8 +78,8 @@ def _sparse_tile_expand(tgn: tiles.TiledGraph, num_tiles: int,
 @partial(jax.jit, static_argnames=("num_colors", "max_levels", "use_kernel",
                                    "interpret", "frontier", "ladder"))
 def run_fused_lt_tiled(tg: tiles.TiledGraph, cb_tiles, starts,
-                       num_colors: int, seed, max_levels: int = 64,
-                       use_kernel: bool = True, interpret: bool = True,
+                       num_colors: int, seed, max_levels: int = 64, *,
+                       use_kernel: bool, interpret: bool,
                        frontier: str = "dense",
                        ladder: tuple[int, ...] | None = None):
     """LT fused traversal on the block-sparse tile layout.
@@ -92,6 +92,8 @@ def run_fused_lt_tiled(tg: tiles.TiledGraph, cb_tiles, starts,
     layout (``tiles.edge_values_to_tiles(tg, lt.selection_cum_before(g))``).
     ``frontier="sparse"`` compacts to the active tiles per level (see
     module docstring); ``ladder`` overrides the capacity buckets.
+    ``interpret`` runs the kernel in the Pallas interpreter
+    (`kernels.ops._interpret` decides it for the backend).
     Returns (visited (V, W) uint32, levels_run int32, grid_steps int32).
     """
     vp = tg.padded_vertices
@@ -100,10 +102,11 @@ def run_fused_lt_tiled(tg: tiles.TiledGraph, cb_tiles, starts,
     visited = jnp.zeros_like(fr0)
     # Selection uniforms are level-independent: ONE table per traversal.
     u = kref.lt_selection_uniforms(jnp.uint32(seed), vp, num_colors)
+    u_t = u.T if use_kernel else None          # the kernel's lane-row layout
 
     def expand_tiles(p, cbt, ts, td, fi, fr, vis):
         if use_kernel:
-            return lse.lt_select_expand(p, cbt, ts, td, fi, fr, vis, u,
+            return lse.lt_select_expand(p, cbt, ts, td, fi, fr, vis, u_t,
                                         interpret=interpret)
         return kref.lt_select_expand_ref(p, cbt, ts, td, fr, vis, u)
 
@@ -146,14 +149,15 @@ def run_fused_lt_tiled(tg: tiles.TiledGraph, cb_tiles, starts,
 @partial(jax.jit, static_argnames=("num_colors", "max_levels", "use_kernel",
                                    "interpret", "frontier", "ladder"))
 def run_fused_tiled(tg: tiles.TiledGraph, starts, num_colors: int, seed,
-                    max_levels: int = 64, use_kernel: bool = True,
-                    interpret: bool = True, frontier: str = "dense",
+                    max_levels: int = 64, *, use_kernel: bool,
+                    interpret: bool, frontier: str = "dense",
                     ladder: tuple[int, ...] | None = None):
     """Returns (visited (V, W) uint32, levels_run int32, grid_steps int32).
 
     ``frontier="sparse"`` compacts each level's expansion to the tiles
     with an active source block (module docstring); works through both
-    the Pallas kernel and the jnp oracle, bit-identical to dense."""
+    the Pallas kernel and the jnp oracle, bit-identical to dense.
+    ``interpret`` as in `run_fused_lt_tiled`."""
     vp = tg.padded_vertices
     fr0 = tiles.pad_mask_rows(
         init_frontier(tg.num_vertices, num_colors, starts), vp)

@@ -1,6 +1,6 @@
 """Fused breadth-first probabilistic traversals (paper §3, Listing 1).
 
-TPU-native formulation (DESIGN.md §2): the frontier is a dense packed color
+TPU-native formulation: the frontier is a dense packed color
 bitmask ``(V, W)`` and one level of the fused traversal is an edge-centric
 sweep
 
@@ -8,7 +8,7 @@ sweep
     frontier'  = scatter_or(dst, contrib) & ~visited'
     visited'   = visited | frontier
 
-which is the OR-AND-semiring SpMM of DESIGN.md.  Because every mask update is
+which is an OR-AND-semiring SpMM.  Because every mask update is
 bitwise-independent per color, the fused traversal restricted to color ``c``
 is *exactly* the single-color BPT driven by the same counter RNG — fused and
 unfused runs are coupled bit-for-bit (used by tests to check equivalence and
@@ -81,34 +81,114 @@ def random_starts(key: jax.Array, num_vertices: int, num_colors: int,
     return jnp.sort(starts) if sort else starts
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class DstSegments:
+    """Edges grouped by destination, for the scatter-free `_scatter_or`.
+
+    ``order`` (E,) sorts the edges by destination (stable); ``rank`` (E,)
+    is sorted edge i's position among its destination's in-edges;
+    ``last`` (R,) is row r's last sorted in-edge, -1 for a row with none;
+    ``max_rank`` () is the largest in-degree minus one.  A function of
+    ``dst`` alone: build it once per graph (`dst_segments`), outside the
+    programs that run the level loops."""
+    order: jnp.ndarray
+    rank: jnp.ndarray
+    last: jnp.ndarray
+    max_rank: jnp.ndarray
+
+
+@partial(jax.jit, static_argnames=("num_rows",))
+def dst_segments(dst: jnp.ndarray, num_rows: int) -> DstSegments:
+    """`DstSegments` of an edge-destination array over ``num_rows`` rows."""
+    e = dst.shape[0]
+    order = jnp.argsort(dst, stable=True).astype(jnp.int32)
+    d = dst[order]
+    pos = jnp.arange(e, dtype=jnp.int32)
+    change = d[1:] != d[:-1]
+    first = jnp.concatenate([jnp.ones((1,), bool), change])
+    is_last = jnp.concatenate([change, jnp.ones((1,), bool)])
+    start_of = jnp.full((num_rows,), 0, jnp.int32).at[
+        jnp.where(first, d, num_rows)].set(pos, mode="drop")
+    last = jnp.full((num_rows,), -1, jnp.int32).at[
+        jnp.where(is_last, d, num_rows)].set(pos, mode="drop")
+    rank = pos - start_of[d]
+    return DstSegments(order, rank, last, jnp.max(rank, initial=0))
+
+
+def _or_by_dst(base_words: jnp.ndarray, contrib: jnp.ndarray,
+               seg: DstSegments) -> jnp.ndarray:
+    """base[dst] |= contrib for ``contrib`` already in destination order.
+
+    No scatter: a segmented Hillis-Steele OR scan — ⌈log₂ max in-degree⌉
+    shifted ORs — folds each destination's in-edges into its last one,
+    which one gather per row picks.  OR is order-free, so this equals a
+    per-bit scatter-max exactly (which on TPU costs 32× the updates and
+    serializes on duplicate destinations)."""
+    zero = jnp.uint32(0)
+
+    def shift_or(carry):
+        acc, s = carry
+        prev = jnp.roll(acc, s, axis=0)          # wrapped rows have rank < s
+        return acc | jnp.where((seg.rank >= s)[:, None], prev, zero), s * 2
+
+    acc, _ = jax.lax.while_loop(lambda c: c[1] <= seg.max_rank, shift_or,
+                                (contrib, jnp.int32(1)))
+    got = jnp.where((seg.last >= 0)[:, None], acc[jnp.maximum(seg.last, 0)],
+                    zero)
+    return base_words | got
+
+
 def _scatter_or(base_words: jnp.ndarray, dst: jnp.ndarray,
-                contrib: jnp.ndarray) -> jnp.ndarray:
-    """base[dst] |= contrib with duplicate destinations ORed together."""
-    lanes = bitmask.unpack_bits(contrib)                    # (E, W, 32)
-    out = bitmask.unpack_bits(base_words)                   # (V, W, 32)
-    out = out.at[dst].max(lanes)
-    return bitmask.pack_bits(out)
+                contrib: jnp.ndarray,
+                segments: DstSegments | None = None) -> jnp.ndarray:
+    """base[dst] |= contrib with duplicate destinations ORed together
+    (`_or_by_dst` after sorting ``contrib`` by destination).  Pass
+    ``segments`` (`dst_segments(dst, rows)`) to reuse them across levels;
+    without it they are built here."""
+    seg = (segments if segments is not None
+           else dst_segments(dst, base_words.shape[0]))
+    return _or_by_dst(base_words, contrib[seg.order], seg)
 
 
-def fused_step(g: Graph, frontier: jnp.ndarray, visited: jnp.ndarray,
-               level: jnp.ndarray, seed: jnp.ndarray):
-    """One level of the fused traversal.  Returns (frontier', visited', info)."""
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class DstView:
+    """The edge arrays a level loop reads, in destination order: sources,
+    probabilities and CSR edge ids (the RNG counter, so every draw is the
+    one the CSR order makes).  Built once per traversal (`dst_view`), so a
+    level gathers only the frontier rows of its edge sources."""
+    src: jnp.ndarray
+    prob: jnp.ndarray
+    edge_id: jnp.ndarray
+    seg: DstSegments
+
+
+def dst_view(g: Graph, segments: DstSegments | None = None) -> DstView:
+    seg = (segments if segments is not None
+           else dst_segments(g.dst, g.num_vertices))
+    return DstView(g.src[seg.order], g.prob[seg.order],
+                   seg.order.astype(jnp.uint32), seg)
+
+
+def _expand(view: DstView, frontier: jnp.ndarray, visited: jnp.ndarray,
+            level: jnp.ndarray, seed: jnp.ndarray):
+    """One level of the fused traversal on a `DstView`.  Returns
+    (frontier', visited', info)."""
     num_words = frontier.shape[-1]
-    edge_ids = jnp.arange(g.padded_edges, dtype=jnp.uint32)
-
     visited = visited | frontier                            # Listing 1 line 8
-    fr_src = frontier[g.src]                                # (E, W) gather
+    fr_src = frontier[view.src]                             # (E, W) gather
     # Independent Bernoulli(p_e) per (edge, color): one packed word per
     # (edge, word) pair.  Padding edges have prob 0 → never propagate.
     word_ids = jnp.arange(num_words, dtype=jnp.uint32)
     rand = jax.vmap(
         lambda w: rng.bernoulli_word(seed, level.astype(jnp.uint32),
-                                     edge_ids, w, g.prob),
+                                     view.edge_id, w, view.prob),
         out_axes=1)(word_ids)                               # (E, W)
-    contrib = fr_src & rand & ~visited[g.dst]               # lines 11-13
-    next_frontier = _scatter_or(jnp.zeros_like(visited), g.dst, contrib)
-    next_frontier = next_frontier & ~visited                # line 11 (re-check
-    # after OR: several sources may race to color the same dst — all valid)
+    # Lines 11-13.  Masking the visited colors once per destination after
+    # the OR equals masking every in-edge before it.
+    next_frontier = _or_by_dst(jnp.zeros_like(visited), fr_src & rand,
+                               view.seg) & ~visited
 
     active_src = bitmask.count_colors(fr_src)               # (E,) per-edge
     info = dict(
@@ -119,6 +199,14 @@ def fused_step(g: Graph, frontier: jnp.ndarray, visited: jnp.ndarray,
         frontier_colors=jnp.sum(bitmask.count_colors(frontier)),
     )
     return next_frontier, visited, info
+
+
+def fused_step(g: Graph, frontier: jnp.ndarray, visited: jnp.ndarray,
+               level: jnp.ndarray, seed: jnp.ndarray, segments=None):
+    """One level of the fused traversal.  Returns (frontier', visited', info).
+
+    Level loops build the `dst_view` once and call `_expand` instead."""
+    return _expand(dst_view(g, segments), frontier, visited, level, seed)
 
 
 def _tile_activity(frontier: jnp.ndarray, tile_rows: int = 128) -> jnp.ndarray:
@@ -133,8 +221,10 @@ def _tile_activity(frontier: jnp.ndarray, tile_rows: int = 128) -> jnp.ndarray:
 
 @partial(jax.jit, static_argnames=("num_colors", "max_levels"))
 def run_fused(g: Graph, starts: jnp.ndarray, num_colors: int,
-              seed: jnp.ndarray, max_levels: int = 64) -> TraversalResult:
-    """Run the fused BPT to frontier exhaustion (≤ max_levels)."""
+              seed: jnp.ndarray, max_levels: int = 64,
+              segments: DstSegments | None = None) -> TraversalResult:
+    """Run the fused BPT to frontier exhaustion (≤ max_levels).
+    ``segments``: `dst_segments(g.dst, V)` when the caller holds it."""
     v = g.num_vertices
     frontier = init_frontier(v, num_colors, starts)
     visited = bitmask.make_mask(v, num_colors)
@@ -142,6 +232,7 @@ def run_fused(g: Graph, starts: jnp.ndarray, num_colors: int,
     zeros_f = jnp.zeros((max_levels,), jnp.float32)
     stats = TraversalStats(jnp.int32(0), zeros_i, zeros_i, zeros_i, zeros_i,
                            zeros_f, zeros_f, zeros_i)
+    view = dst_view(g, segments)
 
     def cond(carry):
         frontier, _, level, _ = carry
@@ -150,7 +241,7 @@ def run_fused(g: Graph, starts: jnp.ndarray, num_colors: int,
     def body(carry):
         frontier, visited, level, stats = carry
         tile_frac = _tile_activity(frontier)
-        nf, nv, info = fused_step(g, frontier, visited, level, seed)
+        nf, nv, info = _expand(view, frontier, visited, level, seed)
         occ = jnp.where(info["frontier_vertices"] > 0,
                         info["frontier_colors"].astype(jnp.float32)
                         / jnp.maximum(info["frontier_vertices"], 1)
@@ -181,7 +272,8 @@ def run_fused(g: Graph, starts: jnp.ndarray, num_colors: int,
 
 @partial(jax.jit, static_argnames=("num_colors", "max_levels"))
 def run_fused_block(g: Graph, starts: jnp.ndarray, seeds: jnp.ndarray,
-                    num_colors: int, max_levels: int = 64):
+                    num_colors: int, max_levels: int = 64,
+                    segments: DstSegments | None = None):
     """Fused multi-batch sweep: ONE dispatch traverses a whole block of
     batches via ``lax.map`` (sequential per batch — one (V, W) transient
     at a time — so a pool build stops paying per-batch dispatch).
@@ -189,7 +281,10 @@ def run_fused_block(g: Graph, starts: jnp.ndarray, seeds: jnp.ndarray,
     starts (B, C) int32 / seeds (B,) uint32 → (visited (B, V, W),
     fused (B,), unfused (B,)) with the edge-visit totals equal to
     ``run_fused``'s per-level stats summed (same int32 arithmetic).
+    ``segments`` as in `run_fused`.
     """
+    view = dst_view(g, segments)
+
     def one(args):
         st, sd = args
         frontier = init_frontier(g.num_vertices, num_colors, st)
@@ -201,7 +296,7 @@ def run_fused_block(g: Graph, starts: jnp.ndarray, seeds: jnp.ndarray,
 
         def body(c):
             fr, vis, lvl, fused, unfused = c
-            nf, nv, info = fused_step(g, fr, vis, lvl, sd)
+            nf, nv, info = _expand(view, fr, vis, lvl, sd)
             return (nf, nv, lvl + 1, fused + info["fused_visits"],
                     unfused + info["unfused_visits"])
 
@@ -231,6 +326,7 @@ def run_single_color(g: Graph, start: jnp.ndarray, color_id: int,
     zeros_f = jnp.zeros((max_levels,), jnp.float32)
     stats = TraversalStats(jnp.int32(0), zeros_i, zeros_i, zeros_i, zeros_i,
                            zeros_f, zeros_f, zeros_i)
+    segments = dst_segments(g.dst, v)
 
     def cond(carry):
         frontier, _, level, _ = carry
@@ -247,7 +343,8 @@ def run_single_color(g: Graph, start: jnp.ndarray, color_id: int,
         draw = (rng.uniform_from_u32(bits) < g.prob)
         rand = jnp.where(draw, lane_bit, jnp.uint32(0))[:, None]
         contrib = fr_src & rand & ~visited[g.dst]
-        nf = _scatter_or(jnp.zeros_like(visited), g.dst, contrib) & ~visited
+        nf = _scatter_or(jnp.zeros_like(visited), g.dst, contrib,
+                         segments) & ~visited
         visits = jnp.sum((fr_src[:, 0] & lane_bit) > 0, dtype=jnp.int32)
         stats = TraversalStats(
             levels_run=stats.levels_run + 1,

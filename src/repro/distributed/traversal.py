@@ -1,4 +1,4 @@
-"""Distributed fused-BPT traversal (DESIGN.md §3).
+"""Distributed fused-BPT traversal.
 
 Two orthogonal axes, composable on one mesh:
 
@@ -39,13 +39,15 @@ from repro.kernels import ref as kref
 
 # ------------------------------------------------------------ sample parallel
 def run_batch(g: csr.Graph, starts, seed, num_colors: int,
-              max_levels: int = 64):
+              max_levels: int = 64, segments=None):
     """One fused batch as a jit-friendly pure function of (graph, starts,
-    seed) — the unit that sample parallelism vmaps/shards."""
-    from repro.core.traversal import fused_step
+    seed) — the unit that sample parallelism vmaps/shards.  ``segments``:
+    `traversal.dst_segments(g.dst, V)` when the caller holds it."""
+    from repro.core.traversal import _expand, dst_view
 
     frontier = init_frontier(g.num_vertices, num_colors, starts)
     visited = jnp.zeros_like(frontier)
+    view = dst_view(g, segments)
 
     def cond(c):
         fr, _, lvl = c
@@ -53,7 +55,7 @@ def run_batch(g: csr.Graph, starts, seed, num_colors: int,
 
     def body(c):
         fr, vis, lvl = c
-        nf, nv, _ = fused_step(g, fr, vis, lvl, seed)
+        nf, nv, _ = _expand(view, fr, vis, lvl, seed)
         return nf, nv, lvl + 1
 
     fr, vis, _ = jax.lax.while_loop(
@@ -310,8 +312,7 @@ def _butterfly_exchange(fr, axis: str, num_shards: int, n: int, k: int):
 
 
 def _local_expand(ptg_local, diffusion: str, cb_local, seed, dst_block_base,
-                  num_colors: int, use_kernel: bool = False,
-                  interpret: bool = True):
+                  num_colors: int, *, use_kernel: bool, interpret: bool):
     """Per-shard expansion closure over the shard's (leading-dim-1) tile
     stacks: IC draws per-(edge, color, level) Bernoullis keyed by CSR edge
     id; LT derives the fixed live-edge selection from GLOBAL destination
@@ -333,13 +334,14 @@ def _local_expand(ptg_local, diffusion: str, cb_local, seed, dst_block_base,
         u = kref.lt_selection_uniforms(
             seed, rows, num_colors,
             row_base=dst_block_base * ptg_local.tile_size)
+        u_t = u.T if use_kernel else None      # the kernel's lane-row layout
 
         def expand(fr_global, vis_local, level):
             if use_kernel:
                 return lse.lt_select_expand(
                     ptg_local.prob[0], cb_local[0], ptg_local.tile_src[0],
                     ptg_local.tile_dst[0], ptg_local.first_of_dst[0],
-                    fr_global, vis_local, u, interpret=interpret)
+                    fr_global, vis_local, u_t, interpret=interpret)
             return kref.lt_select_expand_ref(
                 ptg_local.prob[0], cb_local[0], ptg_local.tile_src[0],
                 ptg_local.tile_dst[0], fr_global, vis_local, u)
@@ -368,7 +370,6 @@ def graph_parallel_traversal(ptg: part_lib.PartitionedTiledGraph,
     Returns (visited (V, W), levels).  Tile stacks enter shard_map with their
     leading shard dim consumed by the mesh axis.
     """
-    from repro.distributed.compat import shard_map
 
     vp = ptg.padded_vertices
     frontier = tiles.pad_mask_rows(
@@ -379,17 +380,17 @@ def graph_parallel_traversal(ptg: part_lib.PartitionedTiledGraph,
         base = (jax.lax.axis_index(axis).astype(jnp.int32)
                 * ptg_local.blocks_per_shard)
         expand = _local_expand(ptg_local, "ic", None, seed, base,
-                               num_colors)
+                               num_colors, use_kernel=False, interpret=False)
         vis, levels, _ = _frontier_gather_loop(expand, frontier_local,
                                                max_levels, axis,
                                                num_shards=ptg.num_shards)
         return vis, levels
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(part_lib.partition_specs(ptg, axis), P(axis)),
         out_specs=(P(axis), P()),
-        check=False)
+        check_vma=False)
     visited, levels = jax.jit(fn)(ptg, frontier)
     return visited[: ptg.num_vertices], levels
 
@@ -407,8 +408,8 @@ def graph_parallel_block(ptg: part_lib.PartitionedTiledGraph, mesh: Mesh, *,
                          data_axis: str = "data", model_axis: str = "model",
                          num_colors: int, max_levels: int = 64,
                          diffusion: str = "ic", frontier: str = "dense",
-                         gather_capacity: int = 0, use_kernel: bool = False,
-                         interpret: bool = True):
+                         gather_capacity: int = 0, use_kernel: bool,
+                         interpret: bool):
     """Build (or fetch the cached) 2-D (data × model) fused-BPT block program.
 
     The composition the `repro.sampling` ``graph_parallel`` backend runs:
@@ -447,8 +448,8 @@ def graph_parallel_block(ptg: part_lib.PartitionedTiledGraph, mesh: Mesh, *,
 
     ``use_kernel=True`` swaps each shard's local tile expansion from the
     jnp oracle to the Pallas kernels (`_local_expand`'s kernel leg);
-    ``interpret`` is forwarded to them (True = emulate off-TPU).  Both are
-    part of the compile cache key.
+    ``interpret`` is forwarded to them (`kernels.ops._interpret` decides
+    it).  Both are part of the compile cache key.
     """
     key = (mesh, data_axis, model_axis, num_colors, max_levels, diffusion,
            frontier, gather_capacity, use_kernel, interpret,
@@ -468,9 +469,7 @@ def graph_parallel_block(ptg: part_lib.PartitionedTiledGraph, mesh: Mesh, *,
 
 def _build_graph_parallel_block(ptg, mesh, *, data_axis, model_axis,
                                 num_colors, max_levels, diffusion, frontier,
-                                gather_capacity, use_kernel=False,
-                                interpret=True):
-    from repro.distributed.compat import shard_map
+                                gather_capacity, use_kernel, interpret):
 
     v, vp = ptg.num_vertices, ptg.padded_vertices
     rows, tile = ptg.rows_per_shard, ptg.tile_size
@@ -504,14 +503,14 @@ def _build_graph_parallel_block(ptg, mesh, *, data_axis, model_axis,
 
     out_specs = (P(data_axis, model_axis), P(data_axis))
     if diffusion == "lt":
-        fn = shard_map(
+        fn = jax.shard_map(
             block_body, mesh=mesh,
             in_specs=(tile_specs, P(model_axis), P(data_axis), P(data_axis)),
-            out_specs=out_specs, check=False)
+            out_specs=out_specs, check_vma=False)
     else:
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda ptg_l, st, sd: block_body(ptg_l, None, st, sd),
             mesh=mesh,
             in_specs=(tile_specs, P(data_axis), P(data_axis)),
-            out_specs=out_specs, check=False)
+            out_specs=out_specs, check_vma=False)
     return jax.jit(fn)

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import gzip
 import os
+import zlib
 
 import numpy as np
 
@@ -56,7 +57,10 @@ def table1_clone(name: str, scale: float = 1.0, prob=(0.0, 1.0),
             path = os.path.join(snap_dir, name + ext)
             if os.path.exists(path):
                 return load_snap(path, prob=prob, seed=seed)
-    v, e, deg = TABLE1[name]
+    v, e, _ = TABLE1[name]
     n = max(int(v * scale), 64)
-    return generators.powerlaw_cluster(n, deg, prob=prob,
-                                       seed=seed + hash(name) % 4096)
+    # Mean out-degree E/V (the table's avg degree counts in + out, 2E/V).
+    # Seeded by a stable digest of the name: Python's str hash is salted
+    # per process.
+    return generators.powerlaw_cluster(
+        n, e / v, prob=prob, seed=seed + zlib.crc32(name.encode()) % 4096)

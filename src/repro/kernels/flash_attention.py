@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
+from repro.kernels.common import checked_interpret
 
 _NEG_INF = -1e30
 
@@ -67,7 +67,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 @functools.partial(jax.jit, static_argnames=(
     "causal", "scale", "kv_offset", "block_q", "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal=True, scale=None, kv_offset=0,
-                    block_q=128, block_k=128, interpret=True):
+                    block_q=128, block_k=128, interpret: bool):
     """See module docstring. q: (Lq, H, D); k, v: (Lk, H, D)."""
     Lq, H, D = q.shape
     Lk = k.shape[0]
@@ -95,7 +95,7 @@ def flash_attention(q, k, v, *, causal=True, scale=None, kv_offset=0,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        interpret=interpret,
-        compiler_params=_CompilerParams(
+        interpret=checked_interpret(interpret),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(q, k, v)

@@ -1,7 +1,7 @@
 """Pallas TPU kernel: fused-BPT frontier expansion over block-sparse tiles.
 
 This is the compute hot-spot the paper optimizes (its GPU kernels in §4).
-TPU adaptation (DESIGN.md §2): one grid step processes one non-empty T×T
+TPU adaptation: one grid step processes one non-empty T×T
 adjacency tile entirely in VMEM —
 
     out[dst_blk] |= ( OR_i frontier[src_blk][i] & Bernoulli_word(edge ij) )
@@ -14,11 +14,13 @@ Bernoulli draws use the same counter hash as the pure-JAX paths, so the
 kernel is bit-for-bit equal to ``ref.fused_expand_ref`` and to the CSR
 edge-centric traversal.
 
-VMEM budget per grid step (T=128, W words):
+The draws are built one color lane at a time over the whole (T, T) tile
+(`rng.bernoulli_word_lanes`), so every transient is a lane-dense (T, T)
+array.  VMEM per grid step (T=128, W words):
     prob tile        128·128·4      =  64 KiB
     edge-id tile     128·128·4      =  64 KiB
     frontier/visited/out blocks     3·128·W·4
-    transient rand lanes 128·128·32·4 = 2 MiB      (dominates; fits 16 MiB)
+    transients       a few (T, T) u32 arrays of 64 KiB each
 """
 from __future__ import annotations
 
@@ -29,7 +31,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import expand_grid_params
+from repro.kernels.common import checked_interpret, expand_grid_params
 
 from repro.core import rng
 
@@ -59,9 +61,10 @@ def _expand_kernel(tile_src_ref, tile_dst_ref, first_ref, scalar_ref,
     fr = frontier_ref[...]                  # (T, W) u32, rows = src lanes
     vis = visited_ref[...]                  # (T, W) u32, rows = dst lanes
 
+    state = rng.edge_state(seed, level, eid)    # (T, T), shared by all words
     for w in range(num_words):              # static unroll over color words
         # Independent Bernoulli(p_e) per (edge, color lane): 32 hash lanes.
-        rand_w = rng.bernoulli_word(seed, level, eid, jnp.uint32(w), prob)
+        rand_w = rng.bernoulli_word_lanes(state, w, prob)
         x = fr[:, w][:, None] & rand_w      # (T, T): src lane i → dst lane j
         contrib = _or_reduce_rows(x)        # (T,) per-dst OR over sources
         out_ref[:, w] |= contrib & ~vis[:, w]
@@ -69,7 +72,7 @@ def _expand_kernel(tile_src_ref, tile_dst_ref, first_ref, scalar_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def fused_expand(tg_prob, tg_eid, tile_src, tile_dst, first_of_dst,
-                 frontier, visited, seed, level, *, interpret=True):
+                 frontier, visited, seed, level, *, interpret: bool):
     """One fused-BPT level on the tiled graph.  See module docstring.
 
     ``frontier`` is (Vf, W) and ``visited`` (Vo, W), both multiples of T.
@@ -100,7 +103,7 @@ def fused_expand(tg_prob, tg_eid, tile_src, tile_dst, first_of_dst,
         functools.partial(_expand_kernel, num_words=W),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Vp, W), jnp.uint32),
-        interpret=interpret,
+        interpret=checked_interpret(interpret),
         compiler_params=expand_grid_params(),      # sequential: accumulation
     )(tile_src, tile_dst, first_of_dst, scalars,
       tg_prob, tg_eid, frontier, visited)

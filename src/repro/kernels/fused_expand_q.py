@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import expand_grid_params
+from repro.kernels.common import checked_interpret, expand_grid_params
 
 from repro.core import rng
 
@@ -90,7 +90,7 @@ def _expand_q_kernel(tile_src_ref, tile_dst_ref, first_ref, scalar_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def fused_expand_q(q8_tiles, tile_src, tile_dst, first_of_dst,
-                   frontier, visited, seed, level, *, interpret=True):
+                   frontier, visited, seed, level, *, interpret: bool):
     """Quantized one-level expansion; same contract as fused_expand."""
     nt, T, _ = q8_tiles.shape
     _, W = frontier.shape
@@ -111,7 +111,7 @@ def fused_expand_q(q8_tiles, tile_src, tile_dst, first_of_dst,
         functools.partial(_expand_q_kernel, num_words=W, tile_size=T),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Vp, W), jnp.uint32),
-        interpret=interpret,
+        interpret=checked_interpret(interpret),
         compiler_params=expand_grid_params(),
     )(tile_src, tile_dst, first_of_dst, scalars,
       q8_tiles, frontier, visited)
@@ -155,7 +155,7 @@ def _expand_q_gathered_kernel(ids_ref, tile_src_ref, tile_dst_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def fused_expand_q_gathered(q8_gathered, tile_ids, tile_src, tile_dst,
                             first_of_dst, frontier, visited, seed, level,
-                            *, interpret=True):
+                            *, interpret: bool):
     """Sparse-grid variant of `fused_expand_q`: the grid iterates a
     compacted (dst-sorted, null-padded) tile list; ``tile_ids`` carries
     each slot's ORIGINAL tile id so the position-derived RNG counters
@@ -181,7 +181,7 @@ def fused_expand_q_gathered(q8_gathered, tile_ids, tile_src, tile_dst,
                           tile_size=T),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Vp, W), jnp.uint32),
-        interpret=interpret,
+        interpret=checked_interpret(interpret),
         compiler_params=expand_grid_params(),
     )(tile_ids, tile_src, tile_dst, first_of_dst, scalars,
       q8_gathered, frontier, visited)

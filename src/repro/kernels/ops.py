@@ -1,9 +1,11 @@
 """Public jit'd wrappers over the Pallas kernels.
 
-``interpret`` auto-detection: kernels run compiled on TPU backends and in
-interpret mode (Python evaluation of the kernel body) everywhere else — this
-container is CPU-only, so tests/benches exercise interpret mode while the
-BlockSpecs/grids target real TPU lowering.
+``_interpret`` is the one place that picks the Pallas execution mode:
+kernels run compiled on a TPU backend and in interpret mode (Python
+evaluation of the kernel body) everywhere else, so CPU tests exercise the
+kernel bodies while the BlockSpecs/grids target the TPU's lowering.  Every
+caller of a kernel passes ``interpret=_interpret()``; a kernel refuses
+interpret mode on a TPU (`kernels.common.checked_interpret`).
 """
 from __future__ import annotations
 
@@ -17,6 +19,7 @@ from repro.kernels import flash_attention as _flash
 
 
 def _interpret() -> bool:
+    """True exactly where no TPU backend runs the kernels."""
     return jax.default_backend() != "tpu"
 
 
@@ -29,13 +32,8 @@ def fused_expand(tg: tiles_lib.TiledGraph, frontier, visited, seed, level):
 
 
 def cover_counts(visited, active):
-    """Marginal-gain counts for greedy max-k-cover (rows padded to 128)."""
-    Vp = visited.shape[0]
-    pad = (-Vp) % 128
-    if pad:
-        visited = jnp.pad(visited, ((0, pad), (0, 0)))
-    out = _coverage.cover_counts(visited, active, interpret=_interpret())
-    return out[:Vp] if pad else out
+    """Marginal-gain counts for greedy max-k-cover: (V, W) × (W,) → (V,)."""
+    return _coverage.cover_counts(visited, active, interpret=_interpret())
 
 
 def cover_counts_batched(visited, active):

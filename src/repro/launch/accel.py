@@ -1,41 +1,31 @@
-"""Accelerator runtime configuration — ONE place that sets the XLA flags
-and platform knobs every launcher and benchmark needs, before jax's backend
-materializes.
+"""Accelerator runtime configuration — the ONE place that sets the XLA
+flags and JAX settings every launcher and benchmark needs, before jax's
+backend materializes.
 
 Two concerns live here:
 
-* **Latency hiding.**  The paper's fused traversal interleaves per-level
-  collectives (frontier all-gather / butterfly exchange over the model
-  axis) with tile-kernel compute; on GPU the win depends on XLA scheduling
-  the collectives asynchronously so the NCCL ring overlaps the next tile
-  batch.  `gpu_latency_hiding_flags` is that flag set, applied idempotently
-  by `configure` whenever the target platform is (or may be) GPU.
+* **Host-device shims.**  CI and ``--smoke`` runs exercise the mesh
+  backends on forced host CPU devices
+  (``--xla_force_host_platform_device_count``).  That happens only where
+  the run explicitly asked for the CPU (``JAX_PLATFORMS=cpu``): anywhere
+  else the mesh is built from the real devices, so a multi-chip path never
+  runs on fake CPU devices by accident.
+* **Persistent compilation cache.**  Where ``JAX_COMPILATION_CACHE_DIR`` is
+  set, JAX reads it itself and nothing here overrides it.  Otherwise the
+  cache goes to ``<checkout>/.jax_cache`` — a fixed path, because the path
+  is part of the cache key.
 
-* **Host-device shims.**  CI and `--smoke` runs exercise the mesh backends
-  on forced host CPU devices (``--xla_force_host_platform_device_count``).
-  `set_host_device_count` owns that dance — including the "explicit
-  accelerator request wins" opt-out — so `serve_influence`, the bench
-  workers and the test-suite all force devices the same way.
-
-Everything here mutates **environment variables only** and must therefore
-run before the first jax device query or op (module imports are safe — the
-backend materializes lazily).  Calls after backend init are not an error,
-but they only affect subsequently spawned workers; `configure` returns the
-flags it applied so callers can log/propagate them to subprocesses.
+Everything here must run before the first jax device query or op (module
+imports are safe — the backend materializes lazily).  ``LIBTPU_INIT_ARGS``
+is never touched.
 """
 from __future__ import annotations
 
 import os
+import pathlib
 
-# XLA flags that let GPU runs overlap the per-level model-axis collectives
-# with tile-kernel compute: the latency-hiding scheduler reorders around
-# async collective start/done pairs, and the dedicated high-priority stream
-# keeps small frontier exchanges from queueing behind large tile matmuls.
-GPU_LATENCY_HIDING_FLAGS = (
-    "--xla_gpu_enable_latency_hiding_scheduler=true",
-    "--xla_gpu_enable_async_collectives=true",
-    "--xla_gpu_enable_highest_priority_async_stream=true",
-)
+# <checkout>/src/repro/launch/accel.py → <checkout>/.jax_cache
+DEFAULT_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
 def _flag_name(flag: str) -> str:
@@ -56,40 +46,40 @@ def append_xla_flags(flags) -> list[str]:
 def set_host_device_count(n: int) -> bool:
     """Force ``n`` host CPU devices (the multi-device smoke/CI trick).
 
-    No-op — returning False — when ``n <= 1`` or the user explicitly
-    requested a real accelerator via ``JAX_PLATFORMS``; production runs
-    never call this with a real backend selected.  Must run before the jax
-    backend materializes (first device query), like everything here.
+    Only where ``JAX_PLATFORMS`` is explicitly ``cpu``; otherwise — and
+    when ``n <= 1`` — a no-op returning False.  Never sets
+    ``JAX_PLATFORMS`` itself.  Must run before the jax backend materializes
+    (first device query), like everything here.
     """
-    if n <= 1 or os.environ.get("JAX_PLATFORMS", "cpu") not in ("", "cpu"):
+    if n <= 1 or os.environ.get("JAX_PLATFORMS") != "cpu":
         return False
-    os.environ["JAX_PLATFORMS"] = "cpu"
     return bool(append_xla_flags(
         [f"--xla_force_host_platform_device_count={n}"]))
 
 
-def configure(*, host_devices: int = 1, gpu: bool | None = None) -> dict:
+def configure_compilation_cache() -> str:
+    """Point JAX's persistent compilation cache at a fixed directory
+    unless ``JAX_COMPILATION_CACHE_DIR`` already names one.  Returns the
+    directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    jax.config.update("jax_compilation_cache_dir", str(DEFAULT_CACHE_DIR))
+    return str(DEFAULT_CACHE_DIR)
+
+
+def configure(*, host_devices: int = 1) -> dict:
     """Apply the standard accelerator configuration.
 
-    ``host_devices > 1`` forces that many host CPU devices (smoke/CI
-    meshes).  ``gpu=None`` auto-detects from ``JAX_PLATFORMS`` (the GPU
-    latency-hiding flags are applied when a cuda/rocm platform is
-    requested, or when nothing is requested — they are inert on CPU/TPU
-    backends, so applying them eagerly costs nothing and covers the
-    "launched bare on a GPU box" case); ``gpu=False`` skips them,
-    ``gpu=True`` forces them.
-
-    Returns ``{"xla_flags_added": [...], "host_devices_forced": bool}`` for
-    launcher logs and worker-env propagation.
+    ``host_devices > 1`` forces that many host CPU devices under
+    ``JAX_PLATFORMS=cpu`` (smoke/CI meshes).  Returns
+    ``{"xla_flags_added": [...], "host_devices_forced": bool,
+    "compilation_cache_dir": str}`` for launcher logs and worker-env
+    propagation.
     """
-    platforms = os.environ.get("JAX_PLATFORMS", "")
-    if gpu is None:
-        gpu = platforms in ("",) or any(
-            p in platforms for p in ("cuda", "rocm", "gpu"))
-    added: list[str] = []
-    if gpu:
-        added += append_xla_flags(GPU_LATENCY_HIDING_FLAGS)
     forced = set_host_device_count(host_devices)
-    if forced:
-        added.append(f"--xla_force_host_platform_device_count={host_devices}")
-    return {"xla_flags_added": added, "host_devices_forced": forced}
+    added = ([f"--xla_force_host_platform_device_count={host_devices}"]
+             if forced else [])
+    return {"xla_flags_added": added, "host_devices_forced": forced,
+            "compilation_cache_dir": configure_compilation_cache()}

@@ -1,5 +1,7 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+    os.environ.get("XLA_FLAGS", ""),
+    "--xla_force_host_platform_device_count=512"]))
 # ^^ MUST precede every other import: jax pins the device count at first
 # init.  Only the dry-run gets 512 placeholder devices; tests/benches see 1.
 
@@ -52,7 +54,7 @@ TRAIN_MICROBATCHES = {"train_4k": 8}
 def _cell_skip_reason(cfg, shape_name: str):
     if shape_name == "long_500k" and cfg.family not in LONG_CONTEXT_FAMILIES:
         return ("full-attention arch: 512K decode requires sub-quadratic "
-                "sequence mixing (DESIGN.md §5)")
+                "sequence mixing")
     return None
 
 
@@ -120,9 +122,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
         t_compile = time.time() - t0
 
         mem = compiled.memory_analysis()
-        xla_cost = compiled.cost_analysis()
-        if isinstance(xla_cost, (list, tuple)):    # jax 0.4.x: per-program list
-            xla_cost = xla_cost[0] if xla_cost else {}
+        xla_cost = compiled.cost_analysis() or {}
         text = compiled.as_text()
         cost = hlo_analysis.full_cost(text)      # loop-weighted (exact for
         # scans; XLA's cost_analysis counts while bodies once — see module)
@@ -180,7 +180,7 @@ def roofline_terms(cfg, shape, flops_dev, bytes_dev, coll_dev, chips):
 
 # ------------------------------------------------------------- BPT workloads
 def lower_bpt_cell(which: str, *, multi_pod: bool) -> dict:
-    """The paper's own workload on the production mesh (DESIGN.md §3)."""
+    """The paper's own workload on the production mesh."""
     mesh = make_production_mesh(multi_pod=multi_pod)
     chips = int(np.prod(list(mesh.shape.values())))
     record = {"arch": f"fused-bpt-{which}", "shape": which,
@@ -280,12 +280,11 @@ def lower_bpt_cell(which: str, *, multi_pod: bool) -> dict:
                         (fr_local, jnp.zeros_like(fr_local), jnp.int32(0)))
                     return vis | fr, lvl
 
-                from repro.distributed.compat import shard_map
-                fn = shard_map(
+                fn = jax.shard_map(
                     body, mesh=mesh,
                     in_specs=(P("model"), P("model"), P("model"),
                               P("model")),
-                    out_specs=(P("model"), P()), check=False)
+                    out_specs=(P("model"), P()), check_vma=False)
 
                 def run(q8, ts, td, starts):
                     fr = tiles_lib.pad_mask_rows(

@@ -27,10 +27,10 @@ max-cover kernel and the pool reproduces the pool-less seeds exactly.
 
 ``--mesh DxM`` serves from a mesh-sharded pool through the distributed
 engine (slots sharded over the ``data`` axis, one psum per coverage
-reduction).  With ``--smoke`` the launcher forces that many host CPU
-devices — the same trick the multi-device equivalence tests use — so the
-full distributed path smokes on a laptop (explicit ``JAX_PLATFORMS=tpu``
-etc. opts out; without ``--smoke``, real devices are required).
+reduction).  Under ``JAX_PLATFORMS=cpu`` the launcher forces that many
+host CPU devices — the same trick the multi-device equivalence tests use —
+so the full distributed path smokes on a laptop; anywhere else the mesh is
+built from the real devices, and too few of them is an error.
 ``--async`` fronts the batcher with the deadline-batched `AsyncFrontEnd`
 and drives it from concurrent client threads.
 """
@@ -47,6 +47,7 @@ import numpy as np
 
 from repro.core import imm
 from repro.graph import csr, generators
+from repro.launch.mesh import make_mesh
 from repro.sampling import SamplerSpec
 from repro.serve.influence import (MicroBatcher, PoolConfig, QueryEngine,
                                    ResultCache, SketchStore)
@@ -58,20 +59,6 @@ def _parse_mesh(spec: str) -> tuple[int, int]:
     except ValueError:
         raise SystemExit(f"--mesh wants DxM (e.g. 8x1), got {spec!r}")
     return d, m
-
-
-def _force_cpu_host_devices(n: int) -> None:
-    """``--smoke --mesh``: run the distributed path on ``n`` forced host
-    CPU devices (the multi-device test-suite trick), whatever the host has.
-
-    Delegates to `repro.launch.accel` (the one owner of XLA-env mutation);
-    must run before jax initializes its backend (imports above don't — the
-    backend materializes on the first device query/op).  An explicit
-    accelerator request (``JAX_PLATFORMS=tpu``/``cuda``...) opts out;
-    production runs don't pass ``--smoke`` and use real devices.
-    """
-    from repro.launch import accel
-    accel.set_host_device_count(n)
 
 
 def build_graph(args):
@@ -233,8 +220,8 @@ def run_distributed(args, shape: tuple[int, int]) -> None:
     if backend == "graph_parallel" and m < 2:
         raise SystemExit("--sampler-backend graph_parallel wants a model "
                          f"axis: use --mesh DxM with M>1 (got {d}x{m})")
-    mesh = jax.make_mesh((d, m), ("data", "model")) if m > 1 else \
-        jax.make_mesh((d,), ("data",))
+    mesh = make_mesh((d, m), ("data", "model")) if m > 1 else \
+        make_mesh((d,), ("data",))
     g = build_graph(args)
     cfg = build_config(args, backend=backend)
     store = ShardedSketchStore(g, cfg, mesh)
@@ -300,7 +287,7 @@ def run_distributed(args, shape: tuple[int, int]) -> None:
     ckpt = args.ckpt_dir or tempfile.mkdtemp(prefix="sharded_pool_")
     store.save(ckpt)
     d2 = max(d // 2, 1)
-    mesh2 = jax.make_mesh((d2, (d * m) // d2), ("data", "model"))
+    mesh2 = make_mesh((d2, (d * m) // d2), ("data", "model"))
     restored = ShardedSketchStore.restore(ckpt, g, cfg, mesh2)
     r_seeds, r_sig = DistributedQueryEngine(restored).top_k(args.k)
     assert np.array_equal(s8, r_seeds) and sig8 == r_sig
@@ -466,7 +453,7 @@ def run_stream(args, shape: tuple[int, int] | None = None) -> None:
         if m != 1:
             raise SystemExit("--stream-smoke --mesh wants Dx1 (deltas on "
                              "graph_parallel pools arrive later)")
-        mesh = jax.make_mesh((d,), ("data",))
+        mesh = make_mesh((d,), ("data",))
         g = build_graph(args)
         cfg = build_config(args, backend="data_parallel")
         store = ShardedSketchStore(g, cfg, mesh)
@@ -621,13 +608,13 @@ def _async_demo(args, engine) -> None:
           f"(deadline {args.deadline * 1e3:.0f} ms)")
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="full lifecycle check on a synthetic graph")
     ap.add_argument("--mesh", default=None, metavar="DxM",
                     help="serve from a sharded pool on a DxM mesh "
-                         "(forces host devices for CPU smoke)")
+                         "(forced host devices under JAX_PLATFORMS=cpu)")
     ap.add_argument("--async", dest="async_frontend", action="store_true",
                     help="front the batcher with the deadline-batched "
                          "AsyncFrontEnd and drive it from client threads")
@@ -689,18 +676,15 @@ def main():
     ap.add_argument("--queries", type=int, default=6)
     ap.add_argument("--ckpt-dir", default=None,
                     help="pool snapshot directory (default: temp dir)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
-    # Standard accelerator config (GPU latency-hiding flags; inert on
-    # CPU/TPU) before any jax backend materializes — the smoke paths below
-    # additionally force host devices through the same module.
+    # Standard accelerator config (host devices under JAX_PLATFORMS=cpu,
+    # compilation cache) before any jax backend materializes.
     from repro.launch import accel
-    accel.configure()
+    shape = _parse_mesh(args.mesh) if args.mesh else None
+    accel.configure(host_devices=shape[0] * shape[1] if shape else 1)
 
     if args.stream_smoke:
-        shape = _parse_mesh(args.mesh) if args.mesh else None
-        if shape is not None:
-            _force_cpu_host_devices(shape[0] * shape[1])
         run_stream(args, shape)
     elif args.tier:
         if args.mesh:
@@ -711,9 +695,6 @@ def main():
                              "quota-starved one)")
         run_tier(args)
     elif args.mesh:
-        shape = _parse_mesh(args.mesh)
-        if args.smoke:
-            _force_cpu_host_devices(shape[0] * shape[1])
         run_distributed(args, shape)
     else:
         run_single(args)

@@ -15,6 +15,7 @@ import numpy as np
 
 from repro.configs import registry
 from repro.distributed import sharding_rules as rules
+from repro.launch.mesh import make_mesh
 from repro.models.config import SHAPES
 from repro.train import loop
 
@@ -22,11 +23,11 @@ from repro.train import loop
 def parse_mesh(spec: str | None):
     if spec is None:
         n = len(jax.devices())
-        return jax.make_mesh((n,), ("data",))
+        return make_mesh((n,), ("data",))
     dims = tuple(int(x) for x in spec.split("x"))
     axes = {1: ("data",), 2: ("data", "model"),
             3: ("pod", "data", "model")}[len(dims)]
-    return jax.make_mesh(dims, axes)
+    return make_mesh(dims, axes)
 
 
 def main():

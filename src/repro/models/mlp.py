@@ -163,7 +163,6 @@ def _moe_forward_a2a(p, x, cfg: ModelConfig, mesh):
     dispatch: local (E, capₗ, D) buffers → all_to_all(model) → each shard
     holds (E/S, S·capₗ, D) for ITS experts; combine is the transpose.
     """
-    from repro.distributed.compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     b, L, d = x.shape
@@ -230,9 +229,9 @@ def _moe_forward_a2a(p, x, cfg: ModelConfig, mesh):
         return out.reshape(bl, ll, d), aux
 
     dp_spec = dp if len(dp) > 1 else (dp[0] if dp else None)
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(dp_spec, "model", None), w_specs),
-                   out_specs=(P(dp_spec, "model", None), P()),
-                   check=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(dp_spec, "model", None), w_specs),
+                       out_specs=(P(dp_spec, "model", None), P()),
+                       check_vma=False)
     out, aux = fn(x, weights)
     return out.astype(x.dtype), aux

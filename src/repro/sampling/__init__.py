@@ -2,6 +2,7 @@
 (diffusion × backend) combination.
 
     from repro import sampling
+    from repro.launch.mesh import make_mesh
 
     spec    = sampling.SamplerSpec(diffusion="ic", backend="data_parallel",
                                    num_colors=64, master_seed=3)
@@ -12,12 +13,12 @@
     # graphs bigger than one device: rows over "model", batches over "data"
     gp = sampling.make_sampler(
         graph, spec.replace(backend="graph_parallel"),
-        mesh=jax.make_mesh((4, 2), ("data", "model")))
+        mesh=make_mesh((4, 2), ("data", "model")))
 
 Every pool consumer (``core.rrr.sample_collection``, ``core.imm.run_imm``,
 ``serve.influence.SketchStore``, ``serve.distributed.ShardedSketchStore``,
 ``core.driver.SamplingDriver``) routes RRR sampling through here; the
-low-level ``rrr.sample_batch`` primitive is private to this package (CI
+per-batch ``rrr.sample_batch`` reference is private to this package (CI
 grep guard).  The cross-backend contract: a given ``(master_seed,
 batch_index)`` yields bit-identical visited masks on every backend that
 supports the diffusion.

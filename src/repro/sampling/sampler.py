@@ -86,6 +86,17 @@ class Sampler:
     def batch_seed(self, batch_index: int) -> jnp.ndarray:
         return rrr.batch_seed(self.spec.master_seed, batch_index)
 
+    def _dst_segments(self):
+        """`traversal.dst_segments` of the reversed graph, built once per
+        sampler (a function of the edge destinations alone, so it also
+        survives values-only rebinds) and handed to every dense-path
+        program — which then contains no sort."""
+        if getattr(self, "_segments", None) is None:
+            from repro.core import traversal
+            self._segments = traversal.dst_segments(self.g_rev.dst,
+                                                    self.g_rev.num_vertices)
+        return self._segments
+
     # ------------------------------------------------------- sampling
     def sample(self, batch_index: int) -> rrr.RRRBatch:
         raise NotImplementedError
@@ -232,14 +243,11 @@ class DenseSampler(Sampler):
                 res.visited, np.asarray(starts), int(batch_index),
                 int(res.stats.fused_edge_visits.sum()),
                 int(res.stats.unfused_edge_visits.sum()))
-        return rrr.sample_batch(
-            self.g_rev, self.spec.num_colors, self.spec.master_seed,
-            int(batch_index), sort_starts=self.spec.sort_starts,
-            max_levels=self.spec.max_iters, model=self.spec.diffusion)
+        return self.sample_many([batch_index])[0]
 
     def sample_many(self, batch_indices) -> list[rrr.RRRBatch]:
         idx = [int(b) for b in batch_indices]
-        if len(idx) <= 1:
+        if not idx or (len(idx) == 1 and self.spec.frontier == "sparse"):
             return [self.sample(b) for b in idx]
         starts = jnp.stack([self.batch_starts(b) for b in idx])
         seeds = jnp.asarray(rrr.batch_seeds(self.spec.master_seed, idx))
@@ -253,13 +261,14 @@ class DenseSampler(Sampler):
         elif spec.diffusion == "lt":
             vis = lt.run_fused_lt_block(self.g_rev, self._lt_cb(), starts,
                                         seeds, spec.num_colors,
-                                        max_levels=spec.max_iters)
+                                        max_levels=spec.max_iters,
+                                        segments=self._dst_segments())
             fused = unfused = np.full(len(idx), -1)
         else:
             from repro.core import traversal
             vis, fused, unfused = traversal.run_fused_block(
                 self.g_rev, starts, seeds, spec.num_colors,
-                max_levels=spec.max_iters)
+                max_levels=spec.max_iters, segments=self._dst_segments())
         roots = np.asarray(starts)
         return [rrr.RRRBatch(vis[i], roots[i], b, int(fused[i]),
                              int(unfused[i]))
@@ -318,18 +327,18 @@ class TiledSampler(Sampler):
         spec = self.spec
         starts = self.batch_starts(batch_index)
         seed = self.batch_seed(batch_index)
-        ladder = self._ladder if spec.frontier == "sparse" else None
-        use_kernel = (spec.backend == "kernel")
+        from repro.kernels import ops
+        kw = dict(max_levels=spec.max_iters,
+                  use_kernel=(spec.backend == "kernel"),
+                  interpret=ops._interpret(), frontier=spec.frontier,
+                  ladder=self._ladder if spec.frontier == "sparse" else None)
         if spec.diffusion == "lt":
             visited, levels, gs = tiled_traversal.run_fused_lt_tiled(
                 self.tg_rev, self._cb_tiles, starts, spec.num_colors,
-                seed, max_levels=spec.max_iters, use_kernel=use_kernel,
-                frontier=spec.frontier, ladder=ladder)
+                seed, **kw)
         else:
             visited, levels, gs = tiled_traversal.run_fused_tiled(
-                self.tg_rev, starts, spec.num_colors, seed,
-                max_levels=spec.max_iters, use_kernel=use_kernel,
-                frontier=spec.frontier, ladder=ladder)
+                self.tg_rev, starts, spec.num_colors, seed, **kw)
         self.last_levels = int(levels)
         self.last_grid_steps = int(gs)
         return rrr.RRRBatch(visited, np.asarray(starts),
@@ -391,7 +400,6 @@ def _data_parallel_block_fn(mesh, axis: str, spec: SamplerSpec, ladder):
     if fn is None:
         from jax.sharding import PartitionSpec as P
 
-        from repro.distributed.compat import shard_map
         from repro.distributed.traversal import run_batch
 
         def one(data, starts, seed):
@@ -410,15 +418,15 @@ def _data_parallel_block_fn(mesh, axis: str, spec: SamplerSpec, ladder):
                     fidx, starts, spec.num_colors, seed,
                     max_levels=spec.max_iters, ladder=ladder).visited
             if spec.diffusion == "lt":
-                g, cb = data
+                g, cb, segs = data
                 sel = lt.selection_mask_from_cb(g, cb, spec.num_colors,
                                                 seed)
                 return lt.lt_traversal_program(g, sel, starts,
                                                spec.num_colors,
-                                               spec.max_iters)
-            (g,) = data
+                                               spec.max_iters, segs)
+            g, segs = data
             return run_batch(g, starts, seed, spec.num_colors,
-                             max_levels=spec.max_iters)
+                             max_levels=spec.max_iters, segments=segs)
 
         def body(data, starts_local, seeds_local):
             # Sequential over the shard's local slice: one (V, W)
@@ -426,9 +434,9 @@ def _data_parallel_block_fn(mesh, axis: str, spec: SamplerSpec, ladder):
             return jax.lax.map(lambda a: one(data, *a),
                                (starts_local, seeds_local))
 
-        fn = jax.jit(shard_map(body, mesh,
-                               in_specs=(P(), P(axis), P(axis)),
-                               out_specs=P(axis)))
+        fn = jax.jit(jax.shard_map(body, mesh=mesh,
+                                   in_specs=(P(), P(axis), P(axis)),
+                                   out_specs=P(axis), check_vma=False))
         _DP_BLOCK_FNS[key] = fn
     return fn
 
@@ -473,8 +481,8 @@ class DataParallelSampler(_BlockSampler):
         if self.spec.frontier == "sparse":
             return (self._fidx,)
         if self.spec.diffusion == "lt":
-            return (self.g_rev, self._cb)
-        return (self.g_rev,)
+            return (self.g_rev, self._cb, self._dst_segments())
+        return (self.g_rev, self._dst_segments())
 
     def _block(self, idx: list[int]):
         """(visited, roots) for one padded block: visited (B, V, W) sharded
@@ -509,14 +517,16 @@ class DataParallelSampler(_BlockSampler):
 
 
 def _gp_use_kernel() -> bool:
-    """Env knob: ``REPRO_GP_KERNEL=1`` routes the graph_parallel backend's
-    per-shard tile expansion through the Pallas kernels instead of the jnp
-    oracle.  An env var rather than a `SamplerSpec` field because it does
-    not change a single output bit — it selects an execution engine for the
-    same partitioned layout, like ``interpret`` — so specs embedded in pool
-    manifests stay portable across machines with and without kernel
-    support."""
-    return os.environ.get("REPRO_GP_KERNEL", "0") == "1"
+    """Whether the graph_parallel backend's per-shard tile expansion runs
+    the Pallas kernels (the default on a TPU backend) or the jnp oracle
+    (the default elsewhere, where the kernels would run interpreted).
+    ``REPRO_GP_KERNEL=1``/``0`` overrides.  An env var rather than a
+    `SamplerSpec` field because it does not change a single output bit —
+    it selects an execution engine for the same partitioned layout, like
+    ``interpret`` — so specs embedded in pool manifests stay portable
+    across machines with and without kernel support."""
+    default = "1" if jax.default_backend() == "tpu" else "0"
+    return os.environ.get("REPRO_GP_KERNEL", default) == "1"
 
 
 class GraphParallelSampler(_BlockSampler):

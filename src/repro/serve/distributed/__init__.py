@@ -14,7 +14,7 @@ Layers over `repro.serve.influence` (which stays the single-device path):
   slot OR oldest-request deadline, background epoch refresh serialized
   with dispatch.
 
-    mesh   = jax.make_mesh((8,), ("data",))
+    mesh   = repro.launch.mesh.make_mesh((8,), ("data",))
     store  = ShardedSketchStore(graph, PoolConfig(num_colors=64), mesh)
     store.ensure(16)
     fe = AsyncFrontEnd(MicroBatcher(DistributedQueryEngine(store),

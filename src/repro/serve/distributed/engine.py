@@ -6,7 +6,7 @@ pool's slot dim is sharded over a mesh axis and every program runs under
 ``shard_map``:
 
 * each device reduces coverage over **its local batches** with the shared
-  count programs (`kernels.ops.cover_counts` / the popcount fallback);
+  count programs (`kernels.ops.cover_counts` / jnp popcounts);
 * **one ``lax.psum``** merges the per-shard partial counts — the ButterFly
   BFS lesson: engineer exactly one deliberate collective per reduction;
 * greedy selection (`core.imm.greedy_extend_program`) argmaxes on the
@@ -29,10 +29,9 @@ All reductions are integer, so the N-shard answer is **bit-identical** to
 the 1-device `QueryEngine` on the same pool — asserted by
 ``tests/serve_distributed_check.py`` (including D×M row-sharded meshes).
 
-``use_kernel`` defaults to the popcount fallback here: the Pallas coverage
-kernel targets TPU lowering and both paths produce identical integer
-counts (asserted by the kernel tests), so on CPU meshes the fallback is
-the conservative choice; pass ``use_kernel=True`` on TPU pods.
+Gain counts go through the Pallas coverage kernel, as in `QueryEngine`
+(compiled on a TPU, interpreted elsewhere); ``use_kernel=False`` selects
+the jnp popcounts, which give identical integer counts.
 """
 from __future__ import annotations
 
@@ -44,7 +43,6 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import imm
-from repro.distributed import compat
 from repro.serve.distributed import sharded_store as store_lib
 from repro.serve.influence import engine as engine_lib
 
@@ -54,7 +52,7 @@ class DistributedQueryEngine:
 
     def __init__(self, store: store_lib.ShardedSketchStore, *,
                  query_slots: int = 8, max_seeds: int = 8,
-                 use_kernel: bool = False):
+                 use_kernel: bool = True):
         self.store = store
         self.query_slots = query_slots
         self.max_seeds = max_seeds
@@ -162,10 +160,10 @@ class DistributedQueryEngine:
 
                 in_vis = P(axis)
 
-            fn = jax.jit(compat.shard_map(
-                body, self.store.mesh,
+            fn = jax.jit(jax.shard_map(
+                body, mesh=self.store.mesh,
                 in_specs=(in_vis, P(axis)),
-                out_specs=(P(), P(axis), P())))
+                out_specs=(P(), P(axis), P()), check_vma=False))
             self._greedy_fns[k] = fn
         return fn
 
@@ -193,9 +191,10 @@ class DistributedQueryEngine:
 
                 in_vis = P(axis)
 
-            self._sigma_fn = jax.jit(compat.shard_map(
-                body, self.store.mesh,
-                in_specs=(in_vis, P(), P()), out_specs=P()))
+            self._sigma_fn = jax.jit(jax.shard_map(
+                body, mesh=self.store.mesh,
+                in_specs=(in_vis, P(), P()), out_specs=P(),
+                check_vma=False))
         return self._sigma_fn
 
     def _marginal(self):
@@ -222,9 +221,10 @@ class DistributedQueryEngine:
 
                 in_vis = P(axis)
 
-            self._marginal_fn = jax.jit(compat.shard_map(
-                body, self.store.mesh,
-                in_specs=(in_vis, P(), P()), out_specs=P()))
+            self._marginal_fn = jax.jit(jax.shard_map(
+                body, mesh=self.store.mesh,
+                in_specs=(in_vis, P(), P()), out_specs=P(),
+                check_vma=False))
         return self._marginal_fn
 
     # -------------------------------------------------------------- top-k

@@ -57,12 +57,11 @@ def make_dp_train_step(cfg: ModelConfig, lr_fn, mesh, axis: str = "data",
 
     rep = P()
     err_spec = jax.tree.map(lambda _: P(axis), _err_structure(cfg))
-    from repro.distributed.compat import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(rep, rep, err_spec, P(axis)),
         out_specs=(rep, rep, err_spec, rep),
-        check=False)
+        check_vma=False)
 
     def init_residual(params):
         return jax.tree.map(

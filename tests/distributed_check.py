@@ -13,6 +13,7 @@ import jax.numpy as jnp     # noqa: E402
 from repro.core import imm, rrr, tiles, traversal          # noqa: E402
 from repro.distributed import traversal as dtrav           # noqa: E402
 from repro.graph import csr, generators, partition         # noqa: E402
+from repro.launch.mesh import make_mesh                    # noqa: E402
 
 
 def main():
@@ -20,7 +21,7 @@ def main():
     g = generators.powerlaw_cluster(500, 8.0, prob=0.3, seed=2)
 
     # ---- sample parallel ≡ per-batch single-device -------------------------
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     B, C = 16, 64
     starts = jnp.stack([
         traversal.random_starts(jax.random.key(b), g.num_vertices, C)
@@ -42,7 +43,7 @@ def main():
     print("OK distributed_greedy")
 
     # ---- graph parallel ≡ single-device (coupled RNG) ----------------------
-    mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+    mesh2 = make_mesh((2, 4), ("data", "model"))
     g2 = csr.dedupe(g)
     tg = tiles.from_graph(g2)
     ptg = partition.partition(tg, num_shards=4)
@@ -55,7 +56,7 @@ def main():
     print("OK graph_parallel")
 
     # ---- graph parallel on a mesh slice with pod axis ----------------------
-    mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh3 = make_mesh((2, 2, 2), ("pod", "data", "model"))
     ptg2 = partition.partition(tg, num_shards=2)
     vis_gp2, _ = dtrav.graph_parallel_traversal(ptg2, st, C, 17, mesh3)
     np.testing.assert_array_equal(np.asarray(vis_gp2),

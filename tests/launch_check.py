@@ -13,11 +13,12 @@ import numpy as np      # noqa: E402
 from repro.configs import registry                      # noqa: E402
 from repro.distributed import sharding_rules as rules   # noqa: E402
 from repro.launch import dryrun, specs                  # noqa: E402
+from repro.launch.mesh import make_mesh                 # noqa: E402
 from repro.models.config import ShapeConfig             # noqa: E402
 
 
 def main():
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     shapes = {
         "train": ShapeConfig("t", "train", 64, 8),
         "prefill": ShapeConfig("p", "prefill", 64, 4),

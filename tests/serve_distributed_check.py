@@ -21,6 +21,7 @@ import jax                      # noqa: E402
 
 from repro import sampling                                  # noqa: E402
 from repro.graph import generators                          # noqa: E402
+from repro.launch.mesh import make_mesh                     # noqa: E402
 from repro.serve.influence import (MicroBatcher, PoolConfig,    # noqa: E402
                                    QueryEngine, ResultCache, SketchStore)
 from repro.serve.distributed import (AsyncFrontEnd,             # noqa: E402
@@ -42,7 +43,7 @@ def main():
     # ---- per-slot bit identity: mesh only decides placement ---------------
     single = SketchStore(g, cfg)
     single.ensure(8)
-    mesh8 = jax.make_mesh((8,), ("data",))
+    mesh8 = make_mesh((8,), ("data",))
     sharded = ShardedSketchStore(g, cfg, mesh8)
     sharded.ensure(8)
     assert sharded.num_shards == 8
@@ -92,7 +93,7 @@ def main():
         extra = ShardedSketchStore.saved_layout(d)
         assert extra["num_shards"] == 8
         assert extra["shard_layout"] == list(range(8))
-        mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+        mesh2 = make_mesh((2, 4), ("data", "model"))
         r2 = ShardedSketchStore.restore(d, g, cfg, mesh2)
         assert r2.num_shards == 2 and r2.shard_layout() == [0] * 4 + [1] * 4
         s2, sig2 = DistributedQueryEngine(r2).top_k(4)
@@ -209,7 +210,7 @@ def main():
                                                     master_seed=3)))
         dense_ref.ensure(6)
         for d, m in ((2, 4), (4, 2)):
-            mesh_dm = jax.make_mesh((d, m), ("data", "model"))
+            mesh_dm = make_mesh((d, m), ("data", "model"))
             gp_cfg = PoolConfig(
                 max_batches=32,
                 spec=sampling.SamplerSpec(diffusion=diffusion,
@@ -238,7 +239,7 @@ def main():
     # engine, never an answer change.
     os.environ["REPRO_GP_KERNEL"] = "1"
     try:
-        mesh_22 = jax.make_mesh((2, 2), ("data", "model"))
+        mesh_22 = make_mesh((2, 2), ("data", "model"))
         for diffusion in ("ic", "lt"):
             ref_k = SketchStore(
                 g2, PoolConfig(max_batches=32,
@@ -291,7 +292,7 @@ def main():
             assert "model" in str(e)
         # a DIFFERENT (data × model) layout restores fine — elastic slot
         # re-sharding + fresh row partition for future refreshes
-        mesh_24 = jax.make_mesh((2, 4), ("data", "model"))
+        mesh_24 = make_mesh((2, 4), ("data", "model"))
         r = ShardedSketchStore.restore(dir_, g2, gp.config, mesh_24)
         assert r.num_shards == 2
         s_r, sig_r = DistributedQueryEngine(r).top_k(4)
@@ -321,7 +322,7 @@ def main():
                                                      num_colors=64,
                                                      master_seed=3)))
         ref.ensure(8)
-        mesh_24 = jax.make_mesh((2, 4), ("data", "model"))
+        mesh_24 = make_mesh((2, 4), ("data", "model"))
         stores = [
             ShardedSketchStore(
                 g2, PoolConfig(max_batches=32, spec=sampling.SamplerSpec(
@@ -360,10 +361,10 @@ def main():
     # and the `have` bitmap dedups re-delivered blocks) and with a
     # capacity so tiny the dense early levels overflow back to the flat
     # all-gather via lax.cond.
-    from jax.sharding import Mesh
-    mesh_bf = Mesh(np.array(jax.devices()).reshape(2, 4), ("data", "model"))
-    mesh_m3 = Mesh(np.array(jax.devices()[:6]).reshape(2, 3),
-                   ("data", "model"))
+    mesh_bf = make_mesh((2, 4), ("data", "model"),
+                        devices=jax.devices())
+    mesh_m3 = make_mesh((2, 3), ("data", "model"),
+                        devices=jax.devices()[:6])
     for diffusion in ("ic", "lt"):
         ref_bf = SketchStore(g2, PoolConfig(
             max_batches=32, spec=sampling.SamplerSpec(
@@ -410,7 +411,8 @@ def main():
     # over data and one over model, and the answers stay bit-identical to
     # the 1-device engine.  Host batches stay full-V, so a snapshot saved
     # under 2×4 restores onto 4×2 or a model-free 8-shard mesh unchanged.
-    mesh_rs = Mesh(np.array(jax.devices()).reshape(2, 4), ("data", "model"))
+    mesh_rs = make_mesh((2, 4), ("data", "model"),
+                        devices=jax.devices())
     rs = ShardedSketchStore(g, cfg, mesh_rs)
     rs.ensure(8)
     assert rs.row_shards == 4 and rs.padded_vertices % 4 == 0
@@ -441,8 +443,8 @@ def main():
         assert extra["row_layout"]["shards"] == 4
         assert extra["row_layout"]["padded_vertices"] == rs.padded_vertices
         want = DistributedQueryEngine(rs).top_k(4)
-        mesh_42 = Mesh(np.array(jax.devices()).reshape(4, 2),
-                       ("data", "model"))
+        mesh_42 = make_mesh((4, 2), ("data", "model"),
+                        devices=jax.devices())
         for new_mesh, m_new in ((mesh_42, 2), (mesh8, 1)):
             r_new = ShardedSketchStore.restore(d_, g, cfg, new_mesh)
             assert r_new.row_shards == m_new

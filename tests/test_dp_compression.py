@@ -15,12 +15,13 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import registry
 from repro.data.pipeline import SyntheticLM
+from repro.launch.mesh import make_mesh
 from repro.models import model
 from repro.optim import adamw
 from repro.train.dp_step import make_dp_train_step
 
 cfg = registry.smoke("llama3.2-3b")
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 data = SyntheticLM(cfg, 16, 32, seed=4)
 
 def run(compressed):

@@ -85,7 +85,8 @@ def test_tiled_traversal_equals_csr_traversal(use_kernel):
     res_csr = traversal.run_fused(g, starts, n_colors, jnp.uint32(21))
     tg = tiles.from_graph(g)
     vis_tiled, levels, grid_steps = tiled_traversal.run_fused_tiled(
-        tg, starts, n_colors, 21, use_kernel=use_kernel)
+        tg, starts, n_colors, 21, use_kernel=use_kernel,
+        interpret=ops._interpret())
     np.testing.assert_array_equal(np.asarray(vis_tiled),
                                   np.asarray(res_csr.visited))
     assert int(levels) == int(res_csr.stats.levels_run)
@@ -114,7 +115,7 @@ def test_lt_select_expand_kernel_matches_ref(tile_size, n_colors):
     out_ref = ref.lt_select_expand_ref(tg.prob, cb, tg.tile_src,
                                        tg.tile_dst, fr, fr, u)
     out_ker = lse.lt_select_expand(tg.prob, cb, tg.tile_src, tg.tile_dst,
-                                   tg.first_of_dst, fr, fr, u,
+                                   tg.first_of_dst, fr, fr, u.T,
                                    interpret=True)
     np.testing.assert_array_equal(np.asarray(out_ref), np.asarray(out_ker))
 
@@ -132,7 +133,8 @@ def test_lt_tiled_kernel_traversal_equals_dense_lt(frontier):
     tg = tiles.from_graph(g)
     cb = tiles.edge_values_to_tiles(tg, lt.selection_cum_before(g))
     vis, levels, gs = tiled_traversal.run_fused_lt_tiled(
-        tg, cb, starts, 64, 9, use_kernel=True, frontier=frontier)
+        tg, cb, starts, 64, 9, use_kernel=True, interpret=ops._interpret(),
+        frontier=frontier)
     np.testing.assert_array_equal(np.asarray(vis), np.asarray(ref_vis))
     if frontier == "dense":
         assert int(gs) == int(levels) * tg.num_tiles

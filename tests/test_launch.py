@@ -83,11 +83,12 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.launch import hlo_analysis as ha
-mesh = jax.make_mesh((2,), ("d",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2,), ("d",))
 def f(x):
     return jax.lax.psum(x, "d")
-from repro.distributed.compat import shard_map
-fn = shard_map(f, mesh=mesh, in_specs=(P("d"),), out_specs=P())
+fn = jax.shard_map(f, mesh=mesh, in_specs=(P("d"),), out_specs=P(),
+                   check_vma=False)
 comp = jax.jit(fn).lower(jax.ShapeDtypeStruct((8, 128), jnp.float32)).compile()
 c = ha.full_cost(comp.as_text())["collective"]
 assert c["op_counts"].get("all-reduce", 0) >= 1, c

@@ -5,13 +5,13 @@ guard.  (Multi-device data_parallel / graph_parallel need forced host
 devices — covered by tests/serve_distributed_check.py.)"""
 import dataclasses
 
-import jax
 import numpy as np
 import pytest
 
 from repro import sampling
 from repro.core import imm, lt, rrr
 from repro.graph import csr, generators
+from repro.launch.mesh import make_mesh
 from repro.serve.influence import (MicroBatcher, PoolConfig, QueryEngine,
                                    ResultCache, SketchStore)
 
@@ -154,7 +154,7 @@ def test_graph_parallel_bit_identical_on_trivial_mesh(graph):
     """The whole row-partitioned block program (frontier all-gather,
     psum-agreed termination, 2-D batch × row sharding) on a 1×1 mesh —
     runnable in the single-device suite — must equal dense exactly."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     for diffusion in ("ic", "lt"):
         spec = sampling.SamplerSpec(diffusion=diffusion,
                                     backend="graph_parallel",
@@ -182,7 +182,7 @@ def test_mesh_backends_require_mesh_and_axes(graph):
     with pytest.raises(ValueError, match="model"):
         sampling.make_sampler(
             graph, sampling.SamplerSpec(backend="graph_parallel"),
-            mesh=jax.make_mesh((1,), ("data",)))
+            mesh=make_mesh((1,), ("data",)))
 
 
 # -------------------------------------------------- sparse frontier mode
@@ -190,7 +190,7 @@ def test_sparse_frontier_bit_identical_across_matrix(graph):
     """frontier="sparse" must be BIT-identical to the dense path on every
     single-process cell of the (diffusion × backend) matrix — compaction
     changes what gets computed, never what comes out."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     for diffusion in ("ic", "lt"):
         backends = ["dense", "tiled", "kernel"]
         ref = sampling.make_sampler(graph, sampling.SamplerSpec(

@@ -9,6 +9,7 @@ import pytest
 from repro.checkpoint import manager as ckpt
 from repro.configs import registry
 from repro.data.pipeline import Prefetcher, SyntheticLM
+from repro.launch.mesh import make_mesh
 from repro.models import model
 from repro.optim import adamw, compress
 from repro.serve import engine
@@ -52,7 +53,7 @@ def test_quantize_roundtrip_error_bounded():
 
 def test_compressed_psum_matches_exact_within_quantization():
     """Run under shard_map on a 1-device mesh (semantics identical)."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     g = {"w": jax.random.normal(jax.random.key(1), (256,))}
 
     def body(gr):
@@ -60,10 +61,9 @@ def test_compressed_psum_matches_exact_within_quantization():
         return mean, res
 
     from jax.sharding import PartitionSpec as P
-    from repro.distributed.compat import shard_map
-    mean, res = jax.jit(shard_map(
+    mean, res = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P(),), out_specs=(P(), P()),
-        check=False))(g)
+        check_vma=False))(g)
     np.testing.assert_allclose(np.asarray(mean["w"] + res["w"]),
                                np.asarray(g["w"]), atol=1e-6)
     # error feedback residual is bounded by half a quantization level
