@@ -62,11 +62,6 @@ def _sections():
         ("Serving tier SLO: open-loop load vs replicas × quota", False,
          lambda: bench("bench_serve_load").run(
              n=400, batches=4, arrivals=120, offered_qps=60.0)),
-        ("Pool build: backend × frontier × diffusion "
-         "(8 forced CPU devices)", True,
-         lambda: bench("bench_pool_build").run(
-             sweeps=bench("bench_pool_build").standard_sweeps(
-                 low_n=1500, gp_n=600, batches=8))),
         ("Streaming deltas: incremental vs cold refresh × churn "
          "(8 forced CPU devices)", True,
          lambda: bench("bench_stream_updates").run(

@@ -67,12 +67,13 @@ def check_kernel_grid() -> None:
         ksp = sampling.make_sampler(g, spec.replace(frontier="sparse"))
         for bi in range(2):
             a = np.asarray(ref.sample(bi).visited)
-            b = np.asarray(kern.sample(bi).visited)
+            kb = kern.sample(bi)
+            b = np.asarray(kb.visited)
             dense_steps = kern.last_grid_steps
             c = np.asarray(ksp.sample(bi).visited)
             np.testing.assert_array_equal(a, b)
             np.testing.assert_array_equal(a, c)
-            assert dense_steps == kern.last_levels * kern.tg_rev.num_tiles
+            assert dense_steps == kb.levels * kern.tg_rev.num_tiles
             if not 0 < ksp.last_grid_steps < dense_steps:
                 raise SystemExit(
                     f"kernel sparse grid not work-proportional at "

@@ -301,6 +301,9 @@ class IMMResult:
     num_batches: int
     fused_edge_visits: int
     unfused_edge_visits: int
+    # Colors still live when `max_iters` stopped their batch's traversal:
+    # RRR sets that were cut short.  0 when every traversal ran out.
+    truncated_sets: int
 
 
 def run_imm(g: csr.Graph, k: int, eps: float = 0.3, *, ell: float = 1.0,
@@ -361,12 +364,14 @@ def run_imm(g: csr.Graph, k: int, eps: float = 0.3, *, ell: float = 1.0,
         seeds=seeds, sigma_estimate=cov * g.num_vertices,
         theta=len(batches) * num_colors, coverage=cov,
         num_batches=len(batches),
-        # Skip the -1 "not instrumented" sentinels (tiled/kernel/LT/
+        # Skip the -1 "not counted" sentinels (tiled/kernel/LT/
         # data_parallel batches) so sums never go negative.
         fused_edge_visits=sum(b.fused_edge_visits for b in batches
                               if b.fused_edge_visits >= 0),
         unfused_edge_visits=sum(b.unfused_edge_visits for b in batches
-                                if b.unfused_edge_visits >= 0))
+                                if b.unfused_edge_visits >= 0),
+        truncated_sets=sum(b.truncated_colors for b in batches
+                           if b.truncated_colors >= 0))
 
 
 def simulate_influence(g: csr.Graph, seeds, num_trials: int = 512,

@@ -118,10 +118,11 @@ def _selection_mask(g: csr.Graph, num_colors: int, seed) -> jnp.ndarray:
 def lt_traversal_program(g: csr.Graph, sel, starts, num_colors: int,
                          max_levels: int, segments=None):
     """Level loop over a fixed live-edge selection — trace-time program
-    (callers jit or stage inside shard_map).  Returns visited (V, W).
+    (callers jit or stage inside shard_map).  Returns (visited (V, W),
+    levels the loop ran, colors still on the frontier when it stopped).
     ``segments``: `traversal.dst_segments(g.dst, V)` when the caller holds
     it (built here otherwise)."""
-    from repro.core.traversal import _or_by_dst, dst_segments
+    from repro.core.traversal import _or_by_dst, dst_segments, live_colors
 
     frontier = init_frontier(g.num_vertices, num_colors, starts)
     visited = jnp.zeros_like(frontier)
@@ -141,9 +142,9 @@ def lt_traversal_program(g: csr.Graph, sel, starts, num_colors: int,
         nf = _or_by_dst(jnp.zeros_like(vis), fr[src] & sel, seg) & ~vis
         return nf, vis, lvl + 1
 
-    fr, vis, _ = jax.lax.while_loop(cond, body,
-                                    (frontier, visited, jnp.int32(0)))
-    return vis | fr
+    fr, vis, levels = jax.lax.while_loop(cond, body,
+                                         (frontier, visited, jnp.int32(0)))
+    return vis | fr, levels, live_colors(fr)
 
 
 def run_fused_lt(g: csr.Graph, starts, num_colors: int, seed,
@@ -161,18 +162,20 @@ def run_fused_lt(g: csr.Graph, starts, num_colors: int, seed,
 def _run_fused_lt_jit(g: csr.Graph, cb, starts, seed, num_colors: int,
                       max_levels: int):
     sel = selection_mask_from_cb(g, cb, num_colors, seed)
-    return lt_traversal_program(g, sel, starts, num_colors, max_levels)
+    return lt_traversal_program(g, sel, starts, num_colors, max_levels)[0]
 
 
 @partial(jax.jit, static_argnames=("num_colors", "max_levels"))
 def run_fused_lt_block(g: csr.Graph, cb, starts, seeds, num_colors: int,
-                       max_levels: int = 64, segments=None) -> jnp.ndarray:
+                       max_levels: int = 64, segments=None):
     """Fused multi-batch LT sweep: ONE dispatch traverses a block of
     batches via ``lax.map`` (each batch draws its own live-edge selection
     from its seed, one (E, W) selection transient at a time).
 
-    starts (B, C) int32 / seeds (B,) uint32 → visited (B, V, W).
-    ``segments`` as in `lt_traversal_program`."""
+    starts (B, C) int32 / seeds (B,) uint32 → (visited (B, V, W),
+    edge_visits (B, 2), level_counts (B, 2)), the outputs of
+    `traversal.run_fused_block`; the edge visits are the -1 "not
+    instrumented" sentinel.  ``segments`` as in `lt_traversal_program`."""
     if segments is None:
         from repro.core.traversal import dst_segments
         segments = dst_segments(g.dst, g.num_vertices)
@@ -180,7 +183,9 @@ def run_fused_lt_block(g: csr.Graph, cb, starts, seeds, num_colors: int,
     def one(args):
         st, sd = args
         sel = selection_mask_from_cb(g, cb, num_colors, sd)
-        return lt_traversal_program(g, sel, st, num_colors, max_levels,
-                                    segments)
+        vis, levels, truncated = lt_traversal_program(
+            g, sel, st, num_colors, max_levels, segments)
+        return (vis, jnp.full((2,), -1, jnp.int32),
+                jnp.stack([levels, truncated]))
 
     return jax.lax.map(one, (starts, seeds))

@@ -13,6 +13,7 @@ node, straggler reissue) reproduces the identical samples.
 from __future__ import annotations
 
 import dataclasses
+from collections.abc import Callable
 
 import jax
 import jax.numpy as jnp
@@ -22,17 +23,55 @@ from repro.core import bitmask, traversal
 from repro.graph import csr
 
 
+NOT_COUNTED = (-1, -1, -1, -1)
+
+
 @dataclasses.dataclass(frozen=True)
 class RRRBatch:
     """One fused batch of ``num_colors`` RRR sets.
 
-    ``*_edge_visits`` are -1 on paths that do not instrument them (tiled,
-    kernel, LT, data_parallel); only the dense IC sweep tracks stats."""
+    ``counts`` holds the traversal counters (``fused_edge_visits``,
+    ``unfused_edge_visits``, ``levels``, ``truncated_colors``), or a
+    callable that returns them when first read, so that a block's
+    counters can stay on the device until then.  They are -1 on paths
+    that do not count them: the edge visits on the tiled, kernel, LT,
+    data_parallel and graph_parallel paths (only the IC sweep tracks
+    them); ``levels`` (levels the traversal ran) and ``truncated_colors``
+    (colors still on the frontier when ``max_iters`` stopped it; 0 when
+    the frontier emptied) on the data_parallel and graph_parallel paths
+    and on restored snapshots.  The tiled and kernel paths count
+    ``levels`` only."""
     visited: jnp.ndarray        # (V, W) uint32; column c = RRR set c
     roots: np.ndarray           # (num_colors,) root vertex per color
     batch_index: int
-    fused_edge_visits: int
-    unfused_edge_visits: int
+    counts: tuple[int, int, int, int] | Callable[[], tuple] = NOT_COUNTED
+
+    def _count(self, k: int) -> int:
+        counts = self.counts() if callable(self.counts) else self.counts
+        return counts[k]
+
+    @property
+    def fused_edge_visits(self) -> int:
+        return self._count(0)
+
+    @property
+    def unfused_edge_visits(self) -> int:
+        return self._count(1)
+
+    @property
+    def levels(self) -> int:
+        return self._count(2)
+
+    @property
+    def truncated_colors(self) -> int:
+        return self._count(3)
+
+
+def stats_counts(stats: traversal.TraversalStats) -> tuple:
+    """`RRRBatch.counts` of one traversal's `TraversalStats`."""
+    return (int(stats.fused_edge_visits.sum()),
+            int(stats.unfused_edge_visits.sum()), int(stats.levels_run),
+            int(stats.truncated_colors))
 
 
 def batch_seeds(master_seed: int, batch_indices) -> np.ndarray:
@@ -79,12 +118,11 @@ def sample_batch(g_rev: csr.Graph, num_colors: int, master_seed: int,
         from repro.core import lt
         visited = lt.run_fused_lt(g_rev, roots, num_colors, seed,
                                   max_levels=max_levels)
-        return RRRBatch(visited, np.asarray(roots), batch_index, -1, -1)
+        return RRRBatch(visited, np.asarray(roots), batch_index)
     res = traversal.run_fused(g_rev, roots, num_colors, seed,
                               max_levels=max_levels)
     return RRRBatch(res.visited, np.asarray(roots), batch_index,
-                    int(res.stats.fused_edge_visits.sum()),
-                    int(res.stats.unfused_edge_visits.sum()))
+                    stats_counts(res.stats))
 
 
 def sample_collection(g: csr.Graph, theta: int,

@@ -45,7 +45,8 @@ import numpy as np
 
 from repro.core import bitmask, rng
 from repro.core.traversal import (TraversalResult, TraversalStats,
-                                  _scatter_or, _tile_activity, init_frontier)
+                                  _empty_stats, _scatter_or, _tile_activity,
+                                  init_frontier, live_colors)
 from repro.graph.csr import Graph
 
 
@@ -291,10 +292,7 @@ def run_fused_sparse(fidx: FrontierIndex, starts, num_colors: int, seed,
     v = fidx.num_vertices
     frontier = init_frontier(v, num_colors, starts)
     visited = bitmask.make_mask(v, num_colors)
-    zeros_i = jnp.zeros((max_levels,), jnp.int32)
-    zeros_f = jnp.zeros((max_levels,), jnp.float32)
-    stats = TraversalStats(jnp.int32(0), zeros_i, zeros_i, zeros_i, zeros_i,
-                           zeros_f, zeros_f, zeros_i)
+    stats = _empty_stats(max_levels)
 
     def cond(carry):
         frontier, _, level, _ = carry
@@ -325,20 +323,24 @@ def run_fused_sparse(fidx: FrontierIndex, starts, num_colors: int, seed,
             occupancy_num=stats.occupancy_num.at[level].set(occ),
             active_tile_frac=stats.active_tile_frac.at[level].set(tile_frac),
             grid_steps=stats.grid_steps.at[level].set(gs),
+            truncated_colors=stats.truncated_colors,
         )
         return nf, visited, level + 1, stats
 
     frontier, visited, _, stats = jax.lax.while_loop(
         cond, body, (frontier, visited, jnp.int32(0), stats))
     visited = visited | frontier
+    stats = dataclasses.replace(stats,
+                                truncated_colors=live_colors(frontier))
     return TraversalResult(visited=visited, stats=stats)
 
 
 @partial(jax.jit, static_argnames=("num_colors", "max_levels", "ladder"))
 def run_fused_lt_sparse(fidx: FrontierIndex, starts, num_colors: int, seed,
                         max_levels: int = 64,
-                        ladder: tuple[int, ...] | None = None) -> jnp.ndarray:
-    """`lt.run_fused_lt` on the sparse-frontier engine (visited (V, W)).
+                        ladder: tuple[int, ...] | None = None):
+    """`lt.run_fused_lt` on the sparse-frontier engine: (visited (V, W),
+    levels, truncated colors) as `lt.lt_traversal_program` returns them.
 
     The LT live-edge selection is recomputed per gathered edge from the
     level-independent uniform table — the same (seed, 0x17, dst, color)
@@ -368,9 +370,9 @@ def run_fused_lt_sparse(fidx: FrontierIndex, starts, num_colors: int, seed,
                                    seed, ladder, u=u)
         return nf, vis, level + 1
 
-    fr, vis, _ = jax.lax.while_loop(cond, body,
-                                    (frontier, visited, jnp.int32(0)))
-    return vis | fr
+    fr, vis, levels = jax.lax.while_loop(cond, body,
+                                         (frontier, visited, jnp.int32(0)))
+    return vis | fr, levels, live_colors(fr)
 
 
 @partial(jax.jit, static_argnames=("num_colors", "max_levels", "ladder",
@@ -383,19 +385,25 @@ def sparse_block(fidx: FrontierIndex, starts, seeds, num_colors: int,
     transients at a time on the device).
 
     starts (B, C) int32, seeds (B,) uint32 → (visited (B, V, W),
-    fused (B,), unfused (B,)) — LT carries the -1 "not instrumented"
-    sentinel like the dense LT path.
+    edge_visits (B, 2), level_counts (B, 2)), the outputs of
+    `traversal.run_fused_block` — LT carries the -1 "not instrumented"
+    sentinel in the edge visits like the dense LT path.
     """
     def one(args):
         st, sd = args
         if diffusion == "lt":
-            vis = run_fused_lt_sparse(fidx, st, num_colors, sd,
-                                      max_levels=max_levels, ladder=ladder)
-            return vis, jnp.int32(-1), jnp.int32(-1)
+            vis, levels, truncated = run_fused_lt_sparse(
+                fidx, st, num_colors, sd, max_levels=max_levels,
+                ladder=ladder)
+            return (vis, jnp.full((2,), -1, jnp.int32),
+                    jnp.stack([levels, truncated]))
         res = run_fused_sparse(fidx, st, num_colors, sd,
                                max_levels=max_levels, ladder=ladder)
-        return (res.visited, res.stats.fused_edge_visits.sum(),
-                res.stats.unfused_edge_visits.sum())
+        return (res.visited,
+                jnp.stack([res.stats.fused_edge_visits.sum(),
+                           res.stats.unfused_edge_visits.sum()]),
+                jnp.stack([res.stats.levels_run,
+                           res.stats.truncated_colors]))
 
     return jax.lax.map(one, (starts, seeds))
 

@@ -54,6 +54,9 @@ class TraversalStats:
     # dense tiled grid records num_tiles; non-gridded (CSR edge-centric)
     # paths record 0 — the counter prices the *grid*, not edge work.
     grid_steps: jnp.ndarray            # (max_levels,) int32
+    # Colors still on the frontier when the loop stopped: 0 when it
+    # emptied, > 0 only when ``max_levels`` cut the traversal.
+    truncated_colors: jnp.ndarray      # () int32
 
 
 @jax.tree_util.register_dataclass
@@ -71,6 +74,19 @@ def init_frontier(num_vertices: int, num_colors: int,
     colors = jnp.arange(num_colors, dtype=jnp.int32)
     frontier = bitmask.make_mask(num_vertices, num_colors)
     return bitmask.set_color(frontier, jnp.asarray(starts, jnp.int32), colors)
+
+
+def live_colors(frontier: jnp.ndarray) -> jnp.ndarray:
+    """() int32: the colors set anywhere on ``frontier`` (V, W)."""
+    words = jax.lax.reduce(frontier, jnp.uint32(0), jax.lax.bitwise_or, (0,))
+    return jnp.sum(bitmask.popcount(words)).astype(jnp.int32)
+
+
+def _empty_stats(max_levels: int) -> TraversalStats:
+    zeros_i = jnp.zeros((max_levels,), jnp.int32)
+    zeros_f = jnp.zeros((max_levels,), jnp.float32)
+    return TraversalStats(jnp.int32(0), zeros_i, zeros_i, zeros_i, zeros_i,
+                          zeros_f, zeros_f, zeros_i, jnp.int32(0))
 
 
 def random_starts(key: jax.Array, num_vertices: int, num_colors: int,
@@ -174,30 +190,38 @@ def dst_view(g: Graph, segments: DstSegments | None = None) -> DstView:
 def _expand(view: DstView, frontier: jnp.ndarray, visited: jnp.ndarray,
             level: jnp.ndarray, seed: jnp.ndarray):
     """One level of the fused traversal on a `DstView`.  Returns
-    (frontier', visited', info)."""
+    (frontier', visited', info).
+
+    Each phase runs under a ``jax.named_scope`` (``bpt.gather``,
+    ``bpt.rng``, ``bpt.or_scan``, ``bpt.count``), so the device trace's
+    name stack ties every op of a level to its phase."""
     num_words = frontier.shape[-1]
     visited = visited | frontier                            # Listing 1 line 8
-    fr_src = frontier[view.src]                             # (E, W) gather
+    with jax.named_scope("bpt.gather"):
+        fr_src = frontier[view.src]                         # (E, W) gather
     # Independent Bernoulli(p_e) per (edge, color): one packed word per
     # (edge, word) pair.  Padding edges have prob 0 → never propagate.
-    word_ids = jnp.arange(num_words, dtype=jnp.uint32)
-    rand = jax.vmap(
-        lambda w: rng.bernoulli_word(seed, level.astype(jnp.uint32),
-                                     view.edge_id, w, view.prob),
-        out_axes=1)(word_ids)                               # (E, W)
+    with jax.named_scope("bpt.rng"):
+        word_ids = jnp.arange(num_words, dtype=jnp.uint32)
+        rand = jax.vmap(
+            lambda w: rng.bernoulli_word(seed, level.astype(jnp.uint32),
+                                         view.edge_id, w, view.prob),
+            out_axes=1)(word_ids)                           # (E, W)
     # Lines 11-13.  Masking the visited colors once per destination after
     # the OR equals masking every in-edge before it.
-    next_frontier = _or_by_dst(jnp.zeros_like(visited), fr_src & rand,
-                               view.seg) & ~visited
+    with jax.named_scope("bpt.or_scan"):
+        next_frontier = _or_by_dst(jnp.zeros_like(visited), fr_src & rand,
+                                   view.seg) & ~visited
 
-    active_src = bitmask.count_colors(fr_src)               # (E,) per-edge
-    info = dict(
-        fused_visits=jnp.sum((active_src > 0).astype(jnp.int32)),
-        unfused_visits=jnp.sum(active_src),
-        frontier_vertices=jnp.sum(
-            (bitmask.count_colors(frontier) > 0).astype(jnp.int32)),
-        frontier_colors=jnp.sum(bitmask.count_colors(frontier)),
-    )
+    with jax.named_scope("bpt.count"):
+        active_src = bitmask.count_colors(fr_src)           # (E,) per-edge
+        info = dict(
+            fused_visits=jnp.sum((active_src > 0).astype(jnp.int32)),
+            unfused_visits=jnp.sum(active_src),
+            frontier_vertices=jnp.sum(
+                (bitmask.count_colors(frontier) > 0).astype(jnp.int32)),
+            frontier_colors=jnp.sum(bitmask.count_colors(frontier)),
+        )
     return next_frontier, visited, info
 
 
@@ -228,10 +252,7 @@ def run_fused(g: Graph, starts: jnp.ndarray, num_colors: int,
     v = g.num_vertices
     frontier = init_frontier(v, num_colors, starts)
     visited = bitmask.make_mask(v, num_colors)
-    zeros_i = jnp.zeros((max_levels,), jnp.int32)
-    zeros_f = jnp.zeros((max_levels,), jnp.float32)
-    stats = TraversalStats(jnp.int32(0), zeros_i, zeros_i, zeros_i, zeros_i,
-                           zeros_f, zeros_f, zeros_i)
+    stats = _empty_stats(max_levels)
     view = dst_view(g, segments)
 
     def cond(carry):
@@ -259,6 +280,7 @@ def run_fused(g: Graph, starts: jnp.ndarray, num_colors: int,
             occupancy_num=stats.occupancy_num.at[level].set(occ),
             active_tile_frac=stats.active_tile_frac.at[level].set(tile_frac),
             grid_steps=stats.grid_steps,          # CSR path: not gridded
+            truncated_colors=stats.truncated_colors,
         )
         return nf, nv, level + 1, stats
 
@@ -267,6 +289,8 @@ def run_fused(g: Graph, starts: jnp.ndarray, num_colors: int,
     # Vertices still on the frontier at the level cap count as visited (their
     # colors have reached them even if not expanded further).
     visited = visited | frontier
+    stats = dataclasses.replace(stats,
+                                truncated_colors=live_colors(frontier))
     return TraversalResult(visited=visited, stats=stats)
 
 
@@ -279,9 +303,12 @@ def run_fused_block(g: Graph, starts: jnp.ndarray, seeds: jnp.ndarray,
     at a time — so a pool build stops paying per-batch dispatch).
 
     starts (B, C) int32 / seeds (B,) uint32 → (visited (B, V, W),
-    fused (B,), unfused (B,)) with the edge-visit totals equal to
-    ``run_fused``'s per-level stats summed (same int32 arithmetic).
-    ``segments`` as in `run_fused`.
+    edge_visits (B, 2), level_counts (B, 2)), all int32 but ``visited``.
+    ``edge_visits`` holds (fused, unfused): ``run_fused``'s per-level
+    stats summed (same int32 arithmetic).  ``level_counts`` holds
+    (levels, truncated): its ``levels_run``, and the colors still on the
+    frontier when the loop stopped (> 0 only when ``max_levels`` cut the
+    batch).  ``segments`` as in `run_fused`.
     """
     view = dst_view(g, segments)
 
@@ -300,10 +327,11 @@ def run_fused_block(g: Graph, starts: jnp.ndarray, seeds: jnp.ndarray,
             return (nf, nv, lvl + 1, fused + info["fused_visits"],
                     unfused + info["unfused_visits"])
 
-        fr, vis, _, fused, unfused = jax.lax.while_loop(
+        fr, vis, levels, fused, unfused = jax.lax.while_loop(
             cond, body,
             (frontier, visited, jnp.int32(0), jnp.int32(0), jnp.int32(0)))
-        return vis | fr, fused, unfused
+        return (vis | fr, jnp.stack([fused, unfused]),
+                jnp.stack([levels, live_colors(fr)]))
 
     return jax.lax.map(one, (starts, seeds))
 
@@ -322,10 +350,7 @@ def run_single_color(g: Graph, start: jnp.ndarray, color_id: int,
     visited = jnp.zeros((v, 1), jnp.uint32)
     edge_ids = jnp.arange(g.padded_edges, dtype=jnp.uint32)
     lane_bit = jnp.uint32(1) << jnp.uint32(lane)
-    zeros_i = jnp.zeros((max_levels,), jnp.int32)
-    zeros_f = jnp.zeros((max_levels,), jnp.float32)
-    stats = TraversalStats(jnp.int32(0), zeros_i, zeros_i, zeros_i, zeros_i,
-                           zeros_f, zeros_f, zeros_i)
+    stats = _empty_stats(max_levels)
     segments = dst_segments(g.dst, v)
 
     def cond(carry):
@@ -356,12 +381,15 @@ def run_single_color(g: Graph, start: jnp.ndarray, color_id: int,
             occupancy_num=stats.occupancy_num,
             active_tile_frac=stats.active_tile_frac,
             grid_steps=stats.grid_steps,
+            truncated_colors=stats.truncated_colors,
         )
         return nf, visited, level + 1, stats
 
     frontier, visited, _, stats = jax.lax.while_loop(
         cond, body, (frontier, visited, jnp.int32(0), stats))
     visited = visited | frontier
+    stats = dataclasses.replace(stats,
+                                truncated_colors=live_colors(frontier))
     return TraversalResult(visited=visited, stats=stats)
 
 

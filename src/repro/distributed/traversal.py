@@ -154,12 +154,13 @@ def _frontier_gather_loop(expand, frontier_local, max_levels: int, axis: str,
     Returns (visited_local, levels, gather_words) where ``gather_words``
     is a (max_levels,) int32 vector of the packed words each level moved
     over ``axis`` (summed across shards; replicated, zero past the last
-    level) — the interconnect-traffic observable `bench_pool_build`
-    records per level.  The exchange collectives name only ``axis``, but
-    the loop's CONTROL decisions (keep going? sparse or dense leg?)
-    reduce over ``sync_axes`` (default: just ``axis``): every mesh axis
-    named there runs the level loop in lockstep, which real SPMD
-    execution implies anyway and the host-device emulation REQUIRES —
+    level) — the interconnect-traffic observable
+    (`GraphParallelSampler.last_gather_words`).  The exchange collectives
+    name only ``axis``, but the loop's CONTROL decisions (keep going?
+    sparse or dense leg?) reduce over ``sync_axes`` (default: just
+    ``axis``): every mesh axis named there runs the level loop in
+    lockstep, which real SPMD execution implies anyway and the
+    host-device emulation REQUIRES —
     ``ppermute`` lowers to one collective-permute spanning every device,
     so shards that diverge on trip count or branch deadlock the
     rendezvous.  A shard whose frontier drained early just exchanges

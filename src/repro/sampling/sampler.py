@@ -42,12 +42,14 @@ can hand any IC-weighted graph to an LT sampler.
 """
 from __future__ import annotations
 
+import functools
 import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import lt, rrr, tiled_traversal, tiles
 from repro.graph import csr
 from repro.sampling.spec import SamplerSpec
@@ -200,8 +202,12 @@ class DenseSampler(Sampler):
     ``sample_many`` fuses the whole block into ONE dispatch (``lax.map``
     over batches inside one jit — `traversal.run_fused_block` /
     `sparse.sparse_block` / `lt.run_fused_lt_block`), so pool builds and
-    refreshes stop paying per-batch dispatch.  IC blocks keep real
-    edge-visit totals; LT carries the usual -1 sentinel.
+    refreshes stop paying per-batch dispatch, and reads the block's
+    counters back with one ``jax.device_get`` when the first of them is
+    read (`_BlockCounts`), so ``sample_many`` returns without waiting for
+    the block.  Every batch counts its levels and truncated colors; IC
+    batches keep real edge-visit totals, LT carries the usual -1
+    sentinel.
     """
 
     def __init__(self, g, spec, *, g_rev=None):
@@ -231,48 +237,49 @@ class DenseSampler(Sampler):
             starts = self.batch_starts(batch_index)
             seed = self.batch_seed(batch_index)
             if self.spec.diffusion == "lt":
-                visited = sparse.run_fused_lt_sparse(
+                visited, levels, truncated = sparse.run_fused_lt_sparse(
                     fidx, starts, self.spec.num_colors, seed,
                     max_levels=self.spec.max_iters, ladder=self._ladder)
                 return rrr.RRRBatch(visited, np.asarray(starts),
-                                    int(batch_index), -1, -1)
+                                    int(batch_index),
+                                    (-1, -1, int(levels), int(truncated)))
             res = sparse.run_fused_sparse(
                 fidx, starts, self.spec.num_colors, seed,
                 max_levels=self.spec.max_iters, ladder=self._ladder)
-            return rrr.RRRBatch(
-                res.visited, np.asarray(starts), int(batch_index),
-                int(res.stats.fused_edge_visits.sum()),
-                int(res.stats.unfused_edge_visits.sum()))
+            return rrr.RRRBatch(res.visited, np.asarray(starts),
+                                int(batch_index), rrr.stats_counts(res.stats))
         return self.sample_many([batch_index])[0]
 
     def sample_many(self, batch_indices) -> list[rrr.RRRBatch]:
         idx = [int(b) for b in batch_indices]
         if not idx or (len(idx) == 1 and self.spec.frontier == "sparse"):
             return [self.sample(b) for b in idx]
-        starts = jnp.stack([self.batch_starts(b) for b in idx])
-        seeds = jnp.asarray(rrr.batch_seeds(self.spec.master_seed, idx))
         spec = self.spec
-        if spec.frontier == "sparse":
-            from repro.core import sparse
-            fidx = self._frontier_index()
-            vis, fused, unfused = sparse.sparse_block(
-                fidx, starts, seeds, spec.num_colors, spec.max_iters,
-                self._ladder, diffusion=spec.diffusion)
-        elif spec.diffusion == "lt":
-            vis = lt.run_fused_lt_block(self.g_rev, self._lt_cb(), starts,
-                                        seeds, spec.num_colors,
-                                        max_levels=spec.max_iters,
-                                        segments=self._dst_segments())
-            fused = unfused = np.full(len(idx), -1)
-        else:
-            from repro.core import traversal
-            vis, fused, unfused = traversal.run_fused_block(
-                self.g_rev, starts, seeds, spec.num_colors,
-                max_levels=spec.max_iters, segments=self._dst_segments())
+        with obs.span("sampling.block_inputs", batches=len(idx)):
+            starts = jnp.stack([self.batch_starts(b) for b in idx])
+            seeds = jnp.asarray(rrr.batch_seeds(spec.master_seed, idx))
+        with obs.span("sampling.dispatch", batches=len(idx)):
+            if spec.frontier == "sparse":
+                from repro.core import sparse
+                fidx = self._frontier_index()
+                vis, visits, counts = sparse.sparse_block(
+                    fidx, starts, seeds, spec.num_colors, spec.max_iters,
+                    self._ladder, diffusion=spec.diffusion)
+            elif spec.diffusion == "lt":
+                vis, visits, counts = lt.run_fused_lt_block(
+                    self.g_rev, self._lt_cb(), starts, seeds,
+                    spec.num_colors, max_levels=spec.max_iters,
+                    segments=self._dst_segments())
+            else:
+                from repro.core import traversal
+                vis, visits, counts = traversal.run_fused_block(
+                    self.g_rev, starts, seeds, spec.num_colors,
+                    max_levels=spec.max_iters,
+                    segments=self._dst_segments())
         roots = np.asarray(starts)
-        return [rrr.RRRBatch(vis[i], roots[i], b, int(fused[i]),
-                             int(unfused[i]))
-                for i, b in enumerate(idx)]
+        block = _BlockCounts(visits, counts)
+        return [rrr.RRRBatch(vis[i], roots[i], b, functools.partial(
+                    block.row, i)) for i, b in enumerate(idx)]
 
     def rebind(self, g, g_rev, touched_row_blocks=None):
         if self._try_patch_fidx(g, g_rev, touched_row_blocks):
@@ -290,6 +297,27 @@ def _tile_graph(g_rev: csr.Graph, spec: SamplerSpec) -> tiles.TiledGraph:
             f"the {spec.backend!r} backend needs a dedupe-clean graph "
             "(build it with csr.from_edges(..., dedupe=True)); "
             f"tiling failed with: {e}") from e
+
+
+class _BlockCounts:
+    """The (B, 2) counter pairs a block program returns, (fused, unfused)
+    edge visits and (levels, truncated colors): left on the device until
+    a batch's counter is first read, then read back for the whole block
+    with one ``jax.device_get``."""
+
+    def __init__(self, visits: jax.Array, counts: jax.Array):
+        self._pending = (visits, counts)
+        self._rows = None
+
+    def row(self, i: int) -> tuple[int, int, int, int]:
+        """`rrr.RRRBatch.counts` of the block's batch ``i``."""
+        if self._rows is None:
+            with obs.span("sampling.readback"):
+                visits, counts = jax.device_get(self._pending)
+            self._rows = [tuple(map(int, v)) + tuple(map(int, c))
+                          for v, c in zip(visits, counts)]
+            self._pending = None
+        return self._rows[i]
 
 
 class TiledSampler(Sampler):
@@ -318,9 +346,9 @@ class TiledSampler(Sampler):
             from repro.core import sparse
             self._ladder = sparse.bucket_ladder(self.tg_rev.num_tiles,
                                                 spec.frontier_capacity)
-        # Grid-work observability (benchmarks' active_grid_frac column):
-        # per-sample totals from the last `sample()` call.
-        self.last_levels = 0
+        # Grid-work observability: the grid steps of the last `sample()`
+        # call (``active_grid_frac`` = steps / (levels · num_tiles), with
+        # the batch's ``levels``).
         self.last_grid_steps = 0
 
     def sample(self, batch_index: int) -> rrr.RRRBatch:
@@ -339,10 +367,9 @@ class TiledSampler(Sampler):
         else:
             visited, levels, gs = tiled_traversal.run_fused_tiled(
                 self.tg_rev, starts, spec.num_colors, seed, **kw)
-        self.last_levels = int(levels)
         self.last_grid_steps = int(gs)
-        return rrr.RRRBatch(visited, np.asarray(starts),
-                            int(batch_index), -1, -1)
+        return rrr.RRRBatch(visited, np.asarray(starts), int(batch_index),
+                            (-1, -1, int(levels), -1))
 
 
 class _BlockSampler(Sampler):
@@ -368,16 +395,15 @@ class _BlockSampler(Sampler):
     def sample_many(self, batch_indices) -> list[rrr.RRRBatch]:
         """Block-sample, then host-stage `RRRBatch`es (each device
         contributes only its own slice of the block — the full block never
-        transits a single device).  Edge-visit stats carry the -1 "not
-        instrumented" sentinel, like the tiled and LT paths."""
+        transits a single device).  Every counter carries the -1 "not
+        counted" sentinel."""
         idx = [int(b) for b in batch_indices]
         if not idx:
             return []
         vis_sharded, roots = self._block(idx)
         vis = np.asarray(jax.device_get(vis_sharded))
         vis = vis[:, : self.g_rev.num_vertices]     # no-op when unpadded
-        return [rrr.RRRBatch(vis[i], roots[i], b, -1, -1)
-                for i, b in enumerate(idx)]
+        return [rrr.RRRBatch(vis[i], roots[i], b) for i, b in enumerate(idx)]
 
 
 _DP_BLOCK_FNS: dict = {}
@@ -413,7 +439,7 @@ def _data_parallel_block_fn(mesh, axis: str, spec: SamplerSpec, ladder):
                 if spec.diffusion == "lt":
                     return sparse.run_fused_lt_sparse(
                         fidx, starts, spec.num_colors, seed,
-                        max_levels=spec.max_iters, ladder=ladder)
+                        max_levels=spec.max_iters, ladder=ladder)[0]
                 return sparse.run_fused_sparse(
                     fidx, starts, spec.num_colors, seed,
                     max_levels=spec.max_iters, ladder=ladder).visited
@@ -423,7 +449,7 @@ def _data_parallel_block_fn(mesh, axis: str, spec: SamplerSpec, ladder):
                                                 seed)
                 return lt.lt_traversal_program(g, sel, starts,
                                                spec.num_colors,
-                                               spec.max_iters, segs)
+                                               spec.max_iters, segs)[0]
             g, segs = data
             return run_batch(g, starts, seed, spec.num_colors,
                              max_levels=spec.max_iters, segments=segs)
@@ -579,8 +605,8 @@ class GraphParallelSampler(_BlockSampler):
         self._shard_tiles = [np.flatnonzero(shard_of == s)
                              for s in range(self.ptg.num_shards)]
         # Per-batch per-level words moved over the model axis by the most
-        # recent `_block` call — (B, max_iters) host int32, the traffic
-        # observable `bench_pool_build` records.
+        # recent `_block` call — (B, max_iters) host int32, the
+        # interconnect-traffic observable.
         self.last_gather_words = None
 
     @property

@@ -48,8 +48,8 @@ so snapshots remain mesh-shape-free and restore onto any D×M layout.
 Refresh reuses the pool allocation: the base class's donated-buffer slot
 scatter (`sketch_store._set_slots`) rewrites only the refreshed slots of
 the sharded stack in place — untouched shards' blocks never move, and the
-whole pool is never re-staged from host (the `BENCH_pool_build.json`
-``refresh_s ≈ build_s`` fix).
+whole pool is never re-staged from host, so a refresh no longer costs as
+much as a build.
 
 Persistence: snapshots are written through the same manifest format as the
 base class, with the shard layout recorded in the manifest's ``extra``

@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.checkpoint import manager
 from repro.core import bitmask, rrr
 from repro.graph import csr
@@ -280,9 +281,10 @@ class SketchStore:
         """
         if self._stack is None:
             return
-        masks = jnp.stack([jnp.asarray(b.visited) for b in new_batches])
-        self._stack = _set_slots(self._stack,
-                                 jnp.asarray(slots, jnp.int32), masks)
+        with obs.span("store.set_slots", slots=len(slots)):
+            masks = jnp.stack([jnp.asarray(b.visited) for b in new_batches])
+            self._stack = _set_slots(self._stack,
+                                     jnp.asarray(slots, jnp.int32), masks)
 
     def refresh(self, fraction: float = 0.25) -> list[int]:
         """Resample the oldest-epoch batches with fresh RNG streams.
@@ -292,20 +294,23 @@ class SketchStore:
         at never-before-used batch indices.  Returns the replaced slots.
         The cached visited stack is updated in place (`_update_stack`) —
         a refresh reuses the pool allocation instead of re-staging it.
+        Runs under the host span ``repro:store.refresh`` (`obs.span`).
         """
         if not self.batches:
             return []
         self.epoch += 1
-        count = min(len(self.batches),
-                    max(1, math.ceil(fraction * len(self.batches))))
-        order = sorted(range(len(self.batches)),
-                       key=lambda i: (self.batch_epochs[i], i))
-        slots = order[:count]
-        new = self._sample_block(self._take_indices(count))
-        for i, b in zip(slots, new):
-            self.batches[i] = b
-            self.batch_epochs[i] = self.epoch
-        self._update_stack(slots, new)
+        with obs.span("store.refresh", epoch=self.epoch,
+                      first_batch=self.next_batch_index):
+            count = min(len(self.batches),
+                        max(1, math.ceil(fraction * len(self.batches))))
+            order = sorted(range(len(self.batches)),
+                           key=lambda i: (self.batch_epochs[i], i))
+            slots = order[:count]
+            new = self._sample_block(self._take_indices(count))
+            for i, b in zip(slots, new):
+                self.batches[i] = b
+                self.batch_epochs[i] = self.epoch
+            self._update_stack(slots, new)
         return slots
 
     # ---------------------------------------------------- streaming deltas
@@ -431,8 +436,8 @@ class SketchStore:
         visits = np.asarray(tree["edge_visits"])
         batches = [
             rrr.RRRBatch(cls._mask_array(visited[i]), roots[i],
-                         int(indices[i]), int(visits[i, 0]),
-                         int(visits[i, 1]))
+                         int(indices[i]), (int(visits[i, 0]),
+                                           int(visits[i, 1]), -1, -1))
             for i in range(visited.shape[0])]
         epochs = [int(e) for e in np.asarray(tree["batch_epochs"])]
         # Pre-streaming snapshots carry 4 counters (no graph epoch): 0.

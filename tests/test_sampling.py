@@ -143,7 +143,7 @@ def test_lt_kernel_bit_identical_to_dense(graph):
         np.testing.assert_array_equal(np.asarray(got.visited),
                                       np.asarray(ref.visited))
         dense_steps = kern.last_grid_steps
-        assert dense_steps == kern.last_levels * kern.tg_rev.num_tiles
+        assert dense_steps == got.levels * kern.tg_rev.num_tiles
         got_sp = kern_sparse.sample(bi)
         np.testing.assert_array_equal(np.asarray(got_sp.visited),
                                       np.asarray(ref.visited))

@@ -161,8 +161,10 @@ def test_run_fused_block_matches_per_batch(small_graph):
         traversal.random_starts(jax.random.key(k), small_graph.num_vertices,
                                 64) for k in range(3)])
     seeds = jnp.asarray([7, 8, 9], jnp.uint32)
-    vis, fused, unfused = traversal.run_fused_block(small_graph, starts,
+    vis, visits, counts = traversal.run_fused_block(small_graph, starts,
                                                     seeds, 64)
+    fused, unfused = np.asarray(visits).T
+    levels, truncated = np.asarray(counts).T
     for i in range(3):
         ref = traversal.run_fused(small_graph, starts[i], 64, seeds[i])
         np.testing.assert_array_equal(np.asarray(vis[i]),
@@ -171,6 +173,8 @@ def test_run_fused_block_matches_per_batch(small_graph):
             ref.stats.fused_edge_visits, np.int64).sum())
         assert int(unfused[i]) == int(np.asarray(
             ref.stats.unfused_edge_visits, np.int64).sum())
+        assert int(levels[i]) == int(ref.stats.levels_run)
+        assert int(truncated[i]) == int(ref.stats.truncated_colors)
 
 
 def test_run_fused_lt_block_matches_per_batch(small_graph):
@@ -181,7 +185,7 @@ def test_run_fused_lt_block_matches_per_batch(small_graph):
         traversal.random_starts(jax.random.key(k), g.num_vertices, 64)
         for k in range(2)])
     seeds = jnp.asarray([3, 4], jnp.uint32)
-    vis = lt.run_fused_lt_block(g, cb, starts, seeds, 64)
+    vis = lt.run_fused_lt_block(g, cb, starts, seeds, 64)[0]
     for i in range(2):
         ref = lt.run_fused_lt(g, starts[i], 64, seeds[i])
         np.testing.assert_array_equal(np.asarray(vis[i]), np.asarray(ref))
