@@ -99,6 +99,7 @@ def any_set(mask: jnp.ndarray) -> jnp.ndarray:
     return jnp.any(mask != 0)
 
 
-def count_colors(mask: jnp.ndarray) -> jnp.ndarray:
-    """Total set bits per row: (R, W) → (R,) int32."""
-    return jnp.sum(popcount(mask), axis=-1).astype(jnp.int32)
+def count_colors(mask: jnp.ndarray, word_axis: int = -1) -> jnp.ndarray:
+    """Total set bits per row: (R, W) → (R,) int32; ``word_axis=0`` counts
+    a word-major (W, R) mask."""
+    return jnp.sum(popcount(mask), axis=word_axis).astype(jnp.int32)

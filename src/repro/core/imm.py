@@ -401,9 +401,12 @@ def simulate_influence(g: csr.Graph, seeds, num_trials: int = 512,
 
 def _run_from_frontier(g: csr.Graph, frontier, num_colors: int, seed,
                        max_levels: int = 64):
-    """Fused traversal from an arbitrary initial frontier; returns visited."""
+    """Fused traversal from an arbitrary initial frontier (V, W); returns
+    visited (V, W).  The loop carries both word-major, as `_expand` takes
+    them."""
     from repro.core import traversal as trav
 
+    frontier = frontier.T
     visited = jnp.zeros_like(frontier)
     view = trav.dst_view(g)
 
@@ -418,4 +421,4 @@ def _run_from_frontier(g: csr.Graph, frontier, num_colors: int, seed,
 
     fr, vis, _ = jax.lax.while_loop(cond, body,
                                     (frontier, visited, jnp.int32(0)))
-    return vis | fr
+    return (vis | fr).T

@@ -1,8 +1,7 @@
 """Fused breadth-first probabilistic traversals (paper §3, Listing 1).
 
 TPU-native formulation: the frontier is a dense packed color
-bitmask ``(V, W)`` and one level of the fused traversal is an edge-centric
-sweep
+bitmask and one level of the fused traversal is an edge-centric sweep
 
     contrib[e] = frontier[src[e]] & bernoulli(prob[e]) & ~visited[dst[e]]
     frontier'  = scatter_or(dst, contrib) & ~visited'
@@ -13,6 +12,20 @@ bitwise-independent per color, the fused traversal restricted to color ``c``
 is *exactly* the single-color BPT driven by the same counter RNG — fused and
 unfused runs are coupled bit-for-bit (used by tests to check equivalence and
 Theorem 1 without sampling error).
+
+Layout: masks enter and leave as ``(V, W)`` (`init_frontier`, the results),
+but the dense CSR level loops (`run_fused`, `run_fused_block`,
+``distributed.traversal.run_batch``) carry them word-major, ``(W, V)``, and
+every edge-length intermediate of a level (`_expand`) is ``(W, E)``: the
+gathered source words, the RNG words, ``contrib``, the OR scan's
+accumulator, so that the edge axis is the minor one.  Under TPU's (8, 128)
+tiling an edge-major ``(E, W)`` array pads its minor dimension from W = 2
+to 128 lanes — 64× its data — and the compiler relays every such buffer
+to and from a lane-dense layout.  Each loop transposes once on entry and
+once on exit.  One padded buffer is left: the compiler writes the source
+row gather ``frontier[:, src]`` edge-major and relays it once; one 1-D
+gather per word would avoid that, but a 1-D gather costs far more per
+index on TPU v5e (PERF.md §5).
 
 Level-synchronous semantics (matching the paper's Ripples port §4.2): the
 whole frontier is folded into ``visited`` first, then expansion excludes all
@@ -76,9 +89,12 @@ def init_frontier(num_vertices: int, num_colors: int,
     return bitmask.set_color(frontier, jnp.asarray(starts, jnp.int32), colors)
 
 
-def live_colors(frontier: jnp.ndarray) -> jnp.ndarray:
-    """() int32: the colors set anywhere on ``frontier`` (V, W)."""
-    words = jax.lax.reduce(frontier, jnp.uint32(0), jax.lax.bitwise_or, (0,))
+def live_colors(frontier: jnp.ndarray, word_axis: int = -1) -> jnp.ndarray:
+    """() int32: the colors set anywhere on ``frontier`` (V, W), or on a
+    word-major (W, V) one with ``word_axis=0``."""
+    rows = 1 if word_axis == 0 else 0
+    words = jax.lax.reduce(frontier, jnp.uint32(0), jax.lax.bitwise_or,
+                           (rows,))
     return jnp.sum(bitmask.popcount(words)).astype(jnp.int32)
 
 
@@ -133,25 +149,32 @@ def dst_segments(dst: jnp.ndarray, num_rows: int) -> DstSegments:
 
 
 def _or_by_dst(base_words: jnp.ndarray, contrib: jnp.ndarray,
-               seg: DstSegments) -> jnp.ndarray:
+               seg: DstSegments, axis: int = 0) -> jnp.ndarray:
     """base[dst] |= contrib for ``contrib`` already in destination order.
 
     No scatter: a segmented Hillis-Steele OR scan — ⌈log₂ max in-degree⌉
     shifted ORs — folds each destination's in-edges into its last one,
     which one gather per row picks.  OR is order-free, so this equals a
     per-bit scatter-max exactly (which on TPU costs 32× the updates and
-    serializes on duplicate destinations)."""
+    serializes on duplicate destinations).
+
+    ``axis`` is the edge axis of ``contrib`` and the row axis of
+    ``base_words``: 0 for edge-major (E, W) / (R, W), 1 for the dense
+    level's word-major (W, E) / (W, R), whose rolls and gather then run
+    along the lane-dense minor axis (see the module docstring)."""
     zero = jnp.uint32(0)
+    edges = (slice(None), None) if axis == 0 else (None, slice(None))
 
     def shift_or(carry):
         acc, s = carry
-        prev = jnp.roll(acc, s, axis=0)          # wrapped rows have rank < s
-        return acc | jnp.where((seg.rank >= s)[:, None], prev, zero), s * 2
+        prev = jnp.roll(acc, s, axis=axis)       # wrapped edges have rank < s
+        return acc | jnp.where((seg.rank >= s)[edges], prev, zero), s * 2
 
     acc, _ = jax.lax.while_loop(lambda c: c[1] <= seg.max_rank, shift_or,
                                 (contrib, jnp.int32(1)))
-    got = jnp.where((seg.last >= 0)[:, None], acc[jnp.maximum(seg.last, 0)],
-                    zero)
+    last = jnp.maximum(seg.last, 0)
+    picked = acc[last] if axis == 0 else acc[:, last]
+    got = jnp.where((seg.last >= 0)[edges], picked, zero)
     return base_words | got
 
 
@@ -189,55 +212,67 @@ def dst_view(g: Graph, segments: DstSegments | None = None) -> DstView:
 
 def _expand(view: DstView, frontier: jnp.ndarray, visited: jnp.ndarray,
             level: jnp.ndarray, seed: jnp.ndarray):
-    """One level of the fused traversal on a `DstView`.  Returns
+    """One level of the fused traversal on a `DstView`, word-major: takes
+    and returns ``frontier`` and ``visited`` as (W, V).  Returns
     (frontier', visited', info).
+
+    Every edge-length intermediate is (W, E) — the gathered source words,
+    the RNG words, ``contrib``, the OR scan — or (E,), never (E, W): a
+    minor dimension of W = 2 pads to 128 lanes under TPU's (8, 128)
+    tiling, and the compiler then relays each such buffer (it still does
+    so once, for the gather's output: module docstring).
 
     Each phase runs under a ``jax.named_scope`` (``bpt.gather``,
     ``bpt.rng``, ``bpt.or_scan``, ``bpt.count``), so the device trace's
     name stack ties every op of a level to its phase."""
-    num_words = frontier.shape[-1]
+    num_words = frontier.shape[0]
     visited = visited | frontier                            # Listing 1 line 8
     with jax.named_scope("bpt.gather"):
-        fr_src = frontier[view.src]                         # (E, W) gather
+        fr_src = frontier[:, view.src]                      # (W, E)
     # Independent Bernoulli(p_e) per (edge, color): one packed word per
-    # (edge, word) pair.  Padding edges have prob 0 → never propagate.
+    # (word, edge) pair.  Padding edges have prob 0 → never propagate.
     with jax.named_scope("bpt.rng"):
         word_ids = jnp.arange(num_words, dtype=jnp.uint32)
         rand = jax.vmap(
             lambda w: rng.bernoulli_word(seed, level.astype(jnp.uint32),
-                                         view.edge_id, w, view.prob),
-            out_axes=1)(word_ids)                           # (E, W)
+                                         view.edge_id, w, view.prob)
+        )(word_ids)                                         # (W, E)
     # Lines 11-13.  Masking the visited colors once per destination after
     # the OR equals masking every in-edge before it.
     with jax.named_scope("bpt.or_scan"):
         next_frontier = _or_by_dst(jnp.zeros_like(visited), fr_src & rand,
-                                   view.seg) & ~visited
+                                   view.seg, axis=1) & ~visited
 
     with jax.named_scope("bpt.count"):
-        active_src = bitmask.count_colors(fr_src)           # (E,) per-edge
+        active_src = bitmask.count_colors(fr_src, word_axis=0)  # (E,)
+        per_row = bitmask.count_colors(frontier, word_axis=0)   # (V,)
         info = dict(
             fused_visits=jnp.sum((active_src > 0).astype(jnp.int32)),
             unfused_visits=jnp.sum(active_src),
-            frontier_vertices=jnp.sum(
-                (bitmask.count_colors(frontier) > 0).astype(jnp.int32)),
-            frontier_colors=jnp.sum(bitmask.count_colors(frontier)),
+            frontier_vertices=jnp.sum((per_row > 0).astype(jnp.int32)),
+            frontier_colors=jnp.sum(per_row),
         )
     return next_frontier, visited, info
 
 
 def fused_step(g: Graph, frontier: jnp.ndarray, visited: jnp.ndarray,
                level: jnp.ndarray, seed: jnp.ndarray, segments=None):
-    """One level of the fused traversal.  Returns (frontier', visited', info).
+    """One level of the fused traversal on (V, W) masks.  Returns
+    (frontier', visited', info).
 
-    Level loops build the `dst_view` once and call `_expand` instead."""
-    return _expand(dst_view(g, segments), frontier, visited, level, seed)
+    Level loops build the `dst_view` once, carry the masks word-major and
+    call `_expand` instead."""
+    nf, nv, info = _expand(dst_view(g, segments), frontier.T, visited.T,
+                           level, seed)
+    return nf.T, nv.T, info
 
 
-def _tile_activity(frontier: jnp.ndarray, tile_rows: int = 128) -> jnp.ndarray:
-    """Fraction of row tiles with ≥1 active vertex (Fig. 9 analogue)."""
-    v = frontier.shape[0]
-    pad = (-v) % tile_rows
-    act = (bitmask.count_colors(frontier) > 0)
+def _tile_activity(frontier: jnp.ndarray, tile_rows: int = 128,
+                   word_axis: int = -1) -> jnp.ndarray:
+    """Fraction of row tiles with ≥1 active vertex (Fig. 9 analogue);
+    ``word_axis`` as in `bitmask.count_colors`."""
+    act = (bitmask.count_colors(frontier, word_axis) > 0)
+    pad = (-act.shape[0]) % tile_rows
     act = jnp.pad(act, (0, pad))
     tiles = act.reshape(-1, tile_rows).any(axis=1)
     return jnp.mean(tiles.astype(jnp.float32))
@@ -248,10 +283,10 @@ def run_fused(g: Graph, starts: jnp.ndarray, num_colors: int,
               seed: jnp.ndarray, max_levels: int = 64,
               segments: DstSegments | None = None) -> TraversalResult:
     """Run the fused BPT to frontier exhaustion (≤ max_levels).
-    ``segments``: `dst_segments(g.dst, V)` when the caller holds it."""
-    v = g.num_vertices
-    frontier = init_frontier(v, num_colors, starts)
-    visited = bitmask.make_mask(v, num_colors)
+    ``segments``: `dst_segments(g.dst, V)` when the caller holds it.
+    The loop carries the masks word-major (W, V); ``visited`` is (V, W)."""
+    frontier = init_frontier(g.num_vertices, num_colors, starts).T
+    visited = jnp.zeros_like(frontier)
     stats = _empty_stats(max_levels)
     view = dst_view(g, segments)
 
@@ -261,7 +296,7 @@ def run_fused(g: Graph, starts: jnp.ndarray, num_colors: int,
 
     def body(carry):
         frontier, visited, level, stats = carry
-        tile_frac = _tile_activity(frontier)
+        tile_frac = _tile_activity(frontier, word_axis=0)
         nf, nv, info = _expand(view, frontier, visited, level, seed)
         occ = jnp.where(info["frontier_vertices"] > 0,
                         info["frontier_colors"].astype(jnp.float32)
@@ -288,9 +323,9 @@ def run_fused(g: Graph, starts: jnp.ndarray, num_colors: int,
         cond, body, (frontier, visited, jnp.int32(0), stats))
     # Vertices still on the frontier at the level cap count as visited (their
     # colors have reached them even if not expanded further).
-    visited = visited | frontier
-    stats = dataclasses.replace(stats,
-                                truncated_colors=live_colors(frontier))
+    visited = (visited | frontier).T
+    stats = dataclasses.replace(
+        stats, truncated_colors=live_colors(frontier, word_axis=0))
     return TraversalResult(visited=visited, stats=stats)
 
 
@@ -309,12 +344,16 @@ def run_fused_block(g: Graph, starts: jnp.ndarray, seeds: jnp.ndarray,
     (levels, truncated): its ``levels_run``, and the colors still on the
     frontier when the loop stopped (> 0 only when ``max_levels`` cut the
     batch).  ``segments`` as in `run_fused`.
+
+    Each batch's level loop carries its masks word-major, (W, V), and
+    transposes once on entry and once on exit (module docstring: an
+    (E, W) level would pad W = 2 to 128 lanes on TPU).
     """
     view = dst_view(g, segments)
 
     def one(args):
         st, sd = args
-        frontier = init_frontier(g.num_vertices, num_colors, st)
+        frontier = init_frontier(g.num_vertices, num_colors, st).T
         visited = jnp.zeros_like(frontier)
 
         def cond(c):
@@ -330,8 +369,8 @@ def run_fused_block(g: Graph, starts: jnp.ndarray, seeds: jnp.ndarray,
         fr, vis, levels, fused, unfused = jax.lax.while_loop(
             cond, body,
             (frontier, visited, jnp.int32(0), jnp.int32(0), jnp.int32(0)))
-        return (vis | fr, jnp.stack([fused, unfused]),
-                jnp.stack([levels, live_colors(fr)]))
+        return ((vis | fr).T, jnp.stack([fused, unfused]),
+                jnp.stack([levels, live_colors(fr, word_axis=0)]))
 
     return jax.lax.map(one, (starts, seeds))
 

@@ -42,10 +42,12 @@ def run_batch(g: csr.Graph, starts, seed, num_colors: int,
               max_levels: int = 64, segments=None):
     """One fused batch as a jit-friendly pure function of (graph, starts,
     seed) — the unit that sample parallelism vmaps/shards.  ``segments``:
-    `traversal.dst_segments(g.dst, V)` when the caller holds it."""
+    `traversal.dst_segments(g.dst, V)` when the caller holds it.  The
+    level loop carries the masks word-major (W, V), as `_expand` takes
+    them; the result is (V, W)."""
     from repro.core.traversal import _expand, dst_view
 
-    frontier = init_frontier(g.num_vertices, num_colors, starts)
+    frontier = init_frontier(g.num_vertices, num_colors, starts).T
     visited = jnp.zeros_like(frontier)
     view = dst_view(g, segments)
 
@@ -60,7 +62,7 @@ def run_batch(g: csr.Graph, starts, seed, num_colors: int,
 
     fr, vis, _ = jax.lax.while_loop(
         cond, body, (frontier, visited, jnp.int32(0)))
-    return vis | fr
+    return (vis | fr).T
 
 
 def sample_parallel_fn(g: csr.Graph, all_starts, batch_seeds,

@@ -8,13 +8,15 @@ lacks, more VMEM than a kernel may use.  T = 128 tiles, 64 colors (W = 2),
 as the samplers and the query engine use them.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import sparse, tiled_traversal, tiles
+from repro.core import sparse, tiled_traversal, tiles, traversal
+from repro.graph import csr
 from repro.kernels import coverage, fused_expand, lt_select_expand
 
 T, C, W = 128, 64, 2
@@ -114,3 +116,72 @@ def test_sparse_grid_traversal_compiles(one_chip, diffusion):
         lowered = tiled_traversal.run_fused_tiled.lower(
             tg, starts, C, seed, **kw)
     _assert_tpu_kernel(lowered)
+
+
+# `%name = u32[2,65536]{1,0:T(2,128)S(1)} copy(%operand), metadata=...`
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\](\{[^}]*\})? "
+                    r"(\S+?)\(([^)]*)\)(.*)$")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) .*\{$")
+
+
+def _edge_buffers(text, e, w):
+    """(padded, relaid) of an optimized TPU program: the ops, outside
+    fusion bodies, that define an edge-length u32 buffer with the word
+    axis minor — `u32[E,W]{1,0:...}` or `u32[W,E]{0,1:...}` — and the
+    copies that move an edge-length array into another layout or tiling
+    (memory space `S(n)` aside).  Each op as (name, opcode, op_name)."""
+    fused = set(re.findall(r"calls=%([\w.\-]+)", text))
+    ops, layout, computation = [], {}, None
+    for line in text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            computation = head[1]
+            continue
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        layout[m[1]] = re.sub(r"S\(\d+\)", "", m[4] or "")
+        if computation not in fused:
+            op_name = re.search(r'op_name="([^"]*)"', m[7])
+            ops.append((m, op_name[1] if op_name else ""))
+    minor = {f"{e},{w}": "{1,0:", f"{w},{e}": "{0,1:"}
+    padded = [(m[1], m[5], n) for m, n in ops if m[2] == "u32"
+              and (m[4] or "").startswith(minor.get(m[3], "-"))]
+    relaid = [(m[1], m[5], n) for m, n in ops
+              if m[5] == "copy" and str(e) in m[3].split(",")
+              and layout[m[1]] != layout.get(m[6].lstrip("%"))]
+    return padded, relaid
+
+
+def test_dense_level_keeps_edge_arrays_lane_dense(one_chip):
+    """`run_fused_block`'s dense level works word-major, (W, E): no
+    edge-length array has the word axis (W = 2) as its minor dimension,
+    which the (8, 128) tiling pads to 128 lanes (`u32[E,2]{1,0:T(8,128)}`),
+    and no copy moves an edge-length array into another layout — with one
+    exception, the source-word row gather `frontier[:, src]`
+    (`bpt.gather`), whose output the compiler writes padded and relays
+    once.  One 1-D gather per word would avoid both, but costs far more
+    per index on the chip (PERF.md §5).  The edge-major level pads the RNG
+    words and the AND besides, and relays them, and fails.
+
+    E = 2**20 at 16 edges a vertex, Graph500's edge factor: below about
+    2**19 edges the compiler also relays the OR scan's accumulator for
+    its row pick, which it does not at the benchmark's 7.6M edges; from
+    2**19 up the program has the benchmark's ops and layouts."""
+    v, e, b = 2 ** 16, 2 ** 20, 4
+    ints, words = jnp.int32, jnp.uint32
+    indptr, src, dst, prob, order, rank, last, max_rank, starts, seeds = (
+        _shapes(one_chip, ((v + 1,), ints), ((e,), ints), ((e,), ints),
+                ((e,), jnp.float32), ((e,), ints), ((e,), ints),
+                ((v,), ints), ((), ints), ((b, C), ints), ((b,), words)))
+    g = csr.Graph(indptr=indptr, src=src, dst=dst, prob=prob,
+                  num_vertices=v, num_edges=e)
+    seg = traversal.DstSegments(order, rank, last, max_rank)
+    text = traversal.run_fused_block.lower(
+        g, starts, seeds, C, max_levels=1024,
+        segments=seg).compile().as_text()
+
+    padded, relaid = _edge_buffers(text, e, W)
+    gather = "/bpt.gather/gather"
+    assert [n.endswith(gather) for _, _, n in padded] == [True], padded
+    assert [n.endswith(gather) for _, _, n in relaid] == [True], relaid

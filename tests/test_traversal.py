@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core import bitmask, traversal
+from repro.core import bitmask, rng, traversal
 from repro.graph import csr, generators
 
 
@@ -153,20 +153,25 @@ def test_stats_occupancy_bounds(small_graph):
     assert (frac >= 0).all() and (frac <= 1.0).all()
 
 
-def test_run_fused_block_matches_per_batch(small_graph):
+@pytest.mark.parametrize("n_colors", [64, 32, 128])
+def test_run_fused_block_matches_per_batch(small_graph, n_colors):
     """The fused multi-batch sweep (ONE lax.map dispatch — the pool-build
     fast path) must reproduce per-batch run_fused exactly: visited masks
-    AND summed edge-visit counters."""
+    AND summed edge-visit counters, levels and truncated colors — at one,
+    two and four words, so the word-major level loop is checked at every
+    width W it transposes."""
     starts = jnp.stack([
         traversal.random_starts(jax.random.key(k), small_graph.num_vertices,
-                                64) for k in range(3)])
+                                n_colors) for k in range(3)])
     seeds = jnp.asarray([7, 8, 9], jnp.uint32)
     vis, visits, counts = traversal.run_fused_block(small_graph, starts,
-                                                    seeds, 64)
+                                                    seeds, n_colors)
+    assert vis.shape == (3, small_graph.num_vertices,
+                         bitmask.num_words(n_colors))
     fused, unfused = np.asarray(visits).T
     levels, truncated = np.asarray(counts).T
     for i in range(3):
-        ref = traversal.run_fused(small_graph, starts[i], 64, seeds[i])
+        ref = traversal.run_fused(small_graph, starts[i], n_colors, seeds[i])
         np.testing.assert_array_equal(np.asarray(vis[i]),
                                       np.asarray(ref.visited))
         assert int(fused[i]) == int(np.asarray(
@@ -175,6 +180,40 @@ def test_run_fused_block_matches_per_batch(small_graph):
             ref.stats.unfused_edge_visits, np.int64).sum())
         assert int(levels[i]) == int(ref.stats.levels_run)
         assert int(truncated[i]) == int(ref.stats.truncated_colors)
+
+
+def _edge_major_level(g, frontier, visited, level, seed):
+    """One level written edge-major in CSR order — (E, W) gathers and RNG
+    words, a sort-based OR — independent of `_expand`'s layout."""
+    visited = visited | frontier
+    fr_src = frontier[g.src]                                # (E, W)
+    edge_ids = jnp.arange(g.padded_edges, dtype=jnp.uint32)
+    rand = jnp.stack([rng.bernoulli_word(seed, jnp.uint32(level), edge_ids,
+                                         jnp.uint32(w), g.prob)
+                      for w in range(frontier.shape[1])], axis=1)
+    nf = traversal._scatter_or(jnp.zeros_like(visited), g.dst,
+                               fr_src & rand) & ~visited
+    return nf, visited
+
+
+@pytest.mark.parametrize("n_colors", [32, 64, 128])
+def test_word_major_level_matches_edge_major(small_graph, n_colors):
+    """The dense level's (W, V) / (W, E) layout draws the same bits, keyed
+    by (seed, level, CSR edge id, color), as the edge-major formulation:
+    frontier and visited agree level by level."""
+    starts = traversal.random_starts(jax.random.key(3),
+                                     small_graph.num_vertices, n_colors)
+    fr = traversal.init_frontier(small_graph.num_vertices, n_colors, starts)
+    vis = jnp.zeros_like(fr)
+    ref_fr, ref_vis = fr, vis
+    for level in range(6):
+        fr, vis, _ = traversal.fused_step(small_graph, fr, vis,
+                                          jnp.int32(level), SEED)
+        ref_fr, ref_vis = _edge_major_level(small_graph, ref_fr, ref_vis,
+                                            level, SEED)
+        np.testing.assert_array_equal(np.asarray(fr), np.asarray(ref_fr))
+        np.testing.assert_array_equal(np.asarray(vis), np.asarray(ref_vis))
+    assert int(bitmask.count_colors(vis).sum()) > n_colors
 
 
 def test_run_fused_lt_block_matches_per_batch(small_graph):
