@@ -34,9 +34,9 @@ class RRRBatch:
     ``unfused_edge_visits``, ``levels``, ``truncated_colors``), or a
     callable that returns them when first read, so that a block's
     counters can stay on the device until then.  They are -1 on paths
-    that do not count them: the edge visits on the tiled, kernel, LT,
-    data_parallel and graph_parallel paths (only the IC sweep tracks
-    them); ``levels`` (levels the traversal ran) and ``truncated_colors``
+    that do not count them: the edge visits on the tiled, kernel,
+    data_parallel and graph_parallel paths (the dense and sparse CSR
+    sweeps, IC and LT, track them); ``levels`` (levels the traversal ran) and ``truncated_colors``
     (colors still on the frontier when ``max_iters`` stopped it; 0 when
     the frontier emptied) on the data_parallel and graph_parallel paths
     and on restored snapshots.  The tiled and kernel paths count
@@ -108,8 +108,9 @@ def sample_batch(g_rev: csr.Graph, num_colors: int, master_seed: int,
     outside ``repro/sampling/`` calls this).
 
     ``model``: "ic" (Independent Cascade, the paper's evaluation model) or
-    "lt" (Linear Threshold via live-edge selection — g_rev must carry
-    LT-normalized in-weights, see core/lt.normalize_lt_weights).
+    "lt" (Linear Threshold via live-edge selection, each frontier vertex
+    picking among its own reversed-CSR row — its forward in-edges; g_rev
+    must carry LT-normalized weights, see core/lt.normalize_lt_weights).
     """
     seed = batch_seed(master_seed, batch_index)
     roots = batch_starts(g_rev.num_vertices, num_colors, master_seed,

@@ -241,7 +241,8 @@ def _sparse_step(fidx: FrontierIndex, frontier, visited, level, seed,
     ``u = None`` selects the IC per-(edge, color, level) Bernoulli gate;
     an ``(V, W·32)`` LT uniform table (`kernels.ref.lt_selection_uniforms`)
     selects the fixed live-edge gate instead (level-independent, computed
-    once per traversal by the caller).
+    once per traversal by the caller), read at each edge's SOURCE row:
+    on the reversed graph the source is the vertex that picks (`core.lt`).
     """
     num_words = frontier.shape[1]
     act = row_block_activity(frontier, fidx.tile_rows)
@@ -263,7 +264,7 @@ def _sparse_step(fidx: FrontierIndex, frontier, visited, level, seed,
                     out_axes=-1)(word_ids)               # (K, EB, W)
             else:
                 cbt = fidx.blk_cb[ids]
-                ug = u[d]                                # (K, EB, W·32)
+                ug = u[s]                                # (K, EB, W·32)
                 sel = jnp.logical_and(ug >= cbt[..., None],
                                       ug < (cbt + p)[..., None])
                 gate = rng.pack_bool_word(
@@ -340,10 +341,11 @@ def run_fused_lt_sparse(fidx: FrontierIndex, starts, num_colors: int, seed,
                         max_levels: int = 64,
                         ladder: tuple[int, ...] | None = None):
     """`lt.run_fused_lt` on the sparse-frontier engine: (visited (V, W),
-    levels, truncated colors) as `lt.lt_traversal_program` returns them.
+    levels, truncated colors, (fused, unfused) edge visits) as
+    `lt.lt_traversal_program` returns them.
 
     The LT live-edge selection is recomputed per gathered edge from the
-    level-independent uniform table — the same (seed, 0x17, dst, color)
+    level-independent uniform table — the same (seed, 0x17, src, color)
     counters as `lt.selection_mask_from_cb`, so the result is bit-identical
     to the dense LT sweep without ever materializing the (E, W) mask.
     """
@@ -360,19 +362,21 @@ def run_fused_lt_sparse(fidx: FrontierIndex, starts, num_colors: int, seed,
     visited = jnp.zeros_like(frontier)
 
     def cond(carry):
-        fr, _, level = carry
+        fr, _, level, _, _ = carry
         return jnp.logical_and(bitmask.any_set(fr), level < max_levels)
 
     def body(carry):
-        fr, vis, level = carry
+        fr, vis, level, fused, unfused = carry
         vis = vis | fr
-        nf, _, _, _ = _sparse_step(fidx, fr, vis, level.astype(jnp.uint32),
-                                   seed, ladder, u=u)
-        return nf, vis, level + 1
+        nf, f, uf, _ = _sparse_step(fidx, fr, vis, level.astype(jnp.uint32),
+                                    seed, ladder, u=u)
+        return nf, vis, level + 1, fused + f, unfused + uf
 
-    fr, vis, levels = jax.lax.while_loop(cond, body,
-                                         (frontier, visited, jnp.int32(0)))
-    return vis | fr, levels, live_colors(fr)
+    zero = jnp.int32(0)
+    fr, vis, levels, fused, unfused = jax.lax.while_loop(
+        cond, body, (frontier, visited, zero, zero, zero))
+    return (vis | fr, levels, live_colors(fr),
+            jnp.stack([fused, unfused]).astype(jnp.int32))
 
 
 @partial(jax.jit, static_argnames=("num_colors", "max_levels", "ladder",
@@ -386,17 +390,15 @@ def sparse_block(fidx: FrontierIndex, starts, seeds, num_colors: int,
 
     starts (B, C) int32, seeds (B,) uint32 → (visited (B, V, W),
     edge_visits (B, 2), level_counts (B, 2)), the outputs of
-    `traversal.run_fused_block` — LT carries the -1 "not instrumented"
-    sentinel in the edge visits like the dense LT path.
+    `traversal.run_fused_block`, for IC and LT alike.
     """
     def one(args):
         st, sd = args
         if diffusion == "lt":
-            vis, levels, truncated = run_fused_lt_sparse(
+            vis, levels, truncated, visits = run_fused_lt_sparse(
                 fidx, st, num_colors, sd, max_levels=max_levels,
                 ladder=ladder)
-            return (vis, jnp.full((2,), -1, jnp.int32),
-                    jnp.stack([levels, truncated]))
+            return vis, visits, jnp.stack([levels, truncated])
         res = run_fused_sparse(fidx, st, num_colors, sd,
                                max_levels=max_levels, ladder=ladder)
         return (res.visited,

@@ -100,15 +100,13 @@ def run_fused_lt_tiled(tg: tiles.TiledGraph, cb_tiles, starts,
     fr0 = tiles.pad_mask_rows(
         init_frontier(tg.num_vertices, num_colors, starts), vp)
     visited = jnp.zeros_like(fr0)
-    # Selection uniforms are level-independent: ONE table per traversal.
-    u = kref.lt_selection_uniforms(jnp.uint32(seed), vp, num_colors)
-    u_t = u.T if use_kernel else None          # the kernel's lane-row layout
+    seed = jnp.uint32(seed)
 
     def expand_tiles(p, cbt, ts, td, fi, fr, vis):
         if use_kernel:
-            return lse.lt_select_expand(p, cbt, ts, td, fi, fr, vis, u_t,
+            return lse.lt_select_expand(p, cbt, ts, td, fi, fr, vis, seed,
                                         interpret=interpret)
-        return kref.lt_select_expand_ref(p, cbt, ts, td, fr, vis, u)
+        return kref.lt_select_expand_ref(p, cbt, ts, td, fr, vis, seed)
 
     if frontier == "sparse":
         if ladder is None:

@@ -14,9 +14,10 @@ Two orthogonal axes, composable on one mesh:
 ``graph_parallel_block`` composes the two on ONE mesh: batches sharded over
 ``data``, rows over ``model``, every collective naming only the model axis
 — the program behind the `repro.sampling` ``graph_parallel`` backend (IC
-and LT; LT derives its live-edge selection shard-locally from global
-destination ids — one local-rows-sized uniform table per traversal — so
-no (E, W) selection mask is ever replicated).
+and LT; LT derives its live-edge selection shard-locally from the global
+ids of its tiles' source rows, hashed per tile — so neither an (E, W)
+selection mask nor a uniform table over every vertex is ever
+replicated).
 
 All paths reuse the exact single-device expansion math (coupled RNG), so
 distributed results are bit-for-bit equal to single-device runs; tests
@@ -314,14 +315,15 @@ def _butterfly_exchange(fr, axis: str, num_shards: int, n: int, k: int):
     return buf_i, buf_w, sent
 
 
-def _local_expand(ptg_local, diffusion: str, cb_local, seed, dst_block_base,
-                  num_colors: int, *, use_kernel: bool, interpret: bool):
+def _local_expand(ptg_local, diffusion: str, cb_local, seed, *,
+                  use_kernel: bool, interpret: bool):
     """Per-shard expansion closure over the shard's (leading-dim-1) tile
     stacks: IC draws per-(edge, color, level) Bernoullis keyed by CSR edge
-    id; LT derives the fixed live-edge selection from GLOBAL destination
-    vertex ids (``dst_block_base`` rebases the shard's local blocks), with
-    the level-independent uniform table built ONCE here — before the level
-    loop — and reused by every level's expansion.
+    id; LT derives the fixed live-edge selection from the GLOBAL ids of
+    the tiles' source rows (the picking vertices, `core.lt`; the
+    expansion reads them from the all-gathered frontier), each tile
+    hashing its own source block's uniforms, so a shard's memory follows
+    its tiles and not the whole graph's vertex count.
 
     ``use_kernel=True`` runs each shard's partitioned tile stack through
     the Pallas tile kernels (`fused_expand` / `lt_select_expand`) instead
@@ -333,21 +335,16 @@ def _local_expand(ptg_local, diffusion: str, cb_local, seed, dst_block_base,
     identical either way."""
     if diffusion == "lt":
         from repro.kernels import lt_select_expand as lse
-        rows = ptg_local.blocks_per_shard * ptg_local.tile_size
-        u = kref.lt_selection_uniforms(
-            seed, rows, num_colors,
-            row_base=dst_block_base * ptg_local.tile_size)
-        u_t = u.T if use_kernel else None      # the kernel's lane-row layout
 
         def expand(fr_global, vis_local, level):
             if use_kernel:
                 return lse.lt_select_expand(
                     ptg_local.prob[0], cb_local[0], ptg_local.tile_src[0],
                     ptg_local.tile_dst[0], ptg_local.first_of_dst[0],
-                    fr_global, vis_local, u_t, interpret=interpret)
+                    fr_global, vis_local, seed, interpret=interpret)
             return kref.lt_select_expand_ref(
                 ptg_local.prob[0], cb_local[0], ptg_local.tile_src[0],
-                ptg_local.tile_dst[0], fr_global, vis_local, u)
+                ptg_local.tile_dst[0], fr_global, vis_local, seed)
     else:
         from repro.kernels import fused_expand as fe
 
@@ -380,10 +377,8 @@ def graph_parallel_traversal(ptg: part_lib.PartitionedTiledGraph,
     seed = jnp.uint32(seed)
 
     def body(ptg_local, frontier_local):
-        base = (jax.lax.axis_index(axis).astype(jnp.int32)
-                * ptg_local.blocks_per_shard)
-        expand = _local_expand(ptg_local, "ic", None, seed, base,
-                               num_colors, use_kernel=False, interpret=False)
+        expand = _local_expand(ptg_local, "ic", None, seed,
+                               use_kernel=False, interpret=False)
         vis, levels, _ = _frontier_gather_loop(expand, frontier_local,
                                                max_levels, axis,
                                                num_shards=ptg.num_shards)
@@ -492,7 +487,7 @@ def _build_graph_parallel_block(ptg, mesh, *, data_axis, model_axis,
             fr = tiles.pad_mask_rows(init_frontier(v, num_colors, starts), vp)
             fr_local = jax.lax.dynamic_slice_in_dim(fr, base * tile, rows)
             expand = _local_expand(ptg_local, diffusion, cb_local, seed,
-                                   base, num_colors, use_kernel=use_kernel,
+                                   use_kernel=use_kernel,
                                    interpret=interpret)
             vis, _, gw = _frontier_gather_loop(
                 expand, fr_local, max_levels, model_axis,

@@ -38,7 +38,15 @@ Backends:
 
 LT diffusion: the facade owns live-edge weight normalization
 (`lt.normalize_lt_weights`, idempotent) on the reversed graph, so consumers
-can hand any IC-weighted graph to an LT sampler.
+can hand any IC-weighted graph to an LT sampler.  On the reversed graph the
+vertex that picks its live in-edge is the edge's SOURCE, the vertex on the
+frontier: its reversed-CSR row is its forward in-edges, so the weights are
+normalized over that row, the selection CDF (``cb``,
+`lt.selection_cum_before`, built once per sampler) runs along it in
+ascending forward-source order, and the pick's uniform is hashed on
+(stream seed, 0x17, frontier vertex, color).  Both host steps run under the
+span ``sampling.lt_prepare``.  Every LT backend follows that rule to the
+bit, and `bench/reference/lt.py` states it without this code.
 """
 from __future__ import annotations
 
@@ -55,6 +63,20 @@ from repro.graph import csr
 from repro.sampling.spec import SamplerSpec
 
 __all__ = ["Sampler", "make_sampler"]
+
+
+def _lt_normalized(g_rev: csr.Graph) -> csr.Graph:
+    """`lt.normalize_lt_weights` under the host span
+    ``sampling.lt_prepare``."""
+    with obs.span("sampling.lt_prepare", step="normalize"):
+        return lt.normalize_lt_weights(g_rev)
+
+
+def _lt_cum_before(g_rev: csr.Graph) -> np.ndarray:
+    """`lt.selection_cum_before` under the host span
+    ``sampling.lt_prepare``."""
+    with obs.span("sampling.lt_prepare", step="cum_before"):
+        return lt.selection_cum_before(g_rev)
 
 
 class Sampler:
@@ -75,7 +97,7 @@ class Sampler:
         g_rev = g_rev if g_rev is not None else csr.transpose(g)
         if spec.diffusion == "lt":
             # Idempotent: an already-normalized graph passes through.
-            g_rev = lt.normalize_lt_weights(g_rev)
+            g_rev = _lt_normalized(g_rev)
         self.g_rev = g_rev
 
     # ------------------------------------------------------------ RNG
@@ -136,7 +158,7 @@ class Sampler:
                 or getattr(self, "_fidx", None) is None):
             return False
         if spec.diffusion == "lt":
-            g_rev = lt.normalize_lt_weights(g_rev)   # idempotent
+            g_rev = _lt_normalized(g_rev)   # idempotent
         if not _same_edge_layout(self.g_rev, g_rev):
             return False
         from repro.core import sparse
@@ -144,7 +166,7 @@ class Sampler:
         self.g_rev = g_rev
         cb = None
         if spec.diffusion == "lt":
-            self._cb = jnp.asarray(lt.selection_cum_before(self.g_rev))
+            self._cb = jnp.asarray(_lt_cum_before(self.g_rev))
             cb = np.asarray(self._cb)
         self._fidx = sparse.patch_frontier_index(
             self._fidx, self.g_rev, touched_row_blocks, cb=cb)
@@ -205,9 +227,8 @@ class DenseSampler(Sampler):
     refreshes stop paying per-batch dispatch, and reads the block's
     counters back with one ``jax.device_get`` when the first of them is
     read (`_BlockCounts`), so ``sample_many`` returns without waiting for
-    the block.  Every batch counts its levels and truncated colors; IC
-    batches keep real edge-visit totals, LT carries the usual -1
-    sentinel.
+    the block.  Every batch, IC or LT, counts its (fused, unfused) edge
+    visits, its levels and its truncated colors.
     """
 
     def __init__(self, g, spec, *, g_rev=None):
@@ -219,7 +240,7 @@ class DenseSampler(Sampler):
     # ----------------------------------------------------- lazy indexes
     def _lt_cb(self):
         if self._cb is None:
-            self._cb = jnp.asarray(lt.selection_cum_before(self.g_rev))
+            self._cb = jnp.asarray(_lt_cum_before(self.g_rev))
         return self._cb
 
     def _frontier_index(self):
@@ -237,12 +258,13 @@ class DenseSampler(Sampler):
             starts = self.batch_starts(batch_index)
             seed = self.batch_seed(batch_index)
             if self.spec.diffusion == "lt":
-                visited, levels, truncated = sparse.run_fused_lt_sparse(
-                    fidx, starts, self.spec.num_colors, seed,
-                    max_levels=self.spec.max_iters, ladder=self._ladder)
-                return rrr.RRRBatch(visited, np.asarray(starts),
-                                    int(batch_index),
-                                    (-1, -1, int(levels), int(truncated)))
+                visited, levels, truncated, visits = \
+                    sparse.run_fused_lt_sparse(
+                        fidx, starts, self.spec.num_colors, seed,
+                        max_levels=self.spec.max_iters, ladder=self._ladder)
+                return rrr.RRRBatch(
+                    visited, np.asarray(starts), int(batch_index),
+                    (*map(int, visits), int(levels), int(truncated)))
             res = sparse.run_fused_sparse(
                 fidx, starts, self.spec.num_colors, seed,
                 max_levels=self.spec.max_iters, ladder=self._ladder)
@@ -324,8 +346,8 @@ class TiledSampler(Sampler):
     """Block-sparse tile path (jnp oracle or Pallas kernel).
 
     The tile layout is built once per sampler from the reversed graph; the
-    counter RNG is keyed by *CSR edge id* (IC) / global destination vertex
-    (LT selection), so results stay bit-identical to the dense path.
+    counter RNG is keyed by *CSR edge id* (IC) / the edge's global source
+    vertex (LT selection), so results stay bit-identical to the dense path.
     Requires a parallel-edge-free graph
     (``csr.from_edges(..., dedupe=True)``).
 
@@ -340,7 +362,7 @@ class TiledSampler(Sampler):
         # LT carries the selection-CDF prefixes alongside the tiles (the
         # per-graph host precompute, done once like the layout itself).
         self._cb_tiles = (jnp.asarray(tiles.edge_values_to_tiles(
-            self.tg_rev, lt.selection_cum_before(self.g_rev)))
+            self.tg_rev, _lt_cum_before(self.g_rev)))
             if spec.diffusion == "lt" else None)
         if spec.frontier == "sparse":
             from repro.core import sparse
@@ -487,7 +509,7 @@ class DataParallelSampler(_BlockSampler):
                              f"{mesh.axis_names}")
         self.mesh = mesh
         self.axis = spec.mesh_axis
-        self._cb = (jnp.asarray(lt.selection_cum_before(self.g_rev))
+        self._cb = (jnp.asarray(_lt_cum_before(self.g_rev))
                     if spec.diffusion == "lt" else None)
         if spec.frontier == "sparse":
             self._fidx, self._ladder = self._sparse_index(
@@ -590,7 +612,7 @@ class GraphParallelSampler(_BlockSampler):
         self._cb_tiles = None
         if spec.diffusion == "lt":
             cb = tiles.edge_values_to_tiles(
-                tg, lt.selection_cum_before(self.g_rev))
+                tg, _lt_cum_before(self.g_rev))
             self._cb_tiles = jnp.asarray(part_lib.partition_tile_values(
                 tg, self.ptg.num_shards, cb))
         # Rebind fast path: the tile layout and shard assignment are pure
@@ -662,7 +684,7 @@ class GraphParallelSampler(_BlockSampler):
         deltas fall back to a full rebuild."""
         import dataclasses as _dc
 
-        g_rev_n = (lt.normalize_lt_weights(g_rev)
+        g_rev_n = (_lt_normalized(g_rev)
                    if self.spec.diffusion == "lt" else g_rev)
         if not _same_edge_layout(self.g_rev, g_rev_n):
             return make_sampler(g, self.spec, self.mesh, g_rev=g_rev)
@@ -678,7 +700,7 @@ class GraphParallelSampler(_BlockSampler):
             # prob > 0, so a resurrected tombstone's CDF value must land
             # and a fresh tombstone's must zero out.
             cb = np.where(prob[: self.g_rev.num_edges] > 0,
-                          np.asarray(lt.selection_cum_before(self.g_rev),
+                          np.asarray(_lt_cum_before(self.g_rev),
                                      np.float32)[: self.g_rev.num_edges],
                           np.float32(0))
             self._cb_tiles = jnp.asarray(self._partition_edge_values(cb))

@@ -32,7 +32,7 @@ BACKENDS = ("dense", "tiled", "kernel", "data_parallel", "graph_parallel")
 FRONTIERS = ("dense", "sparse")
 
 # (diffusion, backend) pairs with an implementation behind them — the
-# matrix is complete: LT's per-(dst, color) live-edge selection has its own
+# matrix is complete: LT's per-(vertex, color) live-edge selection has its own
 # Pallas kernel (`kernels.lt_select_expand`) mirroring the IC expand kernel.
 _SUPPORTED = frozenset(
     (d, b) for d in DIFFUSIONS for b in BACKENDS)

@@ -2,7 +2,7 @@
 
 The whole streaming design hangs on one invariant: **CSR edge ids are
 array positions, and the counter RNG is keyed by them** (`core.rng`
-draws per ``(seed, level, eid, word)``; LT selections per destination).
+draws per ``(seed, level, eid, word)``; LT selections per source row).
 A slot resampled at its recorded ``batch_index`` reproduces its old mask
 bit-for-bit *iff* every edge it can touch kept its id and its bits.  So
 `apply_delta` never rebuilds the edge list (``csr.from_edges`` re-sorts
@@ -32,7 +32,7 @@ is maintained as the cumulative LIVE out-degree (prob > 0) so
 order-free: the dense sweep and `core.sparse.FrontierIndex` key on the
 per-edge ``src`` array (the index argsorts internally), the tile
 layouts sort edges themselves, and `lt.selection_cum_before` groups by
-``dst``.  ``csr.transpose``/``dedupe``/``relabel`` DO renumber ids —
+``src`` and orders each row by ``dst`` itself.  ``csr.transpose``/``dedupe``/``relabel`` DO renumber ids —
 never apply them to a streamed graph; maintain the reversed graph by
 applying ``delta.reversed()`` to it directly.
 
@@ -43,8 +43,8 @@ means *tombstone* to this layer.
 Returned alongside the mutated graph, `AppliedDelta.touched_rows` is
 the conservative set of source rows whose out-edge slots changed in any
 way a traversal or its work counters can observe — the sources of every
-structural op and trimmed tombstone, and, under ``lt_normalized=True``,
-of every live in-edge of a re-normalized destination.  The
+structural op and trimmed tombstone — under ``lt_normalized=True`` those
+are also the rows it re-normalizes.  The
 population-neutral insert/trim policy above is what keeps row 0 OFF
 this list: padding slots carry ``src == 0``, so a padding-count change
 would dirty every traversal that ever activates row 0.
@@ -173,12 +173,12 @@ def apply_delta(g: csr.Graph, delta: EdgeDelta, *,
     """Apply ``delta`` to ``g`` with stable CSR edge ids (see module doc).
 
     ``lt_normalized=True`` declares ``g`` an LT-normalized reversed graph
-    (`lt.normalize_lt_weights` invariant: per-dst in-weights sum ≤ 1):
-    after the structural ops, the live in-edges of every destination the
-    delta touched are re-normalized in place with the exact
-    `normalize_lt_weights` arithmetic (float64 per-dst sums in array
-    order, ``scale = 1/max(1, Σ)``, float32 cast), confined to those
-    destinations — untouched rows keep their bytes.  Normalization is a
+    (`lt.normalize_lt_weights` invariant: each source row's weights sum
+    ≤ 1 — on the reversed graph a row is a vertex's forward in-edges):
+    after the structural ops, every source row the delta touched is
+    re-normalized in place with the exact `normalize_lt_weights`
+    arithmetic (`lt.normalized_row_weights`), confined to those rows —
+    untouched rows keep their bytes.  Normalization is a
     lossy projection (weights only ever scale DOWN): deleting an insert
     that pushed a sum past 1 does not restore the pre-insert bits unless
     the sums stayed ≤ 1 throughout.
@@ -274,20 +274,13 @@ def apply_delta(g: csr.Graph, delta: EdgeDelta, *,
         prob = prob[:e + pad_count]
 
     # --------------------------------------- confined LT re-normalization
+    # The rows it rescales are the delta's own source rows, touched above.
     if lt_normalized and len(delta):
-        affected = np.unique(delta.dst)
-        sel = np.isin(dst[:e], affected)
-        # Exact normalize_lt_weights arithmetic on the affected dsts:
-        # float64 per-dst sums accumulated in array order (tombstones add
-        # an exact +0.0), scale = 1/max(1, Σ), float32 cast.
-        p64 = prob[:e].astype(np.float64)
-        in_sum = np.zeros(v)
-        np.add.at(in_sum, dst[:e][sel], p64[sel])
-        scale = 1.0 / np.maximum(in_sum[dst[:e][sel]], 1.0)
-        prob[:e][sel] = (p64[sel] * scale).astype(np.float32)
-        # Conservative: every live in-edge of an affected dst may have
-        # been rescaled — its source row is touched.
-        touched.append(src[:e][sel & (prob[:e] > 0)])
+        from repro.core import lt
+        sel = np.isin(src[:e], delta.src)
+        prob[:e][sel] = lt.normalized_row_weights(src[:e][sel],
+                                                  dst[:e][sel],
+                                                  prob[:e][sel])
 
     # ------------------------------------------------- live-degree indptr
     live_src = src[:e][prob[:e] > 0]

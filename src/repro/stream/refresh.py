@@ -6,7 +6,7 @@ when one plan must sweep several bit-identical replicas):
 1. apply the delta to BOTH graphs of the store's pair — the forward
    graph directly, the reversed graph via ``delta.reversed()`` (never
    `csr.transpose`, which renumbers edge ids), with LT re-normalization
-   confined to the mutated destinations when the pool is LT;
+   confined to the mutated source rows when the pool is LT;
 2. map the REVERSED graph's touched source rows (traversals run on
    ``g_rev``) to `FrontierIndex` row-blocks and intersect with the
    `DirtySlotTracker` bitsets → the dirty slot set;

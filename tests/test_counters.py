@@ -138,14 +138,14 @@ def test_one_readback_per_block_on_first_read(chain, diffusion,
            for b in batches]
     assert len(reads) == 1
     assert all(levels > 0 for levels, _, _ in got)
-    assert all((fused < 0) == (diffusion == "lt") for _, _, fused in got)
+    assert all(fused > 0 for _, _, fused in got)
 
 
 @pytest.mark.parametrize("block", ["dense_ic", "dense_lt", "sparse_ic",
                                    "sparse_lt"])
 def test_block_programs_share_one_output_shape(chain, block):
     """(visited (B, V, W), edge_visits (B, 2), level_counts (B, 2)) from
-    every block program; LT's edge visits are the -1 sentinel."""
+    every block program; LT counts its edge visits as IC does."""
     frontier, diffusion = block.split("_")
     spec = sampling.SamplerSpec(diffusion=diffusion, frontier=frontier,
                                 num_colors=64, master_seed=3, max_iters=8)
@@ -165,11 +165,40 @@ def test_block_programs_share_one_output_shape(chain, block):
     assert vis.shape == (2, CHAIN, 2)
     assert visits.shape == counts.shape == (2, 2)
     assert visits.dtype == counts.dtype == np.int32
-    assert (np.asarray(visits) == -1).all() == (diffusion == "lt")
-    for b, row in zip(smp.sample_many([0, 1]), np.asarray(counts)):
+    assert (np.asarray(visits) > 0).all()
+    for b, row, pair in zip(smp.sample_many([0, 1]), np.asarray(counts),
+                            np.asarray(visits)):
         assert (b.levels, b.truncated_colors) == tuple(row)
+        assert (b.fused_edge_visits, b.unfused_edge_visits) == tuple(pair)
 
 
 def test_span_runs_without_a_profiler():
     with obs.span("store.refresh", epoch=3, first_batch=12):
         pass
+
+
+@pytest.mark.parametrize("frontier", ["dense", "sparse"])
+def test_lt_edge_visits_count_each_member_once(graph, frontier):
+    """IC's definitions on an LT walk: each (vertex, color) of a set is on
+    the frontier at exactly one level, so the unfused visits are the sum
+    over colors of the out-degrees (reversed graph) of the set's members;
+    the fused visits count an edge once a level whatever its colors, and
+    dense and sparse agree on both."""
+    spec = sampling.SamplerSpec(diffusion="lt", frontier=frontier,
+                                num_colors=64, master_seed=12,
+                                max_iters=1024)
+    smp = sampling.make_sampler(graph, spec)
+    deg = np.bincount(np.asarray(smp.g_rev.src),
+                      minlength=graph.num_vertices)
+    dense = sampling.make_sampler(graph, spec.replace(frontier="dense"))
+    for b, d in zip(smp.sample_many([0, 1, 2]),
+                    dense.sample_many([0, 1, 2])):
+        vis = np.asarray(b.visited)
+        members = sum(int(deg[np.flatnonzero((vis[:, c // 32] >> (c % 32))
+                                             & 1)].sum())
+                      for c in range(64))
+        assert b.truncated_colors == 0
+        assert b.unfused_edge_visits == members
+        assert 0 < b.fused_edge_visits <= b.unfused_edge_visits
+        assert (b.fused_edge_visits, b.unfused_edge_visits) == \
+            (d.fused_edge_visits, d.unfused_edge_visits)

@@ -110,12 +110,11 @@ def test_lt_select_expand_kernel_matches_ref(tile_size, n_colors):
     fr = tiles.pad_mask_rows(
         traversal.init_frontier(g.num_vertices, n_colors, starts),
         tg.padded_vertices)
-    u = ref.lt_selection_uniforms(jnp.uint32(5), tg.padded_vertices,
-                                  n_colors)
+    seed = jnp.uint32(5)
     out_ref = ref.lt_select_expand_ref(tg.prob, cb, tg.tile_src,
-                                       tg.tile_dst, fr, fr, u)
+                                       tg.tile_dst, fr, fr, seed)
     out_ker = lse.lt_select_expand(tg.prob, cb, tg.tile_src, tg.tile_dst,
-                                   tg.first_of_dst, fr, fr, u.T,
+                                   tg.first_of_dst, fr, fr, seed,
                                    interpret=True)
     np.testing.assert_array_equal(np.asarray(out_ref), np.asarray(out_ker))
 

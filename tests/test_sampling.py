@@ -16,6 +16,12 @@ from repro.serve.influence import (MicroBatcher, PoolConfig, QueryEngine,
                                    ResultCache, SketchStore)
 
 
+def _assert_lt_reference(graph, batches, master_seed):
+    """The LT sets equal the plain reference `bench/reference/lt.py`."""
+    from lt_reference import differing_sets, edges_of
+    assert differing_sets(edges_of(graph), batches, master_seed) == 0
+
+
 @pytest.fixture(scope="module")
 def graph():
     """Dedupe-clean graph: the tile layout (tiled/kernel backends) needs
@@ -118,6 +124,7 @@ def test_lt_tiled_bit_identical_to_dense(graph):
     for bi in (0, 3):
         ref = dense.sample(bi)
         got = tiled.sample(bi)
+        _assert_lt_reference(graph, [got], spec.master_seed)
         assert got.batch_index == bi
         np.testing.assert_array_equal(np.asarray(got.visited),
                                       np.asarray(ref.visited))
@@ -140,6 +147,7 @@ def test_lt_kernel_bit_identical_to_dense(graph):
     for bi in (0, 3):
         ref = dense_ref.sample(bi)
         got = kern.sample(bi)
+        _assert_lt_reference(graph, [got], spec.master_seed)
         np.testing.assert_array_equal(np.asarray(got.visited),
                                       np.asarray(ref.visited))
         dense_steps = kern.last_grid_steps
@@ -162,6 +170,8 @@ def test_graph_parallel_bit_identical_on_trivial_mesh(graph):
         gp = sampling.make_sampler(graph, spec, mesh=mesh)
         dense = sampling.make_sampler(graph, spec.replace(backend="dense"))
         got = gp.sample_many([0, 2])
+        if diffusion == "lt":
+            _assert_lt_reference(graph, got, spec.master_seed)
         for b in got:
             ref = dense.sample(b.batch_index)
             np.testing.assert_array_equal(np.asarray(b.visited),
@@ -169,6 +179,30 @@ def test_graph_parallel_bit_identical_on_trivial_mesh(graph):
             np.testing.assert_array_equal(b.roots, np.asarray(ref.roots))
     stacked = gp.sample_stacked([1])
     assert stacked.shape == (1, graph.num_vertices, 2)
+
+
+@pytest.mark.parametrize("use_kernel", ["0", "1"], ids=["oracle", "kernel"])
+def test_graph_parallel_lt_holds_no_vertex_uniform_table(graph, use_kernel,
+                                                         monkeypatch):
+    """A graph-parallel shard's LT program holds no float table over every
+    vertex (it would grow with the whole graph on every shard): each tile
+    hashes its own source block's uniforms, on both expansion legs."""
+    import re
+
+    monkeypatch.setenv("REPRO_GP_KERNEL", use_kernel)
+    mesh = make_mesh((1, 1), ("data", "model"))
+    spec = sampling.SamplerSpec(diffusion="lt", backend="graph_parallel",
+                                num_colors=64, master_seed=9)
+    gp = sampling.make_sampler(graph, spec, mesh=mesh)
+    _, starts, seeds = gp._block_inputs([0], gp.data_shards)
+    hlo = gp._block_fn().lower(gp.ptg, gp._cb_tiles, starts,
+                               seeds).as_text()
+    vp = gp.ptg.padded_vertices
+    assert vp > spec.tile_size
+    tables = re.findall(rf"tensor<(?:{vp}x64|64x{vp})xf32>", hlo)
+    assert not tables, tables[:3]
+    got = gp.sample_many([0])
+    _assert_lt_reference(graph, got, spec.master_seed)
 
 
 def test_mesh_backends_require_mesh_and_axes(graph):
@@ -204,6 +238,8 @@ def test_sparse_frontier_bit_identical_across_matrix(graph):
             for bi in (0, 2):
                 got = s.sample(bi)
                 want = ref.sample(bi)
+                if diffusion == "lt":
+                    _assert_lt_reference(graph, [got], spec.master_seed)
                 np.testing.assert_array_equal(np.asarray(got.visited),
                                               np.asarray(want.visited))
                 np.testing.assert_array_equal(got.roots,

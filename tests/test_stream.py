@@ -189,15 +189,16 @@ def test_touched_rows_and_blocks(graph):
     assert s0 in a.touched_rows
     blocks = touched_row_blocks(a.touched_rows, 64)
     assert s0 // 64 in blocks
-    # LT: re-normalizing dst d0 touches the sources of ALL its live
-    # in-edges, not just the deleted one.
+    # LT: re-normalizing row s0 rescales the weights of ALL its live
+    # edges, not just the deleted one; every row whose weights changed is
+    # touched.
     gn = lt_lib.normalize_lt_weights(graph)
-    _, an = apply_delta(gn, EdgeDelta.deletes([s0], [d0]),
-                        lt_normalized=True)
-    dst = np.asarray(gn.dst)[:e]
-    prob = np.asarray(gn.prob)[:e]
-    peers = set(np.asarray(gn.src)[:e][(dst == d0) & (prob > 0)].tolist())
-    assert peers - {s0} <= set(an.touched_rows.tolist())
+    gd, an = apply_delta(gn, EdgeDelta.deletes([s0], [d0]),
+                         lt_normalized=True)
+    changed = np.asarray(gd.prob)[:e] != np.asarray(gn.prob)[:e]
+    rows = set(np.asarray(gn.src)[:e][changed].tolist())
+    assert s0 in rows
+    assert rows <= set(an.touched_rows.tolist())
 
 
 def test_confined_lt_renorm_matches_full_normalize(graph):
@@ -224,10 +225,10 @@ def test_normalize_lt_weights_is_order_preserving_and_idempotent(graph):
     np.testing.assert_array_equal(np.asarray(gn.indptr),
                                   np.asarray(g1.indptr))
     e = gn.num_edges
-    in_sum = np.zeros(gn.num_vertices)
-    np.add.at(in_sum, np.asarray(gn.dst)[:e],
+    row_sum = np.zeros(gn.num_vertices)
+    np.add.at(row_sum, np.asarray(gn.src)[:e],
               np.asarray(gn.prob)[:e].astype(np.float64))
-    assert in_sum.max() <= 1.0 + 1e-6
+    assert row_sum.max() <= 1.0 + 1e-6
     _assert_graph_identical(lt_lib.normalize_lt_weights(gn), gn)
 
 

@@ -94,7 +94,7 @@ def test_lt_select_expand_compiles(one_chip, frontier_rows):
                    ((NT, T, T), jnp.float32), ((NT,), jnp.int32),
                    ((NT,), jnp.int32), ((NT,), jnp.int32),
                    ((frontier_rows, W), jnp.uint32), ((VO, W), jnp.uint32),
-                   ((W * 32, VO), jnp.float32))
+                   ((), jnp.uint32))
     _assert_tpu_kernel(jax.jit(
         lambda *a: lt_select_expand.lt_select_expand(*a, interpret=False)
     ).lower(*args))
