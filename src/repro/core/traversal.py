@@ -25,7 +25,10 @@ to and from a lane-dense layout.  Each loop transposes once on entry and
 once on exit.  One padded buffer is left: the compiler writes the source
 row gather ``frontier[:, src]`` edge-major and relays it once; one 1-D
 gather per word would avoid that, but a 1-D gather costs far more per
-index on TPU v5e (PERF.md §5).
+index on TPU v5e (PERF.md §5).  The gather costs the same per row at up
+to 128 words, so `run_fused_block` runs G batches of a block in one loop
+of G·W words (`batches_per_loop`: G·W = 8 at 64 colors, one tile's
+sublanes), and one gather a level serves all G.
 
 Level-synchronous semantics (matching the paper's Ripples port §4.2): the
 whole frontier is folded into ``visited`` first, then expansion excludes all
@@ -216,6 +219,13 @@ def _expand(view: DstView, frontier: jnp.ndarray, visited: jnp.ndarray,
     and returns ``frontier`` and ``visited`` as (W, V).  Returns
     (frontier', visited', info).
 
+    ``seed`` is one batch's () counter seed, or the (G,) seeds of a group
+    of G batches whose (W, V) masks ``frontier`` and ``visited`` stack,
+    (G·W, V).  Then word ``g·W + w`` draws as word ``w`` of batch ``g``
+    under its own seed (a (G·W,) seed vector and word ids
+    ``tile(arange(W), G)``), so every batch gets the bits of a loop of its
+    own, and each counter in ``info`` is (G,), one per batch.
+
     Every edge-length intermediate is (W, E) — the gathered source words,
     the RNG words, ``contrib``, the OR scan — or (E,), never (E, W): a
     minor dimension of W = 2 pads to 128 lanes under TPU's (8, 128)
@@ -225,18 +235,22 @@ def _expand(view: DstView, frontier: jnp.ndarray, visited: jnp.ndarray,
     Each phase runs under a ``jax.named_scope`` (``bpt.gather``,
     ``bpt.rng``, ``bpt.or_scan``, ``bpt.count``), so the device trace's
     name stack ties every op of a level to its phase."""
-    num_words = frontier.shape[0]
+    grouped = jnp.ndim(seed) == 1
+    batches = seed.shape[0] if grouped else 1
+    num_words = frontier.shape[0] // batches
     visited = visited | frontier                            # Listing 1 line 8
     with jax.named_scope("bpt.gather"):
-        fr_src = frontier[:, view.src]                      # (W, E)
+        fr_src = frontier[:, view.src]                      # (G·W, E)
     # Independent Bernoulli(p_e) per (edge, color): one packed word per
     # (word, edge) pair.  Padding edges have prob 0 → never propagate.
     with jax.named_scope("bpt.rng"):
-        word_ids = jnp.arange(num_words, dtype=jnp.uint32)
+        word_ids = jnp.tile(jnp.arange(num_words, dtype=jnp.uint32), batches)
+        word_seeds = jnp.repeat(seed, num_words) if grouped else seed
         rand = jax.vmap(
-            lambda w: rng.bernoulli_word(seed, level.astype(jnp.uint32),
-                                         view.edge_id, w, view.prob)
-        )(word_ids)                                         # (W, E)
+            lambda s, w: rng.bernoulli_word(s, level.astype(jnp.uint32),
+                                            view.edge_id, w, view.prob),
+            in_axes=(0 if grouped else None, 0),
+        )(word_seeds, word_ids)                             # (G·W, E)
     # Lines 11-13.  Masking the visited colors once per destination after
     # the OR equals masking every in-edge before it.
     with jax.named_scope("bpt.or_scan"):
@@ -244,15 +258,30 @@ def _expand(view: DstView, frontier: jnp.ndarray, visited: jnp.ndarray,
                                    view.seg, axis=1) & ~visited
 
     with jax.named_scope("bpt.count"):
-        active_src = bitmask.count_colors(fr_src, word_axis=0)  # (E,)
-        per_row = bitmask.count_colors(frontier, word_axis=0)   # (V,)
+        if grouped:                          # (G, E) and (G, V)
+            active_src = _count_by_batch(fr_src, num_words)
+            per_row = _count_by_batch(frontier, num_words)
+        else:                                # (E,) and (V,)
+            active_src = bitmask.count_colors(fr_src, word_axis=0)
+            per_row = bitmask.count_colors(frontier, word_axis=0)
         info = dict(
-            fused_visits=jnp.sum((active_src > 0).astype(jnp.int32)),
-            unfused_visits=jnp.sum(active_src),
-            frontier_vertices=jnp.sum((per_row > 0).astype(jnp.int32)),
-            frontier_colors=jnp.sum(per_row),
+            fused_visits=jnp.sum((active_src > 0).astype(jnp.int32), -1),
+            unfused_visits=jnp.sum(active_src, -1),
+            frontier_vertices=jnp.sum((per_row > 0).astype(jnp.int32), -1),
+            frontier_colors=jnp.sum(per_row, -1),
         )
     return next_frontier, visited, info
+
+
+def _count_by_batch(words: jnp.ndarray, num_words: int) -> jnp.ndarray:
+    """(G·W, N) word-major masks of G batches → (G, N) int32: each batch's
+    set bits in every column, its W rows' popcounts summed by a window of
+    W rows.  (A reshape to (G, W, N) would make the compiler relay the
+    gathered source words into a (W, 128) tiling; a window keeps them in
+    the gather's.)"""
+    return jax.lax.reduce_window(
+        bitmask.popcount(words).astype(jnp.int32), jnp.int32(0), jax.lax.add,
+        (num_words, 1), (num_words, 1), "VALID")
 
 
 def fused_step(g: Graph, frontier: jnp.ndarray, visited: jnp.ndarray,
@@ -329,13 +358,19 @@ def run_fused(g: Graph, starts: jnp.ndarray, num_colors: int,
     return TraversalResult(visited=visited, stats=stats)
 
 
+def batches_per_loop(num_batches: int, num_colors: int) -> int:
+    """G: the batches of a block that `run_fused_block` runs in one level
+    loop — as many as fill the 8 sublanes of one (8, 128) u32 tile with
+    their W words, and no more than the block holds."""
+    return min(num_batches, max(1, 8 // bitmask.num_words(num_colors)))
+
+
 @partial(jax.jit, static_argnames=("num_colors", "max_levels"))
 def run_fused_block(g: Graph, starts: jnp.ndarray, seeds: jnp.ndarray,
                     num_colors: int, max_levels: int = 64,
                     segments: DstSegments | None = None):
     """Fused multi-batch sweep: ONE dispatch traverses a whole block of
-    batches via ``lax.map`` (sequential per batch — one (V, W) transient
-    at a time — so a pool build stops paying per-batch dispatch).
+    batches, so a pool build stops paying per-batch dispatch.
 
     starts (B, C) int32 / seeds (B,) uint32 → (visited (B, V, W),
     edge_visits (B, 2), level_counts (B, 2)), all int32 but ``visited``.
@@ -345,34 +380,73 @@ def run_fused_block(g: Graph, starts: jnp.ndarray, seeds: jnp.ndarray,
     frontier when the loop stopped (> 0 only when ``max_levels`` cut the
     batch).  ``segments`` as in `run_fused`.
 
-    Each batch's level loop carries its masks word-major, (W, V), and
-    transposes once on entry and once on exit (module docstring: an
-    (E, W) level would pad W = 2 to 128 lanes on TPU).
+    The block runs in groups of G = `batches_per_loop` batches, one
+    word-major level loop of G·W words per group (`_group_loop`): every
+    level's source row gather then fills G·W of the 128 lanes the
+    compiler pads its output to (module docstring), for the whole group at
+    once.  Full groups run one after another under ``lax.map``; the last
+    B mod G batches run as one smaller loop.  Each batch's results are
+    those of a loop of its own, bit for bit.
     """
     view = dst_view(g, segments)
+    b, c = starts.shape
+    per_loop = batches_per_loop(b, num_colors)
+    full = b - b % per_loop
 
-    def one(args):
-        st, sd = args
-        frontier = init_frontier(g.num_vertices, num_colors, st).T
-        visited = jnp.zeros_like(frontier)
+    def loop(st, sd):
+        return _group_loop(view, g.num_vertices, st, sd, num_colors,
+                           max_levels)
 
-        def cond(c):
-            fr, _, lvl, _, _ = c
-            return jnp.logical_and(bitmask.any_set(fr), lvl < max_levels)
+    out = jax.lax.map(lambda a: loop(*a),
+                      (starts[:full].reshape(-1, per_loop, c),
+                       seeds[:full].reshape(-1, per_loop)))
+    out = [x.reshape(full, *x.shape[2:]) for x in out]
+    if full < b:
+        tail = loop(starts[full:], seeds[full:])
+        out = [jnp.concatenate([x, t]) for x, t in zip(out, tail)]
+    return tuple(out)
 
-        def body(c):
-            fr, vis, lvl, fused, unfused = c
-            nf, nv, info = _expand(view, fr, vis, lvl, sd)
-            return (nf, nv, lvl + 1, fused + info["fused_visits"],
-                    unfused + info["unfused_visits"])
 
-        fr, vis, levels, fused, unfused = jax.lax.while_loop(
-            cond, body,
-            (frontier, visited, jnp.int32(0), jnp.int32(0), jnp.int32(0)))
-        return ((vis | fr).T, jnp.stack([fused, unfused]),
-                jnp.stack([levels, live_colors(fr, word_axis=0)]))
+def _group_loop(view: DstView, num_vertices: int, starts: jnp.ndarray,
+                seeds: jnp.ndarray, num_colors: int, max_levels: int):
+    """G batches (starts (G, C), seeds (G,)) in one level loop, to the
+    exhaustion of the deepest (≤ max_levels): `run_fused_block`'s three
+    outputs for the G batches.
 
-    return jax.lax.map(one, (starts, seeds))
+    The loop carries the G batches' (W, V) masks stacked word-major,
+    (G·W, V), and transposes once on entry and once on exit.  A batch
+    counts a level where its own words were live; once its frontier has
+    emptied it expands nothing and counts no visits.  With G = 1 this is
+    one batch's loop, under its scalar seed."""
+    num_batches = starts.shape[0]
+    frontier = jnp.concatenate(
+        [init_frontier(num_vertices, num_colors, st).T for st in starts])
+    visited = jnp.zeros_like(frontier)
+    seed = seeds if num_batches > 1 else seeds[0]
+    zeros = jnp.zeros((num_batches,), jnp.int32)
+
+    def cond(c):
+        fr, _, lvl, *_ = c
+        return jnp.logical_and(bitmask.any_set(fr), lvl < max_levels)
+
+    def body(c):
+        fr, vis, lvl, levels, fused, unfused = c
+        nf, nv, info = _expand(view, fr, vis, lvl, seed)
+        live = (info["frontier_colors"] > 0 if num_batches > 1
+                else jnp.ones((1,), bool))
+        return (nf, nv, lvl + 1, levels + live.astype(jnp.int32),
+                fused + info["fused_visits"],
+                unfused + info["unfused_visits"])
+
+    fr, vis, _, levels, fused, unfused = jax.lax.while_loop(
+        cond, body, (frontier, visited, jnp.int32(0), zeros, zeros, zeros))
+    words = bitmask.num_words(num_colors)
+    visited = (vis | fr).reshape(num_batches, words, num_vertices)
+    truncated = bitmask.popcount(jax.lax.reduce(
+        fr, jnp.uint32(0), jax.lax.bitwise_or, (1,)))       # (G·W,)
+    truncated = truncated.reshape(num_batches, words).sum(1).astype(jnp.int32)
+    return (visited.transpose(0, 2, 1), jnp.stack([fused, unfused], 1),
+            jnp.stack([levels, truncated], 1))
 
 
 @partial(jax.jit, static_argnames=("color_id", "max_levels"))

@@ -58,7 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.core import lt, rrr, tiled_traversal, tiles
+from repro.core import lt, rrr, tiled_traversal, tiles, traversal
 from repro.graph import csr
 from repro.sampling.spec import SamplerSpec
 
@@ -116,7 +116,6 @@ class Sampler:
         survives values-only rebinds) and handed to every dense-path
         program — which then contains no sort."""
         if getattr(self, "_segments", None) is None:
-            from repro.core import traversal
             self._segments = traversal.dst_segments(self.g_rev.dst,
                                                     self.g_rev.num_vertices)
         return self._segments
@@ -221,9 +220,10 @@ class DenseSampler(Sampler):
     ladder) — bit-identical masks AND work counters, per-level cost
     proportional to the live frontier instead of E.
 
-    ``sample_many`` fuses the whole block into ONE dispatch (``lax.map``
-    over batches inside one jit — `traversal.run_fused_block` /
-    `sparse.sparse_block` / `lt.run_fused_lt_block`), so pool builds and
+    ``sample_many`` fuses the whole block into ONE dispatch (one jit —
+    `traversal.run_fused_block`, `traversal.batches_per_loop` batches a
+    level loop, / `sparse.sparse_block` / `lt.run_fused_lt_block`, a loop
+    per batch), so pool builds and
     refreshes stop paying per-batch dispatch, and reads the block's
     counters back with one ``jax.device_get`` when the first of them is
     read (`_BlockCounts`), so ``sample_many`` returns without waiting for
@@ -280,7 +280,14 @@ class DenseSampler(Sampler):
         with obs.span("sampling.block_inputs", batches=len(idx)):
             starts = jnp.stack([self.batch_starts(b) for b in idx])
             seeds = jnp.asarray(rrr.batch_seeds(spec.master_seed, idx))
-        with obs.span("sampling.dispatch", batches=len(idx)):
+        # Dense IC runs `traversal.batches_per_loop` batches a level loop;
+        # the other programs one.
+        per_loop = (traversal.batches_per_loop(len(idx), spec.num_colors)
+                    if spec.frontier != "sparse" and spec.diffusion != "lt"
+                    else 1)
+        with obs.span("sampling.dispatch", batches=len(idx),
+                      batches_per_loop=per_loop,
+                      loops=-(-len(idx) // per_loop)):
             if spec.frontier == "sparse":
                 from repro.core import sparse
                 fidx = self._frontier_index()
@@ -293,7 +300,6 @@ class DenseSampler(Sampler):
                     spec.num_colors, max_levels=spec.max_iters,
                     segments=self._dst_segments())
             else:
-                from repro.core import traversal
                 vis, visits, counts = traversal.run_fused_block(
                     self.g_rev, starts, seeds, spec.num_colors,
                     max_levels=spec.max_iters,

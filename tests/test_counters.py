@@ -8,6 +8,8 @@ and a cap of L levels leaves the colors with r ≥ L on the frontier.  Both
 hold under IC (p = 1) and LT (one in-edge of weight 1)."""
 from __future__ import annotations
 
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -170,6 +172,28 @@ def test_block_programs_share_one_output_shape(chain, block):
                             np.asarray(visits)):
         assert (b.levels, b.truncated_colors) == tuple(row)
         assert (b.fused_edge_visits, b.unfused_edge_visits) == tuple(pair)
+
+
+@pytest.mark.parametrize("diffusion,batches,per_loop,loops", [
+    ("ic", 4, 4, 1), ("ic", 5, 4, 2), ("ic", 1, 1, 1), ("lt", 3, 1, 3)])
+def test_dispatch_span_says_how_batches_share_a_loop(
+        chain, monkeypatch, diffusion, batches, per_loop, loops):
+    """The ``sampling.dispatch`` host span carries the batches a level
+    loop runs (dense IC: as many as fill a tile's 8 sublanes) and the
+    loops the block ran."""
+    seen = []
+
+    def span(name, **args):
+        seen.append((name, args))
+        return contextlib.nullcontext()
+
+    monkeypatch.setattr(obs, "span", span)
+    spec = sampling.SamplerSpec(diffusion=diffusion, num_colors=64,
+                                master_seed=3, max_iters=8)
+    sampling.make_sampler(chain, spec).sample_many(range(batches))
+    (args,) = [a for n, a in seen if n == "sampling.dispatch"]
+    assert args == dict(batches=batches, batches_per_loop=per_loop,
+                        loops=loops)
 
 
 def test_span_runs_without_a_profiler():

@@ -154,15 +154,20 @@ def _edge_buffers(text, e, w):
 
 
 def test_dense_level_keeps_edge_arrays_lane_dense(one_chip):
-    """`run_fused_block`'s dense level works word-major, (W, E): no
-    edge-length array has the word axis (W = 2) as its minor dimension,
-    which the (8, 128) tiling pads to 128 lanes (`u32[E,2]{1,0:T(8,128)}`),
-    and no copy moves an edge-length array into another layout — with one
+    """`run_fused_block`'s dense level works word-major, (G·W, E): no
+    edge-length array has the word axis as its minor dimension, which the
+    (8, 128) tiling pads to 128 lanes (`u32[E,8]{1,0:T(8,128)}`), and no
+    copy moves an edge-length array into another layout — with one
     exception, the source-word row gather `frontier[:, src]`
     (`bpt.gather`), whose output the compiler writes padded and relays
     once.  One 1-D gather per word would avoid both, but costs far more
     per index on the chip (PERF.md §5).  The edge-major level pads the RNG
     words and the AND besides, and relays them, and fails.
+
+    A block of b = 4 batches at 64 colors runs as one loop of G = 4
+    batches, 8 words wide (`traversal.batches_per_loop`), so that one
+    gather fills 8 of the padded buffer's 128 lanes; its scratch stays
+    within 10 % of the one-batch, 2-word program's at the same E.
 
     E = 2**20 at 16 edges a vertex, Graph500's edge factor: below about
     2**19 edges the compiler also relays the OR scan's accumulator for
@@ -170,18 +175,27 @@ def test_dense_level_keeps_edge_arrays_lane_dense(one_chip):
     2**19 up the program has the benchmark's ops and layouts."""
     v, e, b = 2 ** 16, 2 ** 20, 4
     ints, words = jnp.int32, jnp.uint32
-    indptr, src, dst, prob, order, rank, last, max_rank, starts, seeds = (
-        _shapes(one_chip, ((v + 1,), ints), ((e,), ints), ((e,), ints),
-                ((e,), jnp.float32), ((e,), ints), ((e,), ints),
-                ((v,), ints), ((), ints), ((b, C), ints), ((b,), words)))
+    indptr, src, dst, prob, order, rank, last, max_rank = _shapes(
+        one_chip, ((v + 1,), ints), ((e,), ints), ((e,), ints),
+        ((e,), jnp.float32), ((e,), ints), ((e,), ints), ((v,), ints),
+        ((), ints))
     g = csr.Graph(indptr=indptr, src=src, dst=dst, prob=prob,
                   num_vertices=v, num_edges=e)
     seg = traversal.DstSegments(order, rank, last, max_rank)
-    text = traversal.run_fused_block.lower(
-        g, starts, seeds, C, max_levels=1024,
-        segments=seg).compile().as_text()
 
-    padded, relaid = _edge_buffers(text, e, W)
+    def compiled(num_batches):
+        starts, seeds = _shapes(one_chip, ((num_batches, C), ints),
+                                ((num_batches,), words))
+        return traversal.run_fused_block.lower(
+            g, starts, seeds, C, max_levels=1024, segments=seg).compile()
+
+    fused = compiled(b)
+    width = traversal.batches_per_loop(b, C) * W
+    assert width == 8
+    padded, relaid = _edge_buffers(fused.as_text(), e, width)
     gather = "/bpt.gather/gather"
     assert [n.endswith(gather) for _, _, n in padded] == [True], padded
     assert [n.endswith(gather) for _, _, n in relaid] == [True], relaid
+    temp = fused.memory_analysis().temp_size_in_bytes
+    one_batch = compiled(1).memory_analysis().temp_size_in_bytes
+    assert temp <= 1.1 * one_batch, (temp, one_batch)

@@ -153,25 +153,18 @@ def test_stats_occupancy_bounds(small_graph):
     assert (frac >= 0).all() and (frac <= 1.0).all()
 
 
-@pytest.mark.parametrize("n_colors", [64, 32, 128])
-def test_run_fused_block_matches_per_batch(small_graph, n_colors):
-    """The fused multi-batch sweep (ONE lax.map dispatch — the pool-build
-    fast path) must reproduce per-batch run_fused exactly: visited masks
-    AND summed edge-visit counters, levels and truncated colors — at one,
-    two and four words, so the word-major level loop is checked at every
-    width W it transposes."""
-    starts = jnp.stack([
-        traversal.random_starts(jax.random.key(k), small_graph.num_vertices,
-                                n_colors) for k in range(3)])
-    seeds = jnp.asarray([7, 8, 9], jnp.uint32)
-    vis, visits, counts = traversal.run_fused_block(small_graph, starts,
-                                                    seeds, n_colors)
-    assert vis.shape == (3, small_graph.num_vertices,
+def _assert_block_matches_per_batch(g, starts, seeds, n_colors, **kw):
+    """`run_fused_block` on a block equals `run_fused` on each of its
+    batches alone: visited masks, summed (fused, unfused) edge visits,
+    levels and truncated colors."""
+    vis, visits, counts = traversal.run_fused_block(g, starts, seeds,
+                                                    n_colors, **kw)
+    assert vis.shape == (len(seeds), g.num_vertices,
                          bitmask.num_words(n_colors))
     fused, unfused = np.asarray(visits).T
     levels, truncated = np.asarray(counts).T
-    for i in range(3):
-        ref = traversal.run_fused(small_graph, starts[i], n_colors, seeds[i])
+    for i in range(len(seeds)):
+        ref = traversal.run_fused(g, starts[i], n_colors, seeds[i], **kw)
         np.testing.assert_array_equal(np.asarray(vis[i]),
                                       np.asarray(ref.visited))
         assert int(fused[i]) == int(np.asarray(
@@ -180,6 +173,51 @@ def test_run_fused_block_matches_per_batch(small_graph, n_colors):
             ref.stats.unfused_edge_visits, np.int64).sum())
         assert int(levels[i]) == int(ref.stats.levels_run)
         assert int(truncated[i]) == int(ref.stats.truncated_colors)
+    return levels, truncated
+
+
+# (batches, colors): every block size at one, two, four and eight words
+# a batch, so G = 8, 4, 2, 1 batches a loop; full groups, a tail group,
+# and G = 1.  The three-batch cases keep their ids.
+BLOCKS = [pytest.param(b, c, id=str(c) if b == 3 else f"b{b}-c{c}")
+          for b in (1, 3, 4, 5, 9) for c in (32, 64, 128, 256)]
+
+
+@pytest.mark.parametrize("num_batches,n_colors", BLOCKS)
+def test_run_fused_block_matches_per_batch(small_graph, num_batches,
+                                           n_colors):
+    """The fused multi-batch sweep (ONE dispatch — the pool-build fast
+    path, G batches a level loop) must reproduce per-batch run_fused
+    exactly: visited masks AND summed edge-visit counters, levels and
+    truncated colors — at one, two, four and eight words, so the
+    word-major level loop is checked at every width it transposes, and
+    at block sizes that leave a tail group."""
+    starts = jnp.stack([
+        traversal.random_starts(jax.random.key(k), small_graph.num_vertices,
+                                n_colors) for k in range(num_batches)])
+    seeds = jnp.asarray(7 + np.arange(num_batches), jnp.uint32)
+    _assert_block_matches_per_batch(small_graph, starts, seeds, n_colors)
+
+
+def test_run_fused_block_group_of_uneven_batches():
+    """One loop of four batches in which one batch empties at its first
+    level and another is cut by ``max_levels``: each still counts its own
+    levels, visits and truncated colors, as a loop of its own would."""
+    n, path = 40, 30
+    src = np.arange(path - 1)                 # a path 0 → 1 → … → 29
+    dst = np.arange(1, path)
+    g = csr.from_edges(np.concatenate([src, [31, 32]]),
+                       np.concatenate([dst, [32, 33]]),
+                       np.ones(path + 1, np.float32), n)
+    sink = n - 1                              # no out-edges
+    starts = jnp.asarray([np.full(64, sink), np.zeros(64),
+                          np.arange(64) % n, np.full(64, 31)], jnp.int32)
+    seeds = jnp.asarray([3, 4, 5, 6], jnp.uint32)
+    assert traversal.batches_per_loop(4, 64) == 4
+    levels, truncated = _assert_block_matches_per_batch(
+        g, starts, seeds, 64, max_levels=8)
+    assert levels.tolist()[0] == 1 and levels.tolist()[3] == 3
+    assert levels.tolist()[1] == 8 and truncated[1] == 64
 
 
 def _edge_major_level(g, frontier, visited, level, seed):
