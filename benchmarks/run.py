@@ -67,8 +67,6 @@ def _sections():
          lambda: bench("bench_stream_updates").run(
              sweeps=bench("bench_stream_updates").standard_sweeps(
                  churn_n=3000, scale_ns=(3000,), batches=8))),
-        ("Fig10/11 device scaling (forced CPU devices)", True,
-         lambda: bench("bench_scaling").run(device_counts=(1, 2, 4, 8))),
         ("Roofline table (from dry-run records)", False,
          lambda: bench("roofline").table()),
     ]

@@ -46,7 +46,7 @@ class SamplingDriver:
                  *, num_workers: int = 4, timeout_s: float = 120.0,
                  max_attempts: int = 5, failure_rate: float = 0.0,
                  slow_rate: float = 0.0, slow_s: float = 0.3,
-                 spec=None, **sample_kw):
+                 spec=None):
         from repro import sampling
 
         self.g_rev = g_rev
@@ -61,8 +61,7 @@ class SamplingDriver:
         # Shared reconciliation policy: the driver's num_colors/master_seed
         # are required args, hence always explicit — a disagreeing spec
         # raises rather than silently overriding.
-        self.spec = sampling.resolve_spec(spec, sample_kw,
-                                          num_colors=num_colors,
+        self.spec = sampling.resolve_spec(spec, num_colors=num_colors,
                                           master_seed=master_seed)
         if self.spec.backend in ("data_parallel", "graph_parallel"):
             raise ValueError(

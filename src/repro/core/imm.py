@@ -309,7 +309,7 @@ class IMMResult:
 def run_imm(g: csr.Graph, k: int, eps: float = 0.3, *, ell: float = 1.0,
             num_colors: int | None = None, master_seed: int | None = None,
             theta_cap: int | None = 100_000, pool=None,
-            spec=None, mesh=None, **sample_kw) -> IMMResult:
+            spec=None, mesh=None) -> IMMResult:
     """Full IMM: θ estimation → top-up sampling → greedy selection.
 
     ``pool``: optional sketch pool (module docstring); batches come from and
@@ -324,13 +324,12 @@ def run_imm(g: csr.Graph, k: int, eps: float = 0.3, *, ell: float = 1.0,
     ``spec``: `repro.sampling.SamplerSpec` choosing diffusion/backend for
     the pool-less path (``sampling.resolve_spec`` policy: explicit
     num_colors/master_seed that disagree with the spec raise); ``mesh``
-    backs the ``data_parallel`` backend.  Legacy ``sample_batch`` kwargs
-    are converted with a DeprecationWarning.
+    backs the ``data_parallel`` backend.
     """
     from repro import sampling
 
     explicit_spec = spec is not None
-    spec = sampling.resolve_spec(spec, sample_kw, num_colors=num_colors,
+    spec = sampling.resolve_spec(spec, num_colors=num_colors,
                                  master_seed=master_seed)
     num_colors, master_seed = spec.num_colors, spec.master_seed
     if pool is not None:

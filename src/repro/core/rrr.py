@@ -129,17 +129,16 @@ def sample_batch(g_rev: csr.Graph, num_colors: int, master_seed: int,
 def sample_collection(g: csr.Graph, theta: int,
                       num_colors: int | None = None,
                       master_seed: int | None = None, *, spec=None,
-                      mesh=None, **kw) -> list[RRRBatch]:
+                      mesh=None) -> list[RRRBatch]:
     """θ RRR sets as ⌈θ/num_colors⌉ fused batches on transpose(g).
 
     Routed through the `repro.sampling` facade (``sampling.resolve_spec``
     policy: explicit num_colors/master_seed that disagree with ``spec``
-    raise); ``mesh`` backs the ``data_parallel`` backend; legacy
-    ``sample_batch`` kwargs convert with a DeprecationWarning.
+    raise); ``mesh`` backs the ``data_parallel`` backend.
     """
     from repro import sampling
 
-    spec = sampling.resolve_spec(spec, kw, num_colors=num_colors,
+    spec = sampling.resolve_spec(spec, num_colors=num_colors,
                                  master_seed=master_seed)
     sampler = sampling.make_sampler(g, spec, mesh=mesh)
     n_batches = -(-theta // spec.num_colors)

@@ -14,21 +14,20 @@ unfused runs are coupled bit-for-bit (used by tests to check equivalence and
 Theorem 1 without sampling error).
 
 Layout: masks enter and leave as ``(V, W)`` (`init_frontier`, the results),
-but the dense CSR level loops (`run_fused`, `run_fused_block`,
-``distributed.traversal.run_batch``) carry them word-major, ``(W, V)``, and
-every edge-length intermediate of a level (`_expand`) is ``(W, E)``: the
-gathered source words, the RNG words, ``contrib``, the OR scan's
-accumulator, so that the edge axis is the minor one.  Under TPU's (8, 128)
-tiling an edge-major ``(E, W)`` array pads its minor dimension from W = 2
-to 128 lanes — 64× its data — and the compiler relays every such buffer
-to and from a lane-dense layout.  Each loop transposes once on entry and
-once on exit.  One padded buffer is left: the compiler writes the source
-row gather ``frontier[:, src]`` edge-major and relays it once; one 1-D
-gather per word would avoid that, but a 1-D gather costs far more per
-index on TPU v5e (PERF.md §5).  The gather costs the same per row at up
-to 128 words, so `run_fused_block` runs G batches of a block in one loop
-of G·W words (`batches_per_loop`: G·W = 8 at 64 colors, one tile's
-sublanes), and one gather a level serves all G.
+but the dense CSR level loops (`run_fused`, `run_fused_block`) carry them
+word-major, ``(W, V)``, and every edge-length intermediate of a level
+(`_expand`) is ``(W, E)``: the gathered source words, the RNG words,
+``contrib``, the OR scan's accumulator, so that the edge axis is the minor
+one.  Under TPU's (8, 128) tiling an edge-major ``(E, W)`` array pads its
+minor dimension from W = 2 to 128 lanes — 64× its data — and the compiler
+relays every such buffer to and from a lane-dense layout.  Each loop
+transposes once on entry and once on exit.  One padded buffer is left: the
+compiler writes the source row gather ``frontier[:, src]`` edge-major and
+relays it once; one 1-D gather per word would avoid that, but a 1-D gather
+costs far more per index on TPU v5e (PERF.md §5).  The gather costs the
+same per row at up to 128 words, so `run_fused_block` runs G batches of a
+block in one loop of G·W words (`batches_per_loop`: G·W = 8 at 64 colors,
+one tile's sublanes), and one gather a level serves all G.
 
 Level-synchronous semantics (matching the paper's Ripples port §4.2): the
 whole frontier is folded into ``visited`` first, then expansion excludes all

@@ -1,134 +1,34 @@
-"""Distributed fused-BPT traversal.
+"""Graph-parallel fused-BPT traversal: the block program behind the
+`repro.sampling` ``graph_parallel`` backend, and its frontier exchange.
 
-Two orthogonal axes, composable on one mesh:
+Sample parallelism (independent batches over a ``data`` axis, zero
+collectives during traversal) needs no program of its own: the
+``data_parallel`` backend runs the dense block program on each shard
+(`repro.sampling.sampler._dense_block`).  Graph parallelism partitions
+destination rows over ``model``: each level exchanges the (packed)
+frontier and expands only locally owned tiles.
 
-* **Sample parallelism** (paper's multi-node axis, Fig. 10): independent
-  fused batches sharded over ``data`` (and ``pod``).  Zero collectives
-  during traversal; one reduction at seed selection.  This is what scaled
-  to 32,768 GPUs in the paper.
-* **Graph parallelism** (beyond-paper): 1-D destination-row partition over
-  ``model``.  Each level all-gathers the (sparse, packed) frontier and
-  expands only locally-owned tiles — the collective-bound cell of the
-  roofline analysis.
-
-``graph_parallel_block`` composes the two on ONE mesh: batches sharded over
-``data``, rows over ``model``, every collective naming only the model axis
-— the program behind the `repro.sampling` ``graph_parallel`` backend (IC
-and LT; LT derives its live-edge selection shard-locally from the global
-ids of its tiles' source rows, hashed per tile — so neither an (E, W)
-selection mask nor a uniform table over every vertex is ever
+``graph_parallel_block`` composes the two on ONE mesh: batches sharded
+over ``data``, rows over ``model``, every collective naming only the model
+axis (IC and LT; LT derives its live-edge selection shard-locally from the
+global ids of its tiles' source rows, hashed per tile — so neither an
+(E, W) selection mask nor a uniform table over every vertex is ever
 replicated).
 
-All paths reuse the exact single-device expansion math (coupled RNG), so
-distributed results are bit-for-bit equal to single-device runs; tests
-assert this under a forced multi-device host platform.
+It reuses the exact single-device expansion math (coupled RNG), so its
+results are bit-for-bit equal to single-device runs; tests assert this
+under a forced multi-device host platform.
 """
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
-import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core import bitmask, rng, tiles
+from repro.core import bitmask, tiles
 from repro.core.traversal import init_frontier
-from repro.graph import csr, partition as part_lib
+from repro.graph import partition as part_lib
 from repro.kernels import ref as kref
-
-
-# ------------------------------------------------------------ sample parallel
-def run_batch(g: csr.Graph, starts, seed, num_colors: int,
-              max_levels: int = 64, segments=None):
-    """One fused batch as a jit-friendly pure function of (graph, starts,
-    seed) — the unit that sample parallelism vmaps/shards.  ``segments``:
-    `traversal.dst_segments(g.dst, V)` when the caller holds it.  The
-    level loop carries the masks word-major (W, V), as `_expand` takes
-    them; the result is (V, W)."""
-    from repro.core.traversal import _expand, dst_view
-
-    frontier = init_frontier(g.num_vertices, num_colors, starts).T
-    visited = jnp.zeros_like(frontier)
-    view = dst_view(g, segments)
-
-    def cond(c):
-        fr, _, lvl = c
-        return jnp.logical_and(bitmask.any_set(fr), lvl < max_levels)
-
-    def body(c):
-        fr, vis, lvl = c
-        nf, nv, _ = _expand(view, fr, vis, lvl, seed)
-        return nf, nv, lvl + 1
-
-    fr, vis, _ = jax.lax.while_loop(
-        cond, body, (frontier, visited, jnp.int32(0)))
-    return (vis | fr).T
-
-
-def sample_parallel_fn(g: csr.Graph, all_starts, batch_seeds,
-                       num_colors: int, max_levels: int = 64):
-    """vmapped batch sweep; shard the batch dim over data axes, replicate
-    the graph — exactly the paper's node-level strategy."""
-    return jax.vmap(
-        lambda s, sd: run_batch(g, s, sd, num_colors, max_levels)
-    )(all_starts, batch_seeds)
-
-
-def sample_parallel_visited(g: csr.Graph, all_starts: jnp.ndarray,
-                            batch_seeds: jnp.ndarray, num_colors: int,
-                            mesh: Mesh, axes=("data",),
-                            max_levels: int = 64) -> jnp.ndarray:
-    """Run B independent fused batches, sharded over ``axes``.
-
-    all_starts: (B, C) start vertices; batch_seeds: (B,) uint32.
-    Returns visited (B, V, W) sharded over the batch dim.
-    """
-    sharding = NamedSharding(mesh, P(axes))
-    replicated = NamedSharding(mesh, P())
-    fn = jax.jit(
-        partial(sample_parallel_fn, num_colors=num_colors,
-                max_levels=max_levels),
-        in_shardings=(jax.tree.map(lambda _: replicated, g),
-                      sharding, sharding),
-        out_shardings=sharding)
-    return fn(g, jax.device_put(all_starts, sharding),
-              jax.device_put(batch_seeds, sharding))
-
-
-def distributed_greedy_max_cover(visited: jnp.ndarray, k: int,
-                                 num_colors: int, mesh: Mesh,
-                                 axes=("data",)):
-    """Greedy max-k-cover with the RRR collection sharded over batches.
-
-    The marginal-gain reduction over the batch axis becomes an all-reduce
-    (GSPMD inserts it); selection state (``active``) is sharded alongside.
-    """
-    b, v, w = visited.shape
-    sharding = NamedSharding(mesh, P(axes))
-    visited = jax.device_put(visited, sharding)
-    active = jax.device_put(
-        jnp.broadcast_to(jnp.asarray(bitmask.color_tail_mask(num_colors)),
-                         (b, w)), sharding)
-
-    @jax.jit
-    def gain_counts(vis, act):
-        return jnp.sum(bitmask.popcount(vis & act[:, None, :]), axis=(0, 2),
-                       dtype=jnp.int32)          # (V,) — cross-batch psum
-
-    @jax.jit
-    def knock_out(act, vis_row):
-        return act & ~vis_row
-
-    seeds = []
-    for _ in range(k):
-        counts = gain_counts(visited, active)
-        sel = int(jnp.argmax(counts))
-        seeds.append(sel)
-        active = knock_out(active, visited[:, sel, :])
-    theta = b * num_colors
-    covered = theta - int(jnp.sum(bitmask.popcount(active)))
-    return np.asarray(seeds, np.int32), covered / theta
 
 
 # ------------------------------------------------------------- graph parallel
@@ -360,37 +260,6 @@ def _local_expand(ptg_local, diffusion: str, cb_local, seed, *,
                 ptg_local.tile_src[0], ptg_local.tile_dst[0],
                 fr_global, vis_local, seed, level)
     return expand
-
-
-def graph_parallel_traversal(ptg: part_lib.PartitionedTiledGraph,
-                             starts, num_colors: int, seed, mesh: Mesh,
-                             axis: str = "model", max_levels: int = 64):
-    """Fused BPT with the graph sharded across ``axis`` (1-D row partition).
-
-    Returns (visited (V, W), levels).  Tile stacks enter shard_map with their
-    leading shard dim consumed by the mesh axis.
-    """
-
-    vp = ptg.padded_vertices
-    frontier = tiles.pad_mask_rows(
-        init_frontier(ptg.num_vertices, num_colors, starts), vp)
-    seed = jnp.uint32(seed)
-
-    def body(ptg_local, frontier_local):
-        expand = _local_expand(ptg_local, "ic", None, seed,
-                               use_kernel=False, interpret=False)
-        vis, levels, _ = _frontier_gather_loop(expand, frontier_local,
-                                               max_levels, axis,
-                                               num_shards=ptg.num_shards)
-        return vis, levels
-
-    fn = jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(part_lib.partition_specs(ptg, axis), P(axis)),
-        out_specs=(P(axis), P()),
-        check_vma=False)
-    visited, levels = jax.jit(fn)(ptg, frontier)
-    return visited[: ptg.num_vertices], levels
 
 
 # Module-level cache of compiled 2-D block programs, keyed on (mesh, axes,

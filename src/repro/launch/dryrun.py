@@ -22,6 +22,7 @@ Usage:
 """
 import argparse
 import dataclasses
+import functools
 import json
 import pathlib
 import time
@@ -187,13 +188,16 @@ def lower_bpt_cell(which: str, *, multi_pod: bool) -> dict:
               "mesh": "x".join(str(mesh.shape[a]) for a in mesh.axis_names),
               "axes": list(mesh.axis_names), "chips": chips, "kind": "bpt"}
     try:
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        from repro.distributed import traversal as dtrav
         from repro.graph import csr, partition as part_lib
 
         if which == "sample":
             # soc-LiveJournal1 scale, graph replicated (paper's strategy):
+            # the data_parallel backend's shard_map of the dense block
+            # program, one batch per chip, batches over every mesh axis.
+            from repro.core import traversal
+            from repro.sampling import SamplerSpec
+            from repro.sampling.sampler import _data_parallel_block_fn
+
             V, E, C = 4_847_571, 68_993_773, 512
             g = csr.Graph(
                 indptr=jax.ShapeDtypeStruct((V + 1,), jnp.int32),
@@ -201,20 +205,15 @@ def lower_bpt_cell(which: str, *, multi_pod: bool) -> dict:
                 dst=jax.ShapeDtypeStruct((E,), jnp.int32),
                 prob=jax.ShapeDtypeStruct((E,), jnp.float32),
                 num_vertices=V, num_edges=E)
-            dp_axes = rules.fsdp_axes(mesh)
-            B = int(np.prod([mesh.shape[a] for a in dp_axes])) * \
-                mesh.shape["model"]
-            starts = jax.ShapeDtypeStruct((B, C), jnp.int32)
-            seeds = jax.ShapeDtypeStruct((B,), jnp.uint32)
-            all_axes = tuple(mesh.axis_names)
-            sh = NamedSharding(mesh, P(all_axes))
-            rep = NamedSharding(mesh, P())
-            fn = jax.jit(
-                lambda g, s, sd: dtrav.sample_parallel_fn(g, s, sd, C,
-                                                          max_levels=64),
-                in_shardings=(jax.tree.map(lambda _: rep, g), sh, sh),
-                out_shardings=sh)
-            lowered = fn.lower(g, starts, seeds)
+            segments = jax.eval_shape(
+                functools.partial(traversal.dst_segments, num_rows=V), g.dst)
+            spec = SamplerSpec(backend="data_parallel", num_colors=C,
+                               max_iters=64)
+            fn = _data_parallel_block_fn(mesh, tuple(mesh.axis_names), spec,
+                                         None)
+            lowered = fn.lower((g, segments),
+                               jax.ShapeDtypeStruct((chips, C), jnp.int32),
+                               jax.ShapeDtypeStruct((chips,), jnp.uint32))
         elif which in ("graph", "graph_q"):          # graph parallel
             # web-BerkStan scale tiled graph, 1-D partition over "model".
             # tiles_per_shard from the measured cluster-reordered density
@@ -224,8 +223,11 @@ def lower_bpt_cell(which: str, *, multi_pod: bool) -> dict:
             nb = -(-(-(-V // T)) // S) * S           # blocks, shard-divisible
             nb_loc = nb // S
             tiles_per_shard = 1900
-            starts = jax.ShapeDtypeStruct((C,), jnp.int32)
             if which == "graph":
+                # The graph_parallel backend's block program: one batch
+                # per data shard, rows over "model".
+                from repro.distributed.traversal import graph_parallel_block
+
                 ptg = part_lib.PartitionedTiledGraph(
                     prob=jax.ShapeDtypeStruct((S, tiles_per_shard, T, T),
                                               jnp.float32),
@@ -239,9 +241,13 @@ def lower_bpt_cell(which: str, *, multi_pod: bool) -> dict:
                                                       jnp.int32),
                     num_vertices=V, num_edges=E, tile_size=T, num_shards=S,
                     blocks_per_shard=nb_loc)
-                fn = jax.jit(lambda p, s: dtrav.graph_parallel_traversal(
-                    p, s, C, 7, mesh, max_levels=64))
-                lowered = fn.lower(ptg, starts)
+                B = mesh.shape["data"]
+                fn = graph_parallel_block(
+                    ptg, mesh, num_colors=C, max_levels=64,
+                    use_kernel=False, interpret=False)
+                lowered = fn.lower(ptg,
+                                   jax.ShapeDtypeStruct((B, C), jnp.int32),
+                                   jax.ShapeDtypeStruct((B,), jnp.uint32))
             else:
                 # §Perf B1: quantized tiles — u8 threshold, no edge-id
                 # plane (8× tile bytes), 8 hashes/word instead of 32.
@@ -251,6 +257,7 @@ def lower_bpt_cell(which: str, *, multi_pod: bool) -> dict:
                 from repro.core.traversal import init_frontier
                 from repro.kernels import fused_expand_q as feq
 
+                starts = jax.ShapeDtypeStruct((C,), jnp.int32)
                 q8 = jax.ShapeDtypeStruct((S, tiles_per_shard, T, T),
                                           jnp.uint8)
                 ts = jax.ShapeDtypeStruct((S, tiles_per_shard), jnp.int32)

@@ -25,9 +25,7 @@ supports the diffusion.
 """
 from repro.sampling.sampler import Sampler, make_sampler
 from repro.sampling.spec import (BACKENDS, DIFFUSIONS, FRONTIERS,
-                                 SamplerSpec, resolve_spec,
-                                 spec_from_sample_kw, supported)
+                                 SamplerSpec, resolve_spec, supported)
 
 __all__ = ["BACKENDS", "DIFFUSIONS", "FRONTIERS", "Sampler", "SamplerSpec",
-           "make_sampler", "resolve_spec", "spec_from_sample_kw",
-           "supported"]
+           "make_sampler", "resolve_spec", "supported"]

@@ -12,9 +12,11 @@ shape without changing a single answer.
 
 Backends:
 
-* ``dense``          — CSR edge-centric sweep (`core.traversal.run_fused` /
-                       `core.lt.run_fused_lt`), one batch per call on the
-                       default device.
+* ``dense``          — CSR edge-centric sweep, a block of batches per
+                       dispatch on the default device: `_dense_block`
+                       chooses `core.traversal.run_fused_block`,
+                       `core.lt.run_fused_lt_block` or
+                       `core.sparse.sparse_block`.
 * ``tiled``          — block-sparse tile expansion, pure-jnp oracle
                        (`core.tiled_traversal.run_fused_tiled`; LT via
                        `run_fused_lt_tiled`).
@@ -22,11 +24,11 @@ Backends:
                        (``fused_expand`` for IC, ``lt_select_expand`` for
                        LT).
 * ``data_parallel``  — batch *blocks* over a mesh axis via ``shard_map``:
-                       each shard traverses its own contiguous slice of the
-                       block with per-batch RNG streams, on its own device
-                       — pool builds parallelize across the mesh instead of
-                       staging one batch at a time through the default
-                       device (the ROADMAP's distributed-sampling item).
+                       each shard runs the dense backend's block program
+                       (`_dense_block`) on its own contiguous slice of the
+                       block, on its own device — pool builds parallelize
+                       across the mesh instead of staging every batch
+                       through the default device.
 * ``graph_parallel`` — the graph itself partitioned: destination rows shard
                        over ``spec.model_axis`` (1-D tile partition, cached
                        on the sampler), batch blocks over ``spec.mesh_axis``
@@ -110,16 +112,6 @@ class Sampler:
     def batch_seed(self, batch_index: int) -> jnp.ndarray:
         return rrr.batch_seed(self.spec.master_seed, batch_index)
 
-    def _dst_segments(self):
-        """`traversal.dst_segments` of the reversed graph, built once per
-        sampler (a function of the edge destinations alone, so it also
-        survives values-only rebinds) and handed to every dense-path
-        program — which then contains no sort."""
-        if getattr(self, "_segments", None) is None:
-            self._segments = traversal.dst_segments(self.g_rev.dst,
-                                                    self.g_rev.num_vertices)
-        return self._segments
-
     # ------------------------------------------------------- sampling
     def sample(self, batch_index: int) -> rrr.RRRBatch:
         raise NotImplementedError
@@ -148,43 +140,7 @@ class Sampler:
         return make_sampler(g, self.spec, getattr(self, "mesh", None),
                             g_rev=g_rev)
 
-    def _try_patch_fidx(self, g, g_rev, touched_row_blocks) -> bool:
-        """Shared sparse-frontier fast path: patch the cached
-        `FrontierIndex` (and LT prefixes) in place when the delta is
-        values-only and names its touched row blocks.  True on success."""
-        spec = self.spec
-        if (spec.frontier != "sparse" or touched_row_blocks is None
-                or getattr(self, "_fidx", None) is None):
-            return False
-        if spec.diffusion == "lt":
-            g_rev = _lt_normalized(g_rev)   # idempotent
-        if not _same_edge_layout(self.g_rev, g_rev):
-            return False
-        from repro.core import sparse
-        self.graph = g
-        self.g_rev = g_rev
-        cb = None
-        if spec.diffusion == "lt":
-            self._cb = jnp.asarray(_lt_cum_before(self.g_rev))
-            cb = np.asarray(self._cb)
-        self._fidx = sparse.patch_frontier_index(
-            self._fidx, self.g_rev, touched_row_blocks, cb=cb)
-        return True
-
-    # -------------------------------------------- sparse-frontier shared
-    def _sparse_index(self, cb=None):
-        """(FrontierIndex, bucket ladder) for ``spec.frontier == "sparse"``
-        — ONE construction path for every backend that compacts edge
-        blocks (tile_rows follows ``spec.tile_size``, capacity follows
-        ``spec.frontier_capacity``).  ``cb`` attaches the LT
-        selection-CDF prefixes."""
-        from repro.core import sparse
-        fidx = sparse.build_frontier_index(
-            self.g_rev, tile_rows=self.spec.tile_size, cb=cb)
-        return fidx, sparse.bucket_ladder(fidx.num_blocks,
-                                          self.spec.frontier_capacity)
-
-    # ------------------------------------------------- mesh-backend shared
+    # ----------------------------------------------------- block inputs
     def _block_inputs(self, idx: list[int], shards: int):
         """(padded_len, starts (Bp, C), seeds (Bp,)) for a block padded to a
         multiple of ``shards`` with repeats of the last index (identical
@@ -211,6 +167,36 @@ def _same_edge_layout(a: csr.Graph, b: csr.Graph) -> bool:
             and np.array_equal(np.asarray(a.dst), np.asarray(b.dst)))
 
 
+def _dense_block(spec: SamplerSpec, ladder, data, starts, seeds):
+    """THE choice of the dense path's block program: IC or LT × dense or
+    sparse frontier.  ``data`` is `DenseSampler._block_data`'s pytree,
+    ``ladder`` the sparse bucket ladder (None on the dense frontier).
+
+    starts (B, C) int32 / seeds (B,) uint32 → (visited (B, V, W),
+    edge_visits (B, 2), level_counts (B, 2)) from
+    `traversal.run_fused_block`, `lt.run_fused_lt_block` or
+    `sparse.sparse_block`, each jitted on its own.  `DenseSampler` calls
+    it on a whole block, the data_parallel shard body on its local slice
+    (inside a trace), so every single-graph backend runs one program.
+    Reads ``spec.frontier``, ``diffusion``, ``num_colors`` and
+    ``max_iters`` only."""
+    if spec.frontier == "sparse":
+        from repro.core import sparse
+        (fidx,) = data
+        return sparse.sparse_block(fidx, starts, seeds, spec.num_colors,
+                                   spec.max_iters, ladder,
+                                   diffusion=spec.diffusion)
+    if spec.diffusion == "lt":
+        g, cb, segments = data
+        return lt.run_fused_lt_block(g, cb, starts, seeds, spec.num_colors,
+                                     max_levels=spec.max_iters,
+                                     segments=segments)
+    g, segments = data
+    return traversal.run_fused_block(g, starts, seeds, spec.num_colors,
+                                     max_levels=spec.max_iters,
+                                     segments=segments)
+
+
 class DenseSampler(Sampler):
     """CSR edge-centric path — IC and LT.
 
@@ -220,66 +206,75 @@ class DenseSampler(Sampler):
     ladder) — bit-identical masks AND work counters, per-level cost
     proportional to the live frontier instead of E.
 
-    ``sample_many`` fuses the whole block into ONE dispatch (one jit —
-    `traversal.run_fused_block`, `traversal.batches_per_loop` batches a
-    level loop, / `sparse.sparse_block` / `lt.run_fused_lt_block`, a loop
-    per batch), so pool builds and
-    refreshes stop paying per-batch dispatch, and reads the block's
-    counters back with one ``jax.device_get`` when the first of them is
-    read (`_BlockCounts`), so ``sample_many`` returns without waiting for
-    the block.  Every batch, IC or LT, counts its (fused, unfused) edge
-    visits, its levels and its truncated colors.
+    ``sample_many`` runs the whole block in ONE dispatch of `_dense_block`
+    (dense IC: `traversal.batches_per_loop` batches a level loop; the
+    other programs a loop per batch), so pool builds and refreshes stop
+    paying per-batch dispatch, and reads the block's counters back with
+    one ``jax.device_get`` when the first of them is read
+    (`_BlockCounts`), so ``sample_many`` returns without waiting for the
+    block.  ``sample(b)`` is a block of one.  Every batch, IC or LT,
+    counts its (fused, unfused) edge visits, its levels and its truncated
+    colors.
     """
 
     def __init__(self, g, spec, *, g_rev=None):
         super().__init__(g, spec, g_rev=g_rev)
+        self._segments = None
         self._fidx = None
         self._ladder = None
         self._cb = None
 
     # ----------------------------------------------------- lazy indexes
+    def _dst_segments(self):
+        """`traversal.dst_segments` of the reversed graph, built once per
+        sampler and handed to every dense-frontier program — which then
+        contains no sort."""
+        if self._segments is None:
+            self._segments = traversal.dst_segments(self.g_rev.dst,
+                                                    self.g_rev.num_vertices)
+        return self._segments
+
     def _lt_cb(self):
         if self._cb is None:
             self._cb = jnp.asarray(_lt_cum_before(self.g_rev))
         return self._cb
 
     def _frontier_index(self):
+        """The sparse frontier's `FrontierIndex` (tile_rows follows
+        ``spec.tile_size``; LT attaches the selection-CDF prefixes) and
+        its bucket ladder (``spec.frontier_capacity``), built once."""
         if self._fidx is None:
+            from repro.core import sparse
             cb = (np.asarray(self._lt_cb())
                   if self.spec.diffusion == "lt" else None)
-            self._fidx, self._ladder = self._sparse_index(cb)
+            self._fidx = sparse.build_frontier_index(
+                self.g_rev, tile_rows=self.spec.tile_size, cb=cb)
+            self._ladder = sparse.bucket_ladder(self._fidx.num_blocks,
+                                                self.spec.frontier_capacity)
         return self._fidx
+
+    def _block_data(self):
+        """The graph-dependent pytree `_dense_block` takes as a traced
+        INPUT — what a streaming update swaps out under a cached program
+        (`repro.stream` rebinds samplers per delta).  Builds the sparse
+        ladder (``_ladder``) on first use."""
+        if self.spec.frontier == "sparse":
+            return (self._frontier_index(),)
+        if self.spec.diffusion == "lt":
+            return (self.g_rev, self._lt_cb(), self._dst_segments())
+        return (self.g_rev, self._dst_segments())
 
     # -------------------------------------------------------- sampling
     def sample(self, batch_index: int) -> rrr.RRRBatch:
-        if self.spec.frontier == "sparse":
-            from repro.core import sparse
-            fidx = self._frontier_index()
-            starts = self.batch_starts(batch_index)
-            seed = self.batch_seed(batch_index)
-            if self.spec.diffusion == "lt":
-                visited, levels, truncated, visits = \
-                    sparse.run_fused_lt_sparse(
-                        fidx, starts, self.spec.num_colors, seed,
-                        max_levels=self.spec.max_iters, ladder=self._ladder)
-                return rrr.RRRBatch(
-                    visited, np.asarray(starts), int(batch_index),
-                    (*map(int, visits), int(levels), int(truncated)))
-            res = sparse.run_fused_sparse(
-                fidx, starts, self.spec.num_colors, seed,
-                max_levels=self.spec.max_iters, ladder=self._ladder)
-            return rrr.RRRBatch(res.visited, np.asarray(starts),
-                                int(batch_index), rrr.stats_counts(res.stats))
         return self.sample_many([batch_index])[0]
 
     def sample_many(self, batch_indices) -> list[rrr.RRRBatch]:
         idx = [int(b) for b in batch_indices]
-        if not idx or (len(idx) == 1 and self.spec.frontier == "sparse"):
-            return [self.sample(b) for b in idx]
+        if not idx:
+            return []
         spec = self.spec
         with obs.span("sampling.block_inputs", batches=len(idx)):
-            starts = jnp.stack([self.batch_starts(b) for b in idx])
-            seeds = jnp.asarray(rrr.batch_seeds(spec.master_seed, idx))
+            _, starts, seeds = self._block_inputs(idx, 1)
         # Dense IC runs `traversal.batches_per_loop` batches a level loop;
         # the other programs one.
         per_loop = (traversal.batches_per_loop(len(idx), spec.num_colors)
@@ -288,26 +283,37 @@ class DenseSampler(Sampler):
         with obs.span("sampling.dispatch", batches=len(idx),
                       batches_per_loop=per_loop,
                       loops=-(-len(idx) // per_loop)):
-            if spec.frontier == "sparse":
-                from repro.core import sparse
-                fidx = self._frontier_index()
-                vis, visits, counts = sparse.sparse_block(
-                    fidx, starts, seeds, spec.num_colors, spec.max_iters,
-                    self._ladder, diffusion=spec.diffusion)
-            elif spec.diffusion == "lt":
-                vis, visits, counts = lt.run_fused_lt_block(
-                    self.g_rev, self._lt_cb(), starts, seeds,
-                    spec.num_colors, max_levels=spec.max_iters,
-                    segments=self._dst_segments())
-            else:
-                vis, visits, counts = traversal.run_fused_block(
-                    self.g_rev, starts, seeds, spec.num_colors,
-                    max_levels=spec.max_iters,
-                    segments=self._dst_segments())
+            data = self._block_data()
+            vis, visits, counts = _dense_block(spec, self._ladder, data,
+                                               starts, seeds)
         roots = np.asarray(starts)
         block = _BlockCounts(visits, counts)
         return [rrr.RRRBatch(vis[i], roots[i], b, functools.partial(
                     block.row, i)) for i, b in enumerate(idx)]
+
+    # ------------------------------------------------------- rebinding
+    def _try_patch_fidx(self, g, g_rev, touched_row_blocks) -> bool:
+        """Sparse-frontier fast path: patch the cached `FrontierIndex`
+        (and LT prefixes) in place when the delta is values-only and names
+        its touched row blocks.  True on success."""
+        spec = self.spec
+        if (spec.frontier != "sparse" or touched_row_blocks is None
+                or self._fidx is None):
+            return False
+        if spec.diffusion == "lt":
+            g_rev = _lt_normalized(g_rev)   # idempotent
+        if not _same_edge_layout(self.g_rev, g_rev):
+            return False
+        from repro.core import sparse
+        self.graph = g
+        self.g_rev = g_rev
+        cb = None
+        if spec.diffusion == "lt":
+            self._cb = jnp.asarray(_lt_cum_before(self.g_rev))
+            cb = np.asarray(self._cb)
+        self._fidx = sparse.patch_frontier_index(
+            self._fidx, self.g_rev, touched_row_blocks, cb=cb)
+        return True
 
     def rebind(self, g, g_rev, touched_row_blocks=None):
         if self._try_patch_fidx(g, g_rev, touched_row_blocks):
@@ -438,15 +444,18 @@ _DP_BLOCK_FNS: dict = {}
 
 
 def _data_parallel_block_fn(mesh, axis: str, spec: SamplerSpec, ladder):
-    """jit(shard_map) block traversal for the data_parallel backend.
+    """jit(shard_map) of `_dense_block` for the data_parallel backend:
+    each shard runs the dense block program on its local slice of the
+    block; the three outputs come back sharded ``P(axis)``.
 
-    Cached at MODULE level on (mesh, statics), with the graph / frontier
-    index passed as a traced ARGUMENT rather than baked into the closure
-    as a trace-time constant — so rebinding a sampler to a mutated graph
-    of the same shape (the `repro.stream` delta path builds one per
-    delta) reuses the compiled program instead of recompiling it, and an
-    incremental refresh stays churn-priced.  jit retraces per input
-    shape, so one entry serves every padded block size and graph shape.
+    Cached at MODULE level on (mesh, the statics `_dense_block` reads),
+    with the graph / frontier index passed as a traced ARGUMENT rather
+    than baked into the closure as a trace-time constant — so rebinding a
+    sampler to a mutated graph of the same shape (the `repro.stream`
+    delta path builds one per delta) reuses the compiled program instead
+    of recompiling it, and an incremental refresh stays churn-priced.
+    jit retraces per input shape, so one entry serves every padded block
+    size and graph shape.
     """
     key = (mesh, axis, spec.diffusion, spec.frontier, spec.num_colors,
            spec.max_iters, ladder)
@@ -454,39 +463,13 @@ def _data_parallel_block_fn(mesh, axis: str, spec: SamplerSpec, ladder):
     if fn is None:
         from jax.sharding import PartitionSpec as P
 
-        from repro.distributed.traversal import run_batch
-
-        def one(data, starts, seed):
-            if spec.frontier == "sparse":
-                # The sparse engine is fully traced (capacity-bucket
-                # conds are shard-local — no collectives), so it drops
-                # straight into the shard_map body; fidx rides along
-                # replicated like the graph.
-                from repro.core import sparse
-                (fidx,) = data
-                if spec.diffusion == "lt":
-                    return sparse.run_fused_lt_sparse(
-                        fidx, starts, spec.num_colors, seed,
-                        max_levels=spec.max_iters, ladder=ladder)[0]
-                return sparse.run_fused_sparse(
-                    fidx, starts, spec.num_colors, seed,
-                    max_levels=spec.max_iters, ladder=ladder).visited
-            if spec.diffusion == "lt":
-                g, cb, segs = data
-                sel = lt.selection_mask_from_cb(g, cb, spec.num_colors,
-                                                seed)
-                return lt.lt_traversal_program(g, sel, starts,
-                                               spec.num_colors,
-                                               spec.max_iters, segs)[0]
-            g, segs = data
-            return run_batch(g, starts, seed, spec.num_colors,
-                             max_levels=spec.max_iters, segments=segs)
-
         def body(data, starts_local, seeds_local):
-            # Sequential over the shard's local slice: one (V, W)
-            # transient at a time per device, parallel across shards.
-            return jax.lax.map(lambda a: one(data, *a),
-                               (starts_local, seeds_local))
+            # All three outputs, though `_block` keeps only the masks:
+            # with the counters dead the compiler drops the level's count
+            # and lays its edge arrays out edge-major, padded to 128
+            # lanes — twice the dense path's scratch (PERF.md §7).
+            return _dense_block(spec, ladder, data, starts_local,
+                                seeds_local)
 
         fn = jax.jit(jax.shard_map(body, mesh=mesh,
                                    in_specs=(P(), P(axis), P(axis)),
@@ -499,11 +482,14 @@ class DataParallelSampler(_BlockSampler):
     """Batch blocks over a mesh axis via ``shard_map`` — IC and LT.
 
     A block of B batch indices is padded to the shard count and sharded
-    ``P(axis)`` over its leading dim; each shard runs a sequential
-    ``lax.map`` of full traversals over its local slice (its own devices,
-    its own RNG streams — zero collectives).  Slot blocks land exactly
-    where `ShardedSketchStore` shards them, so pool builds and refreshes
-    parallelize across the mesh with no default-device staging.
+    ``P(axis)`` over its leading dim; each shard runs the dense backend's
+    block program (`_dense_block`) over its local slice (its own devices,
+    its own RNG streams — zero collectives).  The `DenseSampler` of the
+    same spec holds the program's graph-side data (LT CDF, frontier index
+    and ladder, destination segments) and serves single batches.  Slot
+    blocks land exactly where `ShardedSketchStore` shards them, so pool
+    builds and refreshes parallelize across the mesh with no
+    default-device staging.
     """
 
     def __init__(self, g, spec, mesh, *, g_rev=None):
@@ -515,37 +501,22 @@ class DataParallelSampler(_BlockSampler):
                              f"{mesh.axis_names}")
         self.mesh = mesh
         self.axis = spec.mesh_axis
-        self._cb = (jnp.asarray(_lt_cum_before(self.g_rev))
-                    if spec.diffusion == "lt" else None)
-        if spec.frontier == "sparse":
-            self._fidx, self._ladder = self._sparse_index(
-                None if self._cb is None else np.asarray(self._cb))
-        else:
-            self._fidx = self._ladder = None
+        self._dense = DenseSampler(g, spec.replace(backend="dense"),
+                                   g_rev=self.g_rev)
 
     @property
     def num_shards(self) -> int:
         return int(self.mesh.shape[self.axis])
-
-    # ----------------------------------------------------- block program
-    def _block_data(self):
-        """The graph-dependent pytree the block program takes as a traced
-        INPUT — what a streaming update swaps out under the cached
-        program (`repro.stream` rebinds samplers per delta)."""
-        if self.spec.frontier == "sparse":
-            return (self._fidx,)
-        if self.spec.diffusion == "lt":
-            return (self.g_rev, self._cb, self._dst_segments())
-        return (self.g_rev, self._dst_segments())
 
     def _block(self, idx: list[int]):
         """(visited, roots) for one padded block: visited (B, V, W) sharded
         ``P(axis)``, roots (B, C) host numpy — starts are derived once and
         shared by the traversal and the returned `RRRBatch` roots."""
         padded, starts, seeds = self._block_inputs(idx, self.num_shards)
+        data = self._dense._block_data()
         fn = _data_parallel_block_fn(self.mesh, self.axis, self.spec,
-                                     self._ladder)
-        vis = fn(self._block_data(), starts, seeds)
+                                     self._dense._ladder)
+        vis = fn(data, starts, seeds)[0]
         # Slicing a sharded array re-gathers; keep the P(axis) layout when
         # the block divides evenly (the pool-build case).
         if padded != len(idx):
@@ -556,16 +527,11 @@ class DataParallelSampler(_BlockSampler):
         """Single batch: go through the dense path — padding a 1-batch
         block to the shard count would traverse the same batch on every
         shard for one kept result.  Bit-identical by the facade contract."""
-        if not hasattr(self, "_dense"):
-            self._dense = DenseSampler(self.graph,
-                                       self.spec.replace(backend="dense"),
-                                       g_rev=self.g_rev)
         return self._dense.sample(batch_index)
 
     def rebind(self, g, g_rev, touched_row_blocks=None):
-        if self._try_patch_fidx(g, g_rev, touched_row_blocks):
-            # The lazily built single-batch helper binds the old graph.
-            self.__dict__.pop("_dense", None)
+        if self._dense._try_patch_fidx(g, g_rev, touched_row_blocks):
+            self.graph, self.g_rev = g, self._dense.g_rev
             return self
         return make_sampler(g, self.spec, self.mesh, g_rev=g_rev)
 

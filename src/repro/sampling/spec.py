@@ -25,7 +25,6 @@ index and a pool may be built under one backend and extended under another.
 from __future__ import annotations
 
 import dataclasses
-import warnings
 
 DIFFUSIONS = ("ic", "lt")
 BACKENDS = ("dense", "tiled", "kernel", "data_parallel", "graph_parallel")
@@ -119,8 +118,7 @@ class SamplerSpec:
         return cls(**{k: v for k, v in d.items() if k in names})
 
 
-def resolve_spec(spec: SamplerSpec | None = None,
-                 sample_kw: dict | None = None, *,
+def resolve_spec(spec: SamplerSpec | None = None, *,
                  num_colors: int | None = None,
                  master_seed: int | None = None) -> SamplerSpec:
     """THE one spec-vs-arguments reconciliation policy, shared by every
@@ -128,58 +126,18 @@ def resolve_spec(spec: SamplerSpec | None = None,
     `rrr.sample_collection`, `SamplingDriver`).
 
     ``num_colors``/``master_seed`` are ``None`` when the caller did not set
-    them explicitly.  Legacy ``sample_kw`` dicts convert (with a
-    DeprecationWarning, exclusive with ``spec``); an explicit ``spec`` wins
-    over unset arguments, and a set argument that disagrees with the spec
-    raises — never a silent override.
+    them explicitly.  An explicit ``spec`` wins over unset arguments, and
+    a set argument that disagrees with the spec raises — never a silent
+    override.
     """
-    nc = 64 if num_colors is None else num_colors
-    ms = 0 if master_seed is None else master_seed
-    if sample_kw:
-        if spec is not None:
-            raise ValueError("pass spec OR legacy sample_kw, not both")
-        return spec_from_sample_kw(sample_kw, num_colors=nc, master_seed=ms)
     if spec is None:
-        return SamplerSpec(num_colors=nc, master_seed=ms)
+        return SamplerSpec(
+            num_colors=64 if num_colors is None else num_colors,
+            master_seed=0 if master_seed is None else master_seed)
     for name, mine in (("num_colors", num_colors),
                        ("master_seed", master_seed)):
         theirs = getattr(spec, name)
         if mine is not None and mine != theirs:
             raise ValueError(f"{name}={mine} conflicts with "
                              f"spec.{name}={theirs} — set it in one place")
-    return spec
-
-
-def spec_from_sample_kw(sample_kw: dict, *, num_colors: int = 64,
-                        master_seed: int = 0,
-                        warn: bool = True) -> SamplerSpec:
-    """Convert a legacy ``rrr.sample_batch``-kwargs dict to a `SamplerSpec`.
-
-    The old untyped dict (``PoolConfig.sample_kw`` / ``run_imm(**kw)``)
-    carried ``model``, ``tg_rev``/``use_kernel``, ``max_levels`` and
-    ``sort_starts``.  A prebuilt ``tg_rev`` cannot ride along (the facade
-    owns tiling) — its presence selects the tiled/kernel backend and the
-    tile layout is rebuilt from the graph.
-    """
-    if warn:
-        warnings.warn(
-            "sample_kw dicts are deprecated — pass a repro.sampling."
-            "SamplerSpec instead (converted automatically for now)",
-            DeprecationWarning, stacklevel=3)
-    kw = dict(sample_kw)
-    diffusion = kw.pop("model", "ic")
-    tg_rev = kw.pop("tg_rev", None)
-    use_kernel = kw.pop("use_kernel", False)
-    backend = "dense"
-    tile_size = 128
-    if tg_rev is not None:
-        backend = "kernel" if use_kernel else "tiled"
-        tile_size = int(getattr(tg_rev, "tile_size", 128))
-    spec = SamplerSpec(
-        diffusion=diffusion, backend=backend, num_colors=num_colors,
-        master_seed=master_seed, max_iters=int(kw.pop("max_levels", 64)),
-        sort_starts=bool(kw.pop("sort_starts", False)), tile_size=tile_size)
-    if kw:
-        raise ValueError(f"unknown sample_kw keys {sorted(kw)} — cannot "
-                         "convert to SamplerSpec")
     return spec

@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from repro import obs, sampling
-from repro.core import imm, lt, sparse, traversal
+from repro.core import imm, lt, rrr, sparse, traversal
 from repro.graph import csr, generators
 from repro.launch.mesh import make_mesh
 
@@ -172,6 +172,25 @@ def test_block_programs_share_one_output_shape(chain, block):
                             np.asarray(visits)):
         assert (b.levels, b.truncated_colors) == tuple(row)
         assert (b.fused_edge_visits, b.unfused_edge_visits) == tuple(pair)
+        # A single batch is a block of one, with the same four counters
+        # as the block's row and, on the sparse frontier, as the
+        # single-batch sparse programs count them.
+        one = smp.sample(b.batch_index)
+        one = (one.fused_edge_visits, one.unfused_edge_visits, one.levels,
+               one.truncated_colors)
+        assert one == (*pair, *row)
+        if frontier == "sparse":
+            i = b.batch_index
+            if diffusion == "lt":
+                _, levels, truncated, v = sparse.run_fused_lt_sparse(
+                    smp._frontier_index(), starts[i], 64, seeds[i],
+                    max_levels=8, ladder=smp._ladder)
+                want = (*map(int, v), int(levels), int(truncated))
+            else:
+                want = rrr.stats_counts(sparse.run_fused_sparse(
+                    smp._frontier_index(), starts[i], 64, seeds[i],
+                    max_levels=8, ladder=smp._ladder).stats)
+            assert one == want
 
 
 @pytest.mark.parametrize("diffusion,batches,per_loop,loops", [

@@ -1,8 +1,8 @@
 """Unified Sampler API: spec validation, cross-backend bit-identity (dense
-vs tiled vs kernel vs single-device graph_parallel, single process), LT
-serving end-to-end, PoolConfig spec rules, and the manifest diffusion
-guard.  (Multi-device data_parallel / graph_parallel need forced host
-devices — covered by tests/serve_distributed_check.py.)"""
+vs tiled vs kernel vs one-device data_parallel and graph_parallel, single
+process), LT serving end-to-end, PoolConfig spec rules, and the manifest
+diffusion guard.  (Multi-device data_parallel / graph_parallel need forced
+host devices — covered by tests/serve_distributed_check.py.)"""
 import dataclasses
 
 import numpy as np
@@ -59,30 +59,41 @@ def test_spec_is_hashable_and_manifest_round_trips():
 
 
 def test_spec_from_sample_kw_warns_and_converts(graph):
-    with pytest.warns(DeprecationWarning):
-        spec = sampling.spec_from_sample_kw(
-            {"model": "lt", "max_levels": 32, "sort_starts": True},
-            num_colors=32, master_seed=9)
-    assert spec == sampling.SamplerSpec(
-        diffusion="lt", backend="dense", num_colors=32, master_seed=9,
-        max_iters=32, sort_starts=True)
-    with pytest.warns(DeprecationWarning):
-        with pytest.raises(ValueError, match="unknown sample_kw"):
-            sampling.spec_from_sample_kw({"bogus": 1})
+    """The legacy ``sample_kw`` conversion (warned since the Sampler-API
+    PR) is removed: its converter is gone, and the old keywords raise
+    `TypeError` wherever they were accepted."""
+    from repro.core.driver import SamplingDriver
+    assert not hasattr(sampling, "spec_from_sample_kw")
+    with pytest.raises(TypeError):
+        sampling.resolve_spec(None, {"model": "lt"})
+    with pytest.raises(TypeError, match="model"):
+        rrr.sample_collection(graph, 64, 64, 9, model="lt")
+    with pytest.raises(TypeError, match="max_levels"):
+        SamplingDriver(csr.transpose(graph), 64, 9, max_levels=32)
 
 
 # --------------------------------------------- cross-backend bit identity
+def _data_parallel_block(graph, spec, batch_indices):
+    """``sample_many`` of a data_parallel sampler on a one-device mesh:
+    the shard body (`sampler._dense_block` under ``shard_map``) runs —
+    ``sample`` would take the private dense sampler instead."""
+    smp = sampling.make_sampler(graph, spec.replace(backend="data_parallel"),
+                                mesh=make_mesh((1,), ("data",)))
+    return {b.batch_index: b for b in smp.sample_many(batch_indices)}
+
+
 def test_dense_tiled_kernel_bit_identical(graph):
     """Same (master_seed, batch_index) ⇒ identical RRRBatch.visited on
     every backend (the facade's core contract)."""
     specs = {b: sampling.SamplerSpec(backend=b, num_colors=64, master_seed=5)
              for b in ("dense", "tiled", "kernel")}
     samplers = {b: sampling.make_sampler(graph, s) for b, s in specs.items()}
-    for bi in (0, 3):
+    dp = _data_parallel_block(graph, specs["dense"], [0, 3, 5])
+    for bi in (0, 3, 5):
         ref = samplers["dense"].sample(bi)
         assert ref.batch_index == bi
-        for b in ("tiled", "kernel"):
-            got = samplers[b].sample(bi)
+        for got in (samplers["tiled"].sample(bi),
+                    samplers["kernel"].sample(bi), dp[bi]):
             np.testing.assert_array_equal(np.asarray(got.visited),
                                           np.asarray(ref.visited))
             np.testing.assert_array_equal(got.roots, ref.roots)
@@ -121,14 +132,15 @@ def test_lt_tiled_bit_identical_to_dense(graph):
     spec = sampling.SamplerSpec(diffusion="lt", num_colors=64, master_seed=5)
     dense = sampling.make_sampler(graph, spec)
     tiled = sampling.make_sampler(graph, spec.replace(backend="tiled"))
+    dp = _data_parallel_block(graph, spec, [0, 3])
     for bi in (0, 3):
         ref = dense.sample(bi)
-        got = tiled.sample(bi)
-        _assert_lt_reference(graph, [got], spec.master_seed)
-        assert got.batch_index == bi
-        np.testing.assert_array_equal(np.asarray(got.visited),
-                                      np.asarray(ref.visited))
-        np.testing.assert_array_equal(got.roots, np.asarray(ref.roots))
+        for got in (tiled.sample(bi), dp[bi]):
+            _assert_lt_reference(graph, [got], spec.master_seed)
+            assert got.batch_index == bi
+            np.testing.assert_array_equal(np.asarray(got.visited),
+                                          np.asarray(ref.visited))
+            np.testing.assert_array_equal(got.roots, np.asarray(ref.roots))
 
 
 def test_lt_kernel_bit_identical_to_dense(graph):
@@ -229,14 +241,18 @@ def test_sparse_frontier_bit_identical_across_matrix(graph):
         backends = ["dense", "tiled", "kernel"]
         ref = sampling.make_sampler(graph, sampling.SamplerSpec(
             diffusion=diffusion, num_colors=64, master_seed=5))
-        for backend in backends + ["graph_parallel"]:
+        for backend in backends + ["graph_parallel", "data_parallel"]:
             spec = sampling.SamplerSpec(
                 diffusion=diffusion, backend=backend, num_colors=64,
                 master_seed=5, frontier="sparse")
-            m = mesh if backend == "graph_parallel" else None
-            s = sampling.make_sampler(graph, spec, mesh=m)
+            if backend == "data_parallel":
+                # Through the shard body: its `sample` is the dense one.
+                sample = _data_parallel_block(graph, spec, [0, 2]).__getitem__
+            else:
+                m = mesh if backend == "graph_parallel" else None
+                sample = sampling.make_sampler(graph, spec, mesh=m).sample
             for bi in (0, 2):
-                got = s.sample(bi)
+                got = sample(bi)
                 want = ref.sample(bi)
                 if diffusion == "lt":
                     _assert_lt_reference(graph, [got], spec.master_seed)
@@ -396,9 +412,14 @@ def test_lt_pool_serves_topk_end_to_end(graph):
 
 
 def test_run_imm_legacy_sample_kw_warns(graph):
-    with pytest.warns(DeprecationWarning):
-        res = imm.run_imm(graph, k=2, eps=0.5, num_colors=64, master_seed=1,
-                          theta_cap=256, sort_starts=True)
+    """``run_imm(**sample_kw)`` is gone: a legacy keyword raises, and the
+    same setting goes through the spec."""
+    with pytest.raises(TypeError, match="sort_starts"):
+        imm.run_imm(graph, k=2, eps=0.5, num_colors=64, master_seed=1,
+                    theta_cap=256, sort_starts=True)
+    res = imm.run_imm(graph, k=2, eps=0.5, theta_cap=256,
+                      spec=sampling.SamplerSpec(num_colors=64, master_seed=1,
+                                                sort_starts=True))
     assert len(res.seeds) == 2
 
 

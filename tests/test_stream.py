@@ -51,11 +51,18 @@ def _absent_pairs(g, count, seed=0):
 
 
 def _stream_store(g, *, diffusion="ic", frontier="dense", batches=6,
-                  colors=32, tile=64, seed=9):
+                  colors=32, tile=64, seed=9, mesh=None):
+    """A dense-backend pool, or with ``mesh`` a data_parallel one."""
     spec = SamplerSpec(diffusion=diffusion, backend="dense",
                        num_colors=colors, master_seed=seed,
                        tile_size=tile, frontier=frontier)
-    store = SketchStore(g, PoolConfig(max_batches=16, spec=spec))
+    if mesh is None:
+        store = SketchStore(g, PoolConfig(max_batches=16, spec=spec))
+    else:
+        from repro.serve.distributed import ShardedSketchStore
+        store = ShardedSketchStore(
+            g, PoolConfig(max_batches=16,
+                          spec=spec.replace(backend="data_parallel")), mesh)
     store.ensure(batches)
     return store
 
@@ -350,20 +357,31 @@ def test_patch_frontier_index_matches_fresh_build(graph):
 
 
 def test_values_only_delta_patches_sampler_in_place(graph):
-    store = _stream_store(graph, frontier="sparse", batches=3)
-    s0 = store.sampler
-    tracker = DirtySlotTracker.for_store(store)
-    rng = np.random.default_rng(73)
-    incremental_refresh(store, tracker,
-                        random_delta(store.graph, rng, num_deletes=3,
-                                     num_inserts=0))
-    assert store.sampler is s0, \
-        "a tombstone-only delta must patch the frontier index in place"
-    (sa, da), = _absent_pairs(store.graph, 1, seed=73)
-    incremental_refresh(store, tracker,
-                        EdgeDelta.inserts([sa], [da], [0.05]))
-    assert store.sampler is not s0, \
-        "an appending insert changes the edge layout → full rebuild"
+    """Dense, and data_parallel on a one-device mesh, whose shards run the
+    dense sampler's block program on its patched data: a rebind keeps the
+    module-level compiled program (one cache entry per statics)."""
+    from repro.launch.mesh import make_mesh
+    from repro.sampling import sampler as sampler_mod
+    for mesh in (None, make_mesh((1,), ("data",))):
+        store = _stream_store(graph, frontier="sparse", batches=3, mesh=mesh)
+        s0 = store.sampler
+        programs = len(sampler_mod._DP_BLOCK_FNS)
+        tracker = DirtySlotTracker.for_store(store)
+        rng = np.random.default_rng(73)
+        incremental_refresh(store, tracker,
+                            random_delta(store.graph, rng, num_deletes=3,
+                                         num_inserts=0))
+        assert store.sampler is s0, \
+            "a tombstone-only delta must patch the frontier index in place"
+        (sa, da), = _absent_pairs(store.graph, 1, seed=73)
+        incremental_refresh(store, tracker,
+                            EdgeDelta.inserts([sa], [da], [0.05]))
+        assert store.sampler is not s0, \
+            "an appending insert changes the edge layout → full rebuild"
+        assert len(sampler_mod._DP_BLOCK_FNS) == programs
+        for got, want in zip(store.batches, cold_rebuild_batches(store)):
+            np.testing.assert_array_equal(np.asarray(got.visited),
+                                          np.asarray(want.visited))
 
 
 # ------------------------------------------------------------- compaction

@@ -167,7 +167,11 @@ def test_dense_level_keeps_edge_arrays_lane_dense(one_chip):
     A block of b = 4 batches at 64 colors runs as one loop of G = 4
     batches, 8 words wide (`traversal.batches_per_loop`), so that one
     gather fills 8 of the padded buffer's 128 lanes; its scratch stays
-    within 10 % of the one-batch, 2-word program's at the same E.
+    within 10 % of the one-batch, 2-word program's at the same E.  The
+    data_parallel backend's shard program (`_dense_block` under
+    ``shard_map``, here on a one-chip mesh) must compile to the same
+    level: with only its masks live the compiler dropped the count and
+    laid the edge arrays out edge-major.
 
     E = 2**20 at 16 edges a vertex, Graph500's edge factor: below about
     2**19 edges the compiler also relays the OR scan's accumulator for
@@ -192,10 +196,37 @@ def test_dense_level_keeps_edge_arrays_lane_dense(one_chip):
     fused = compiled(b)
     width = traversal.batches_per_loop(b, C) * W
     assert width == 8
-    padded, relaid = _edge_buffers(fused.as_text(), e, width)
-    gather = "/bpt.gather/gather"
-    assert [n.endswith(gather) for _, _, n in padded] == [True], padded
-    assert [n.endswith(gather) for _, _, n in relaid] == [True], relaid
-    temp = fused.memory_analysis().temp_size_in_bytes
     one_batch = compiled(1).memory_analysis().temp_size_in_bytes
-    assert temp <= 1.1 * one_batch, (temp, one_batch)
+    gather = "/bpt.gather/gather"
+    for program in (fused, _data_parallel_compiled(one_chip, g, seg, b)):
+        padded, relaid = _edge_buffers(program.as_text(), e, width)
+        assert [n.endswith(gather) for _, _, n in padded] == [True], padded
+        assert [n.endswith(gather) for _, _, n in relaid] == [True], relaid
+        temp = program.memory_analysis().temp_size_in_bytes
+        assert temp <= 1.1 * one_batch, (temp, one_batch)
+
+
+def _data_parallel_compiled(one_chip, g, seg, num_batches):
+    """The data_parallel shard program for ``num_batches`` batches on a
+    one-device mesh of the described chip, compiled."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.launch.mesh import make_mesh
+    from repro.sampling import SamplerSpec
+    from repro.sampling.sampler import _data_parallel_block_fn
+
+    mesh = make_mesh((1,), ("data",), devices=list(one_chip.device_set))
+
+    def on_mesh(x, spec):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    data = jax.tree.map(lambda x: on_mesh(x, P()), (g, seg))
+    starts = on_mesh(jax.ShapeDtypeStruct((num_batches, C), jnp.int32),
+                     P("data"))
+    seeds = on_mesh(jax.ShapeDtypeStruct((num_batches,), jnp.uint32),
+                    P("data"))
+    fn = _data_parallel_block_fn(
+        mesh, "data", SamplerSpec(backend="data_parallel", num_colors=C,
+                                  max_iters=1024), None)
+    return fn.lower(data, starts, seeds).compile()
